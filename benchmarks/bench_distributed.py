@@ -1,5 +1,7 @@
 """Row-sharded execution benchmark: the data-mesh path vs the
-single-process chunked baseline, same process, 8 forced CPU devices.
+single-process chunked baseline, same process, 8 forced CPU devices
+(a CPU rehearsal of the mesh; the chip mesh is ``chip_smoke.py
+--chips 4``).
 
 Two workloads, the tentpole's acceptance rows:
 
@@ -116,6 +118,9 @@ def run_subprocess(csv=print, smoke=True, timeout=1800):
     CSV stdout lines into ``csv`` (benchmarks/run.py's Recorder)."""
     root = pathlib.Path(__file__).resolve().parent.parent
     env = dict(os.environ)
+    # a CPU rehearsal of the mesh: the child must not reach for an
+    # accelerator the parent process already holds
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={N_DEVICES}")
     env["PYTHONPATH"] = os.pathsep.join(

@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
 import pathlib
 import platform
 import sys
@@ -65,6 +66,13 @@ def main(argv=None):
                          "Chrome trace) — keeps the repo root clean")
     args = ap.parse_args(argv)
     pathlib.Path(args.outdir).mkdir(parents=True, exist_ok=True)
+    import jax
+
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        # a fixed in-checkout path (the path is part of the cache key);
+        # JAX reads JAX_COMPILATION_CACHE_DIR itself when it is set
+        jax.config.update("jax_compilation_cache_dir",
+                          str(_ROOT / ".jax_cache"))
 
     rec = Recorder()
     t0 = time.time()
@@ -182,7 +190,6 @@ def main(argv=None):
         bench_dryrun.main([], csv=rec)
 
     if args.json:
-        import jax
         payload = {
             "meta": {
                 "schema": "bench-v1",

@@ -83,7 +83,8 @@ class DMLResult(SandwichEffectResult):
             key=jax.random.fold_in(ctx.key, 0x0b00), alpha=alpha,
             n_replicates=n_boot, scheme=resolve_scheme(method),
             executor=exe, point=self.theta, point_se=self.stderr,
-            rules=ctx.rules, row_block=self.cfg.row_block, **rt_kw)
+            rules=ctx.rules, row_block=self.cfg.row_block,
+            strategy=self.cfg.row_block_strategy, **rt_kw)
 
     def _summary_extra(self):
         d = self.diagnostics

@@ -112,12 +112,11 @@ def _active_data_mesh():
     return None if rd is None else rd.current_data_mesh()
 
 
-def design(X: Array, *, intercept: bool = False,
-           append: Optional[Array] = None) -> Array:
-    """Assemble the per-(block-)row design ``[X | 1? | append?]`` in
-    fp32.  ``append`` (a target / residual column) is how cross-moments
-    ride inside a Gram — the replicate-invariant trick from
-    repro.inference.numerics."""
+def design_parts(X: Array, *, intercept: bool = False,
+                 append: Optional[Array] = None) -> list:
+    """The fp32 column parts ``[X, 1?, append?]`` of the design.  The
+    fused kernel takes them apart and assembles each row block in VMEM,
+    so it never writes an (n, q) copy of X to HBM."""
     f32 = jnp.float32
     cols = [X.astype(f32)]
     if intercept:
@@ -125,6 +124,16 @@ def design(X: Array, *, intercept: bool = False,
     if append is not None:
         a = append.astype(f32)
         cols.append(a[:, None] if a.ndim == 1 else a)
+    return cols
+
+
+def design(X: Array, *, intercept: bool = False,
+           append: Optional[Array] = None) -> Array:
+    """Assemble the per-(block-)row design ``[X | 1? | append?]`` in
+    fp32.  ``append`` (a target / residual column) is how cross-moments
+    ride inside a Gram — the replicate-invariant trick from
+    repro.inference.numerics."""
+    cols = design_parts(X, intercept=intercept, append=append)
     return cols[0] if len(cols) == 1 else jnp.concatenate(cols, axis=1)
 
 
@@ -251,7 +260,7 @@ def weighted_gram(X: Array, w: Array, *, intercept: bool = False,
     ``n_eff = Σ_n w_n`` from the same blocked reduction.  With
     ``append=y``, the cross-moment ``Σ w·d·y`` is ``G[:, -1]``."""
     if _use_pallas(X.shape[0], row_block, strategy):
-        D = design(X, intercept=intercept, append=append)
+        D = design_parts(X, intercept=intercept, append=append)
         G = _seg_ops().design_gram(D, w=w, row_block=row_block)
         return G, w.astype(jnp.float32).sum()
     if append is None:
@@ -343,7 +352,7 @@ def fold_gram(X: Array, folds: Array, k: int, *, intercept: bool = False,
     (k, q, q) plus per-fold row counts (k,).  Integer fold ids are
     padded with -1 so padded rows one-hot to the zero row."""
     if _use_pallas(X.shape[0], row_block, strategy):
-        D = design(X, intercept=intercept, append=append)
+        D = design_parts(X, intercept=intercept, append=append)
         return _seg_ops().fold_design_gram(D, folds, k,
                                            row_block=row_block)
 
@@ -378,7 +387,7 @@ def fold_weighted_gram(X: Array, Wk: Array, *, intercept: bool = False,
         D = design(X, intercept=intercept, append=append)
         return jnp.einsum("ni,kn,nj->kij", D, Wk.astype(f32), D), n_eff
     if strategy == "pallas":
-        D = design(X, intercept=intercept, append=append)
+        D = design_parts(X, intercept=intercept, append=append)
         return _seg_ops().fold_weighted_design_gram(D, Wk, row_block=r), n_eff
 
     def block(Xb, Wb, *rest):
@@ -418,8 +427,7 @@ def residual_moments(y: Array, t: Array, my: Array, mt: Array, phi: Array,
     if backend in ("pallas", "interpret"):
         def block(yb, tb, myb, mtb, phib):
             return rg_ops.residual_gram(yb, tb, myb, mtb, phib,
-                                        backend=backend,
-                                        block_n=min(512, r))
+                                        backend=backend)
     else:
         def block(yb, tb, myb, mtb, phib):
             ry = (yb - myb).astype(jnp.float32)

@@ -191,52 +191,19 @@ def tree_size_bytes(tree) -> int:
     return int(total)
 
 
-def mesh_context(mesh: Mesh):
-    """``jax.set_mesh(mesh)`` where available (jax >= 0.6), else the
-    nearest equivalent on older jax (``jax.sharding.use_mesh`` /
-    ``use_abstract_mesh``, falling back to the bare mesh context).
-    Lowering with explicit in_shardings is correct under all of them;
-    only activation ``constrain``s need the abstract mesh populated."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    use_mesh = getattr(jax.sharding, "use_mesh", None)
-    if use_mesh is not None:
-        return use_mesh(mesh)
-    return mesh
-
-
-def _active_mesh():
-    """The mesh the current trace sees: the abstract mesh on jax >= 0.6
-    (installed by ``jax.set_mesh``), the thread-resources physical mesh
-    (installed by the bare ``with mesh:`` context) on older jax.
-    Returns None when no mesh is active."""
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract is not None:
-        return get_abstract()
-    try:
-        from jax._src.mesh import thread_resources
-        return thread_resources.env.physical_mesh
-    except Exception:
-        return None
-
-
 def constrain(x: jax.Array, axes: Sequence[Optional[str]],
               rules: Optional[ShardingRules]) -> jax.Array:
     """with_sharding_constraint by logical axes; no-op when rules are
     None (smoke tests) or outside a ``jax.set_mesh`` scope.
 
-    NOTE: on jax >= 0.6 the mesh must be installed with
-    ``jax.set_mesh(mesh)`` — there the bare ``with mesh:`` context does
+    NOTE: the mesh must be installed with ``jax.set_mesh(mesh)`` — the
+    bare ``with mesh:`` context does
     NOT populate the abstract mesh and silently disables every
-    activation constraint (this cost 10x memory in the first dry-run;
-    see EXPERIMENTS.md §Perf, iteration 0).  Use
-    ``sharding.mesh_context(mesh)`` to get the right scope on any jax
-    version."""
+    activation constraint (this cost 10x memory in the first dry-run)."""
     if rules is None:
         return x
-    mesh = _active_mesh()
-    if mesh is None or mesh.empty:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return x
     spec = logical_to_spec(axes, rules, mesh if mesh.axis_names else None)
     return jax.lax.with_sharding_constraint(x, spec)

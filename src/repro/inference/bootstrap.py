@@ -70,22 +70,26 @@ def fit_predict_folds(nuis: Nuisance, key: jax.Array, X: jax.Array,
 
     ridge/logistic take the replicate-invariant fold-batched kernels
     (serial == vmap bitwise), streamed in row blocks when the nuisance
-    carries a ``row_block`` hyper (or one is passed); other nuisances
+    carries a ``row_block`` hyper (or one is passed) through the
+    nuisance's ``strategy`` hyper (the pallas strategy takes the fused
+    fold-weighted kernel, like the point fit); other nuisances
     (MLP, custom) fall back to vmapping ``nuis.fit`` over folds —
     statistically identical, but LAPACK-free bit-identity is not
     guaranteed there.
     """
     rb = row_block or int(_hyper(nuis, "row_block", 0))
+    st = _hyper(nuis, "strategy", None)
     if nuis.name == "ridge":
         lam = _hyper(nuis, "lam", 1e-3)
         return predict_folds_linear(
-            ridge_fit_folds_w(lam, X, target, Wk, row_block=rb), X)
+            ridge_fit_folds_w(lam, X, target, Wk, row_block=rb,
+                              strategy=st), X)
     if nuis.name == "logistic":
         lam = _hyper(nuis, "lam", 1e-3)
         iters = int(_hyper(nuis, "iters", 16))
         return predict_folds_logistic(
             logistic_fit_folds_w(lam, iters, X, target, Wk,
-                                 row_block=rb), X)
+                                 row_block=rb, strategy=st), X)
     k = Wk.shape[0]
     keys = jax.random.split(key, k)
     st0 = jax.vmap(nuis.init, in_axes=(0, None))(keys, X.shape[1])
@@ -116,7 +120,8 @@ def dml_residuals_once(nuis_y: Nuisance, nuis_t: Nuisance, n_folds: int,
 def dml_theta_once(nuis_y: Nuisance, nuis_t: Nuisance, n_folds: int,
                    XW: jax.Array, y: jax.Array, t: jax.Array,
                    phi: jax.Array, key: jax.Array, w: jax.Array,
-                   *, with_se: bool = True, row_block: int = 0
+                   *, with_se: bool = True, row_block: int = 0,
+                   strategy: Optional[str] = None
                    ) -> Dict[str, jax.Array]:
     """One full weighted DML re-estimation (the replicate closure body):
     fold keys re-derived from ``key``, nuisances cross-fit under
@@ -125,7 +130,7 @@ def dml_theta_once(nuis_y: Nuisance, nuis_t: Nuisance, n_folds: int,
     r = dml_residuals_once(nuis_y, nuis_t, n_folds, XW, y, t, key, w,
                            row_block=row_block)
     theta, se = weighted_theta(r["ry"], r["rt"], phi, w, with_se=with_se,
-                               row_block=row_block)
+                               row_block=row_block, strategy=strategy)
     out = {"theta": theta}
     if se is not None:
         out["se"] = se
@@ -134,7 +139,8 @@ def dml_theta_once(nuis_y: Nuisance, nuis_t: Nuisance, n_folds: int,
 
 def make_dml_replicate_fn(nuis_y: Nuisance, nuis_t: Nuisance,
                           n_folds: int, *, scheme: str = "pairs",
-                          with_se: bool = True, row_block: int = 0):
+                          with_se: bool = True, row_block: int = 0,
+                          strategy: Optional[str] = None):
     """The bootstrap replicate closure: (key, XW, y, t, phi) ->
     {theta[, se]}.  The data tensors arrive as executor pass-through
     arguments (not closure constants) so compiled programs take them as
@@ -147,7 +153,7 @@ def make_dml_replicate_fn(nuis_y: Nuisance, nuis_t: Nuisance,
         w = bootstrap_weights(kw, XW.shape[0], scheme)
         return dml_theta_once(nuis_y, nuis_t, n_folds, XW, y, t, phi,
                               kfit, w, with_se=with_se,
-                              row_block=row_block)
+                              row_block=row_block, strategy=strategy)
 
     return replicate
 
@@ -161,8 +167,9 @@ def dml_bootstrap(nuis_y: Nuisance, nuis_t: Nuisance, *, n_folds: int,
                   point: Optional[jax.Array] = None,
                   point_se: Optional[jax.Array] = None,
                   mesh=None, rules=None,
-                  row_block: int = 0, memory_budget: int = 0,
-                  chunk: int = 0, max_retries: int = 2) -> InferenceResult:
+                  row_block: int = 0, strategy: Optional[str] = None,
+                  memory_budget: int = 0, chunk: int = 0,
+                  max_retries: int = 2) -> InferenceResult:
     """B weighted DML refits scheduled by the task runtime: the
     replicate axis streams in memory-budgeted chunks (repro.runtime),
     each chunk retrying down the backend ladder on failure — results
@@ -174,7 +181,8 @@ def dml_bootstrap(nuis_y: Nuisance, nuis_t: Nuisance, *, n_folds: int,
     keys = replicate_keys(key, n_replicates)
     replicate = make_dml_replicate_fn(nuis_y, nuis_t, n_folds,
                                       scheme=scheme, with_se=with_se,
-                                      row_block=row_block)
+                                      row_block=row_block,
+                                      strategy=strategy)
     out = rt.map(replicate, keys, XW, y, t, phi, label="dml_bootstrap")
     thetas = out["theta"]
     se = jnp.std(thetas, axis=0, ddof=1)

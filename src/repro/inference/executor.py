@@ -170,7 +170,6 @@ class ShardMapExecutor:
         return Mesh(np.asarray(jax.devices()), (self.axis,))
 
     def map(self, fn, xs, *args):
-        from jax.experimental.shard_map import shard_map
         mesh = self._mesh()
         size = mesh.shape[self.axis]
         b = _leading_dim(xs)
@@ -189,13 +188,13 @@ class ShardMapExecutor:
             @jax.jit
             def sharded(xs_, *a):
                 # replicate axis sharded; pass-through args replicated
-                inner = shard_map(
+                inner = jax.shard_map(
                     lambda x_, *aa: jax.vmap(lambda e: g(e, *aa))(x_),
                     mesh=mesh,
                     in_specs=(spec,) + tuple(
                         jax.tree_util.tree_map(lambda _: P(), aa_)
                         for aa_ in a),
-                    out_specs=spec, check_rep=False)
+                    out_specs=spec, check_vma=False)
                 return inner(xs_, *a)
             return sharded
 

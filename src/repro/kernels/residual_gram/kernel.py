@@ -12,8 +12,8 @@ zero-padded inside the kernel wrapper — all-zero rows produce all-zero
 M rows, contributing exactly 0.0 to G and b (tested bitwise in
 tests/test_kernels_seg_gram.py).
 
-``interpret=None`` auto-detects the platform: compiled mosaic on TPU,
-interpret mode elsewhere.
+``interpret`` is explicit: False compiles with Mosaic (TPU only),
+True runs the same grid in interpret mode.
 """
 
 from __future__ import annotations
@@ -33,8 +33,8 @@ def residual_gram_pallas(
     mt: jax.Array,
     phi: jax.Array,
     *,
-    block_n: int = 512,
-    interpret: Optional[bool] = None,
+    interpret: bool,
+    block_n: Optional[int] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """y,t,my,mt: (n,); phi: (n,p). Returns (G (p,p), b (p,)) in fp32."""
     p = phi.shape[1]

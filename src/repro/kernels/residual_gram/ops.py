@@ -4,14 +4,15 @@ Used by repro.core.final_stage: local (per-shard) moments are computed
 here, then psum'd over the data axis — the distributed normal equations
 of the DML final stage.  The kernel path routes through the unified
 segment-Gram kernel (repro.kernels.seg_gram), whose wrapper zero-pads
-the row tail (exact no-op) — no n % block_n divisibility requirement —
-and auto-detects interpret mode off-TPU.
+the row tail (exact no-op) — no n % block_n divisibility requirement.
+"pallas" compiles with Mosaic and raises off TPU; "interpret" runs the
+same kernel in interpret mode.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 
@@ -32,12 +33,19 @@ def residual_gram(
     phi: jax.Array,
     *,
     backend: str = "",
-    block_n: int = 512,
+    block_n: Optional[int] = None,
 ) -> Tuple[jax.Array, jax.Array]:
-    """Fused residualize->moments. Returns (G (p,p), b (p,)), fp32."""
+    """Fused residualize->moments. Returns (G (p,p), b (p,)), fp32.
+    ``block_n`` overrides the kernel's VMEM-planned row block."""
     be = backend or default_backend()
     if be == "ref":
         return _ref.residual_gram_ref(y, t, my, mt, phi)
+    if be not in ("pallas", "interpret"):
+        raise ValueError(f"unknown residual_gram backend {be!r}")
+    if be == "pallas" and jax.default_backend() != "tpu":
+        raise RuntimeError(
+            "residual_gram: the 'pallas' lowering runs on TPU only (the "
+            f"backend is {jax.default_backend()!r}); use 'interpret' or 'ref'")
     return _kernel.residual_gram_pallas(
         y,
         t,
@@ -45,5 +53,5 @@ def residual_gram(
         mt,
         phi,
         block_n=block_n,
-        interpret=True if be == "interpret" else None,
+        interpret=be == "interpret",
     )

@@ -9,42 +9,127 @@ this kernel streams (block_n, d) tiles through VMEM once per input,
 runs the builder in registers, applies the segment mask and bootstrap
 weight in registers, and accumulates into a VMEM-resident output:
 
-  grid        (n / block_n,) — sequential; outputs use a constant block
-              index so they stay pinned in VMEM across iterations.
+  grid        (segment tiles, n / block_n) — the row axis is innermost
+              and sequential, so each segment tile's output block stays
+              pinned in VMEM while every row block streams past it.
   S == 1      g (qL, qR):        g += (w * L)^T R      (one MXU matmul)
-  S  > 1      g (S*qL, qR):      the weighted one-hot expands L into
-              T[n, s*qL + i] = oh[n, s] * L[n, i] and g += T^T R — the
-              segmented sum IS the matmul, which is the layout the MXU
-              wants (a 2-D (S*qL, qR) accumulator, not (S, qL, qR)).
+  S  > 1      one tile covers ``seg_tile`` segments; the weighted
+              one-hot of the tile's segments expands L into
+              T[n, s*qL + i] = oh[n, s] * L[n, i] and g_tile += T^T R —
+              the segmented sum IS the matmul, a 2-D (seg_tile*qL, qR)
+              accumulator the MXU wants.  Tiling the segment axis bounds
+              the accumulator and T at any S: untiled, the store's
+              final-stage Gram (S = E*K = 320, q = 106 at p = 50) needs
+              a (35840, 128) accumulator, 40 MB of VMEM with its double
+              buffer even at 8-row blocks.  Each tile re-reads the rows,
+              so HBM traffic grows with the tile count, not the VMEM
+              footprint.
 
-VMEM working set (fp32): input tiles ~ block_n * sum(d_i), T tile
-block_n * S*qL, accumulator S*qL * qR.  block_n=512, S*qL=768, qR=128:
-512*768*4 + 768*128*4 ~ 1.9 MiB << 16 MiB.
+Sizing: ``plan`` picks the segment tile (T about ``TILE_ROWS`` wide) and
+then the largest row block whose VMEM working set (``vmem_bytes``)
+fits ``VMEM_BUDGET``; the kernel is compiled with ``VMEM_LIMIT`` so
+builder temporaries have headroom.  Nothing is taken from the caller's
+``row_block``: that is the XLA strategies' streaming unit, not a tile.
 
-Padding contract: the row tail is zero-padded to a multiple of block_n
-with seg = -1 (matches no lane of the iota compare -> zero mask row)
-and w = 0; builders map all-zero rows to all-zero L/R rows, so padded
-rows contribute exactly 0.0 to every accumulator.  On the mosaic path
-L/R columns are zero-padded in registers to the (8, 128) fp32 tile
-(sliced off the output) — interpret mode skips the column padding.
+Operands: inputs of 128 columns or more (and broadcast (1, d) rows)
+stream as their own tiles; every narrower input, the weights and the
+segment ids are packed into one (n, m) operand and sliced apart in
+VMEM, because HBM tiles pad an array's minor dim to 128 lanes (an
+(n, 1) f32 column alone would take n * 512 bytes).
 
-``interpret=None`` auto-detects: compiled mosaic on TPU, interpret
-elsewhere (the CPU certification mode the tests pin).
+Padding contract: ``plan`` prefers a block height that divides n (no
+tail at all); otherwise the row tail is zero-padded to a multiple of
+block_n with seg = -1 (matches no lane of the iota compare -> zero mask
+row) and w = 0; builders map all-zero rows to all-zero L/R rows, so
+padded rows contribute exactly 0.0 to every accumulator.  L/R columns are
+zero-padded in registers to the (8, 128) fp32 tile and segments to a
+whole number of tiles (sliced off the output).  Interpret mode runs the
+same tiling, so CPU certification covers the compiled block structure.
+
+The Gram matmul runs at ``PRECISION``: the moments are sums over
+millions of rows that the estimators solve against, so they need f32
+products, not a single bf16 pass (the MXU default for f32 operands).
 """
 
 from __future__ import annotations
 
-from typing import Optional, Sequence
+from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 Array = jax.Array
 
+VMEM_BUDGET = 24 << 20  # working set ``plan`` sizes tiles for
+VMEM_LIMIT = 64 << 20  # scoped VMEM granted to Mosaic (a v5e core has 128 MiB)
+MAX_BLOCK_N = 8192
+TILE_ROWS = 1024  # target accumulator height seg_tile * qL
+LANES = 128  # inputs narrower than this share one packed operand
+PRECISION = lax.Precision.HIGHEST
+
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def vmem_bytes(block_n: int, row_widths: Sequence[int], seg_tile: int,
+               qlp: int, qrp: int, segmented: bool) -> int:
+    """fp32 VMEM working set of one grid step: double-buffered row
+    tiles (each padded to 128 lanes — an (n, 1) column costs a full
+    lane row), the builder's L / w*L / R, for S > 1 the one-hot
+    expansion ((rows, seg_tile, qL) then T and its transpose), and the
+    double-buffered accumulator block."""
+    lanes = lambda d: _round_up(d, 128)  # noqa: E731
+    rows_in = 2 * block_n * sum(lanes(d) for d in row_widths)
+    regs = block_n * (2 * lanes(qlp) + qrp)
+    expand = 0
+    if segmented:
+        expand = block_n * (_round_up(seg_tile, 8) * lanes(qlp)
+                            + 2 * lanes(seg_tile * qlp))
+    acc = 2 * seg_tile * qlp * qrp
+    return 4 * (rows_in + regs + expand + acc)
+
+
+def plan(n: int, row_widths: Sequence[int], n_segments: int, qL: int,
+         qR: int, *, block_n: Optional[int] = None
+         ) -> Tuple[int, int, int, int]:
+    """(block_n, seg_tile, qlp, qrp) for an (n, ...) problem.  Raises
+    when even an 8-row block cannot fit the VMEM budget (no silent
+    fallback: the caller learns the shape is out of the kernel's
+    reach)."""
+    qlp, qrp = _round_up(qL, 8), _round_up(qR, 128)
+    S = int(n_segments)
+    seg_tile = 1 if S == 1 else max(1, min(S, TILE_ROWS // qlp))
+    if block_n is None:
+        bn = MAX_BLOCK_N
+        while bn > 8 and vmem_bytes(bn, row_widths, seg_tile, qlp, qrp,
+                                    S > 1) > VMEM_BUDGET:
+            bn //= 2
+        need = vmem_bytes(bn, row_widths, seg_tile, qlp, qrp, S > 1)
+        if need > VMEM_BUDGET:
+            raise ValueError(
+                f"seg_gram: (S={S}, qL={qL}, qR={qR}) needs {need} bytes "
+                f"of VMEM at block_n=8, over the {VMEM_BUDGET}-byte budget")
+    else:
+        bn = int(block_n)
+    # one block covering every row takes the array's own height; else
+    # a block height within a factor 2 of the planned one that divides
+    # n needs no padded tail (no padded copy of the inputs), and failing
+    # that the blocks are balanced so the tail stays under 8 rows a block
+    nb = max(1, -(-n // bn))
+    if nb == 1:
+        return n, seg_tile, qlp, qrp
+    div = next((b for b in range(bn - bn % 8, bn // 2, -8) if n % b == 0), 0)
+    bn = div or _round_up(-(-n // nb), 8)
+    return bn, seg_tile, qlp, qrp
+
 
 def _pad_rows(a: Array, pad: int, value) -> Array:
+    if pad == 0:
+        return a
     return jnp.pad(a, ((0, pad), (0, 0)), constant_values=value)
 
 
@@ -61,85 +146,116 @@ def seg_gram_pallas(
     seg: Optional[Array] = None,
     w: Optional[Array] = None,
     n_segments: int = 1,
-    block_n: int = 512,
-    interpret: Optional[bool] = None,
+    interpret: bool,
+    block_n: Optional[int] = None,
 ) -> Array:
     """Fused segmented Gram.  ``arrays``: 2-D fp32 inputs, row-shaped
     (n, d) or broadcast (1, d); ``seg``: (n, 1) int32 ids in
-    [0, n_segments); ``w``: (n, 1) row weights (default ones).  Returns
-    (qL, qR) when n_segments == 1, else (n_segments, qL, qR), fp32."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    [0, n_segments); ``w``: (n, 1) row weights (default: none, every
+    row weighs 1).  Returns (qL, qR) when n_segments == 1, else
+    (n_segments, qL, qR), fp32.
+
+    ``interpret`` is explicit: False compiles with Mosaic (TPU only),
+    True runs the same grid in interpret mode.  ``block_n`` overrides
+    the VMEM-planned row block (tests use small blocks to exercise
+    multi-block grids at small n)."""
     S = int(n_segments)
     rows = [a for a in arrays if a.shape[0] != 1]
     n = rows[0].shape[0]
-    bn = min(int(block_n), n)
     qL, qR = jax.eval_shape(
         builder,
         *[
-            jax.ShapeDtypeStruct(
-                (a.shape[0] if a.shape[0] == 1 else bn,) + a.shape[1:],
-                a.dtype,
-            )
+            jax.ShapeDtypeStruct((1 if a.shape[0] == 1 else 8,) + a.shape[1:],
+                                 a.dtype)
             for a in arrays
         ],
     )
     qL, qR = qL.shape[1], qR.shape[1]
-    # mosaic wants (sublane, lane) = (8, 128) fp32 output tiles; padded
-    # columns are exact zeros and are sliced off below
-    pad_l = 0 if interpret else (-qL) % 8
-    pad_r = 0 if interpret else (-qR) % 128
-    qlp, qrp = qL + pad_l, qR + pad_r
+
+    # Narrow row inputs (fewer than LANES columns), the weights and the
+    # segment ids travel as ONE packed (n, m) operand, sliced apart per
+    # block in VMEM: HBM tiles pad an array's minor dim to 128 lanes, so
+    # every separate (n, 1) column would occupy n * 512 bytes.
+    slots, operands, cols = [], [], []  # slot: (operand index) or (offset, width)
+    for a in arrays:
+        if a.shape[0] == 1 or a.shape[1] >= LANES:
+            slots.append((len(operands),))
+            operands.append(a)
+        else:
+            slots.append((sum(c.shape[1] for c in cols), a.shape[1]))
+            cols.append(a.astype(jnp.float32))
+    m = sum(c.shape[1] for c in cols)
+    w_at = seg_at = None
+    if w is not None:
+        w_at, m = m, m + 1
+        cols.append(w.astype(jnp.float32))
+    if S > 1:
+        seg_at, m = m, m + 1
+        cols.append(seg.astype(jnp.float32))  # exact: ids < 2**24
+
+    row_widths = [a.shape[1] for a in operands if a.shape[0] != 1]
+    row_widths += [m] if m else []
+    bn, st, qlp, qrp = plan(n, row_widths, S, qL, qR, block_n=block_n)
+    pad_l, pad_r = qlp - qL, qrp - qR
+    n_tiles = -(-S // st)
 
     pad = (-n) % bn
-    if w is None:
-        w = jnp.ones((n, 1), jnp.float32)
-    if pad:
-        arrays = [a if a.shape[0] == 1 else _pad_rows(a, pad, 0) for a in arrays]
-        w = _pad_rows(w, pad, 0)
-        if seg is not None:
-            seg = _pad_rows(seg, pad, -1)
+    operands = [a if a.shape[0] == 1 else _pad_rows(a, pad, 0) for a in operands]
+    if m:
+        pad_values = [0.0] * len(cols)
+        if seg_at is not None:
+            pad_values[-1] = -1.0  # matches no segment lane
+        operands.append(jnp.concatenate(
+            [_pad_rows(c, pad, v) for c, v in zip(cols, pad_values)], axis=1))
     nb = (n + pad) // bn
 
     def _spec(a: Array) -> pl.BlockSpec:
         if a.shape[0] == 1:
-            return pl.BlockSpec((1, a.shape[1]), lambda i: (0, 0))
-        return pl.BlockSpec((bn, a.shape[1]), lambda i: (i, 0))
-
-    inputs = list(arrays) + ([seg] if S > 1 else []) + [w]
+            return pl.BlockSpec((1, a.shape[1]), lambda j, i: (0, 0))
+        return pl.BlockSpec((bn, a.shape[1]), lambda j, i: (i, 0))
 
     def kern(*refs):
-        *data_refs, w_ref, g_ref = refs
-        if S > 1:
-            *data_refs, seg_ref = data_refs
-        i = pl.program_id(0)
+        *in_refs, g_ref = refs
+        i = pl.program_id(1)
 
         @pl.when(i == 0)
         def _init():
             g_ref[...] = jnp.zeros_like(g_ref)
 
-        L, R = builder(*[r[...] for r in data_refs])
+        P = in_refs[-1][...] if m else None  # (bn, m) packed columns
+
+        def col(at, width=1):
+            return P[:, at:at + width]
+
+        L, R = builder(*[in_refs[s[0]][...] if len(s) == 1 else col(*s)
+                         for s in slots])
         L = _pad_cols(L, pad_l)
         R = _pad_cols(R, pad_r)
-        wb = w_ref[...]  # (bn, 1)
         if S == 1:
-            g_ref[...] += (L * wb).T @ R
+            T = L if w_at is None else L * col(w_at)
         else:
-            ids = seg_ref[...]  # (bn, 1) int32
-            iota = lax.broadcasted_iota(jnp.int32, (ids.shape[0], S), 1)
-            oh = jnp.where(ids == iota, wb, 0.0)  # (bn, S)
-            T = (oh[:, :, None] * L[:, None, :]).reshape(ids.shape[0], S * qlp)
-            g_ref[...] += T.T @ R
+            # ids relative to this tile: segments outside it (and the
+            # -1 pad id) match no lane and zero their rows
+            ids = col(seg_at).astype(jnp.int32) - pl.program_id(0) * st
+            hit = ids == lax.broadcasted_iota(jnp.int32, (bn, st), 1)
+            oh = (hit.astype(jnp.float32) if w_at is None
+                  else jnp.where(hit, col(w_at), 0.0))  # (bn, st)
+            T = (oh[:, :, None] * L[:, None, :]).reshape(bn, st * qlp)
+        g_ref[...] += lax.dot_general(
+            T, R, (((0,), (0,)), ((), ())), precision=PRECISION,
+            preferred_element_type=jnp.float32)
 
-    out_rows = qlp if S == 1 else S * qlp
     g = pl.pallas_call(
         kern,
-        grid=(nb,),
-        in_specs=[_spec(a) for a in inputs],
-        out_specs=pl.BlockSpec((out_rows, qrp), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((out_rows, qrp), jnp.float32),
+        grid=(n_tiles, nb),
+        in_specs=[_spec(a) for a in operands],
+        out_specs=pl.BlockSpec((st * qlp, qrp), lambda j, i: (j, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_tiles * st * qlp, qrp), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
-    )(*inputs)
+    )(*operands)
     if S == 1:
         return g[:qL, :qR]
-    return g.reshape(S, qlp, qrp)[:, :qL, :qR]
+    return g.reshape(n_tiles * st, qlp, qrp)[:S, :qL, :qR]

@@ -3,9 +3,10 @@
 ``repro.core.moments`` routes ``row_block_strategy="pallas"`` here.
 Three lowerings of the same builder vocabulary (ref.py):
 
-  "pallas"    the Pallas kernel (kernel.py): compiled mosaic on TPU,
-              interpret mode elsewhere — ONE fused HBM pass.
-  "interpret" the Pallas kernel forced into interpret mode — the CPU
+  "pallas"    the Pallas kernel (kernel.py) compiled with Mosaic — ONE
+              fused HBM pass.  TPU only: asking for it on another
+              backend raises rather than quietly interpreting.
+  "interpret" the same kernel grid in interpret mode — the CPU
               certification target (same block decomposition and
               accumulation order as the compiled kernel).
   "scatter"   pure-XLA fast lowering for hosts without a mosaic
@@ -19,6 +20,12 @@ Three lowerings of the same builder vocabulary (ref.py):
 ``default_backend()`` picks "pallas" on TPU and "scatter" elsewhere;
 ``force_backend("interpret")`` pins the kernel path for parity tests
 (the conformance suite certifies chunked = pallas estimator-wide).
+
+Every dispatch is counted on ``obs.metrics.default_registry()`` at
+trace time: ``seg_gram.lowering[<name>]`` names the lowering that ran,
+and an active data mesh, which replaces the kernel with the sharded
+scatter lowering, also counts ``seg_gram.fallback[data_mesh:<builder>]``
+so the substitution is never silent.
 
 Contract: all lowerings share the padding rules of the moments engine
 (zero data rows, seg = -1 — ``segment_sum`` drops negative ids exactly
@@ -39,6 +46,7 @@ from jax import lax
 
 from repro.kernels.seg_gram import kernel as _kernel
 from repro.kernels.seg_gram import ref as _ref
+from repro.obs.metrics import default_registry
 
 Array = jax.Array
 _F32 = jnp.float32
@@ -209,8 +217,9 @@ def seg_reduce(
     init: Optional[Array] = None,
 ) -> Array:
     """The one entry point: dispatch ``G[s] = sum w_n L_n (x) R_n`` to
-    the selected lowering.  ``row_block`` sets the kernel block size
-    (and bounds the scatter lowering's temporaries).
+    the selected lowering.  ``row_block`` bounds the scatter lowering's
+    temporaries and engages the data mesh; the kernel sizes its own
+    tiles from VMEM (kernel.plan).
 
     ``init`` seeds the accumulator (incremental ingest): the blocked
     scatter lowering threads it as the scan seed — bitwise the one-shot
@@ -225,14 +234,27 @@ def seg_reduce(
         seg = seg.astype(jnp.int32)
         seg = seg[:, None] if seg.ndim == 1 else seg
     n = max(a.shape[0] for a in arrays)
+    reg = default_registry()
     if be != "ref" and 0 < row_block < n:
         dm = _active_data_mesh()
         if dm is not None:
             # an active data mesh overrides the single-host lowerings
             # on the blocked path ("ref" stays the unsharded oracle)
+            if be != "scatter":
+                reg.counter(
+                    f"seg_gram.fallback[data_mesh:{builder.__name__}]").inc()
+            reg.counter("seg_gram.lowering[scatter_dist]").inc()
             return _scatter_dist(
                 builder, arrays, seg, w, n_segments, row_block, init, dm
             )
+    if be not in ("ref", "scatter", "pallas", "interpret"):
+        raise ValueError(f"unknown seg_gram backend {be!r}")
+    if be == "pallas" and jax.default_backend() != "tpu":
+        raise RuntimeError(
+            "seg_gram: the 'pallas' lowering compiles with Mosaic and runs "
+            f"on TPU only (the backend is {jax.default_backend()!r}); use "
+            "'interpret' to run the kernel in interpret mode, or 'scatter'")
+    reg.counter(f"seg_gram.lowering[{be}]").inc()
     if be == "ref":
         G = _ref.seg_gram_ref(
             builder, arrays, seg=seg, w=w, n_segments=n_segments
@@ -242,18 +264,13 @@ def seg_reduce(
         return _scatter(
             builder, arrays, seg, w, n_segments, row_block, init=init
         )
-    if be not in ("pallas", "interpret"):
-        raise ValueError(f"unknown seg_gram backend {be!r}")
-    interpret = True if be == "interpret" else None
-    bn = row_block if 0 < row_block else 512
     G = _kernel.seg_gram_pallas(
         builder,
         arrays,
         seg=seg,
         w=w,
         n_segments=n_segments,
-        block_n=bn,
-        interpret=interpret,
+        interpret=be == "interpret",
     )
     return G if init is None else init + G
 
@@ -278,27 +295,33 @@ def segment_counts(
 # ---------------------------------------------------------------------------
 
 
+def _parts(D) -> List[Array]:
+    """A design given whole or as its column parts ``[X, 1, y]``."""
+    return list(D) if isinstance(D, (list, tuple)) else [D]
+
+
 def design_gram(
-    D: Array, *, w: Optional[Array] = None, row_block: int = 0, backend: str = ""
+    D, *, w: Optional[Array] = None, row_block: int = 0, backend: str = ""
 ) -> Array:
-    """(q, q) weighted Gram over a pre-assembled design."""
+    """(q, q) weighted Gram over a design (whole or column parts)."""
     return seg_reduce(
-        _ref.build_design, [D], w=w, row_block=row_block, backend=backend
+        _ref.build_design, _parts(D), w=w, row_block=row_block, backend=backend
     )
 
 
 def fold_design_gram(
-    D: Array,
+    D,
     folds: Array,
     k: int,
     *,
     row_block: int = 0,
     backend: str = "",
 ) -> Tuple[Array, Array]:
-    """(k, q, q) fold-segmented Gram + per-fold counts."""
+    """(k, q, q) fold-segmented Gram + per-fold counts, over a design
+    whole or as column parts."""
     G = seg_reduce(
         _ref.build_design,
-        [D],
+        _parts(D),
         seg=folds,
         n_segments=k,
         row_block=row_block,
@@ -308,16 +331,18 @@ def fold_design_gram(
 
 
 def fold_weighted_design_gram(
-    D: Array, Wk: Array, *, row_block: int = 0, backend: str = ""
+    D, Wk: Array, *, row_block: int = 0, backend: str = ""
 ) -> Array:
     """(k, q, q) dense-weight fold Gram ``G[k] = Σ_n Wk[k, n] d_n d_nᵀ``
     — the ``ni,kn,nj->kij`` form fused as one kernel pass (the kron
     builder widens L to k·q columns; n_eff stays outside, computed as a
-    plain strategy-independent sum by moments.fold_weighted_gram)."""
-    k, q = Wk.shape[0], D.shape[1]
+    plain strategy-independent sum by moments.fold_weighted_gram).  The
+    design comes whole or as column parts."""
+    parts = _parts(D)
+    k, q = Wk.shape[0], sum(a.shape[1] for a in parts)
     G = seg_reduce(
         _ref.build_fold_weighted,
-        [Wk.T, D],
+        [Wk.T] + parts,
         row_block=row_block,
         backend=backend,
     )

@@ -21,7 +21,7 @@ in every accumulator).
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -35,8 +35,15 @@ def build_pair(U: Array, V: Array) -> Pair:
     return U, V
 
 
-def build_design(D: Array) -> Pair:
-    """Symmetric Gram over a pre-assembled design ``[X | 1? | y?]``."""
+def _cat(parts: Sequence[Array]) -> Array:
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
+
+
+def build_design(*D: Array) -> Pair:
+    """Symmetric Gram over a design ``[X | 1? | y?]``, whole or as its
+    column parts (assembled per row block, so the kernel path never
+    copies X into an (n, q) design)."""
+    D = _cat(D)
     return D, D
 
 
@@ -60,11 +67,13 @@ def build_iv(ry: Array, rt: Array, rz: Array, phi: Array) -> Pair:
     return M, M
 
 
-def build_fold_weighted(Wt: Array, D: Array) -> Pair:
+def build_fold_weighted(Wt: Array, *D: Array) -> Pair:
     """Dense per-fold weight matrix (moments.fold_weighted_gram):
-    L_n = Wt_n ⊗ d_n (the k per-fold weights kron the design row), so
-    G = L^T R reshapes to the (k, q, q) stack Σ_n Wk[k, n] d_n d_nᵀ.
-    Zero rows give zero L/R rows (both factors vanish)."""
+    L_n = Wt_n ⊗ d_n (the k per-fold weights kron the design row, the
+    design whole or as its column parts), so G = L^T R reshapes to the
+    (k, q, q) stack Σ_n Wk[k, n] d_n d_nᵀ.  Zero rows give zero L/R
+    rows (both factors vanish)."""
+    D = _cat(D)
     r = Wt.shape[0]
     L = (Wt[:, :, None] * D[:, None, :]).reshape(r, Wt.shape[1] * D.shape[1])
     return L, D

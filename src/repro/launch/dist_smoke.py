@@ -1,18 +1,19 @@
 """Two-process ``jax.distributed`` smoke test for the data mesh.
 
-Launches N worker processes (default 2) on localhost, each with its own
-forced CPU device count, initializes ``jax.distributed`` against a
-local coordinator, builds a ``("hosts", "devices")`` data mesh spanning
-every process, and runs one ``dist_reduce`` weighted-Gram pass in
-"psum" mode, checking the result against a local numpy reference.
+Launches N worker processes (default 2) on localhost, each pinned to
+the CPU (``JAX_PLATFORMS=cpu``) with its own forced device count,
+initializes ``jax.distributed`` against a local coordinator, builds a
+``("hosts", "devices")`` data mesh spanning every process, and runs one
+``dist_reduce`` weighted-Gram pass in "psum" mode, checking the result
+against a local numpy reference.
 
-Best-effort by design: multi-process CPU collectives are not supported
-on every jax build, so anything short of an explicit identity FAILURE
-reports SKIP and exits 0 — CI treats SKIP as success-with-a-note.  The
-bitwise "ordered" certificate is carried by the single-process forced-
-8-device suite (tests/test_distributed_runtime.py); this script only
-establishes that the same entry points run under a real multi-process
-``jax.distributed`` runtime when the platform allows it.
+A CPU multi-process rehearsal: the workers never touch an accelerator,
+so the script is safe on a TPU host (where a child reaching for the
+chip would fight its holder).  Anything but a matching result — a
+wrong answer, a worker error, a timeout — is a FAIL with a non-zero
+exit.  The bitwise "ordered" certificate is carried by the
+single-process forced-8-device suite (tests/test_distributed_runtime.py);
+the real multi-chip path is ``chip_smoke.py --chips 4``.
 
 Usage:  python -m repro.launch.dist_smoke [--nprocs 2]
 """
@@ -67,11 +68,12 @@ def _free_port() -> int:
 
 def run_smoke(nprocs: int = 2, devices_per_proc: int = 2,
               timeout: float = 120.0) -> str:
-    """Spawn the workers; returns "OK", "SKIP: <why>", or "FAIL"."""
+    """Spawn the workers; returns "OK" or "FAIL: <why>"."""
     port = _free_port()
     src = os.path.dirname(os.path.dirname(
         os.path.dirname(os.path.abspath(__file__))))
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={devices_per_proc}")
     env["PYTHONPATH"] = os.pathsep.join(
@@ -93,14 +95,15 @@ def run_smoke(nprocs: int = 2, devices_per_proc: int = 2,
     except subprocess.TimeoutExpired:
         for pr in procs:
             pr.kill()
-        return "SKIP: timeout (multi-process collectives unsupported?)"
+            pr.communicate()
+        return f"FAIL: workers timed out after {timeout:.0f}s"
     combined = "\n".join(outs)
     if FAIL_MARKER in combined:
-        return "FAIL"
+        return "FAIL: result diverged from the numpy reference"
     if OK_MARKER in combined and all(pr.returncode == 0 for pr in procs):
         return "OK"
     tail = combined.strip().splitlines()[-1] if combined.strip() else "no output"
-    return f"SKIP: workers did not converge ({tail[:120]})"
+    return f"FAIL: workers did not complete ({tail[:200]})"
 
 
 def main(argv=None) -> int:
@@ -117,7 +120,7 @@ def main(argv=None) -> int:
                         devices_per_proc=args.devices_per_proc,
                         timeout=args.timeout)
     print(f"dist_smoke: {verdict}")
-    return 1 if verdict == "FAIL" else 0
+    return 0 if verdict == "OK" else 1
 
 
 if __name__ == "__main__":

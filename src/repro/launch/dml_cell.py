@@ -136,8 +136,7 @@ def lower_dml_cell(mesh: Mesh, cfg: CausalConfig = None,
     step = make_dml_step(cfg, engine, rules)
     specs = input_specs(n, p)
     sh = row_sharding(mesh)
-    from repro.distributed.sharding import mesh_context
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(
             step,
             in_shardings=(sh["X"], sh["y"], sh["t"], sh["folds"]),
@@ -154,8 +153,7 @@ def lower_iv_cell(mesh: Mesh, cfg: CausalConfig = None,
     step = make_iv_step(cfg, engine, rules)
     specs = input_specs(n, p, with_instrument=True)
     sh = row_sharding(mesh, with_instrument=True)
-    from repro.distributed.sharding import mesh_context
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(
             step,
             in_shardings=(sh["X"], sh["y"], sh["t"], sh["z"],

@@ -31,16 +31,19 @@ import traceback
 from typing import Any, Dict, Optional
 
 import jax
-from repro.distributed.sharding import mesh_context
 
 from repro.config import SHAPES, TrainConfig
 from repro.configs import ARCH_IDS
 from repro.launch.cells import Cell, cell_input_shardings, make_cell
 from repro.launch.mesh import make_production_mesh
 from repro.launch import hlo_cost
-from repro.launch.roofline import Roofline, model_flops_for
+from repro.launch.roofline import Roofline, model_flops_for, peaks_for
 from repro.launch.train import make_train_step
 from repro.optim.adamw import AdamWState, adamw_init
+
+
+# the chip the production meshes (launch/mesh.py) are laid out for
+TARGET_DEVICE_KIND = "TPU v5 lite"
 
 
 def _abstract_opt(model, params_abs) -> AdamWState:
@@ -65,7 +68,7 @@ def lower_cell(cell: Cell, mesh, tcfg: Optional[TrainConfig] = None):
         opt_abs = _abstract_opt(model, params_abs)
         opt_sh = _opt_shardings(param_sh, mesh)
         step = make_train_step(model, tcfg)
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(
                 step,
                 in_shardings=(param_sh, opt_sh, input_sh),
@@ -77,7 +80,7 @@ def lower_cell(cell: Cell, mesh, tcfg: Optional[TrainConfig] = None):
         def prefill(params, batch):
             return model.prefill(params, batch)
 
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(
                 prefill, in_shardings=(param_sh, input_sh),
             ).lower(params_abs, inputs)
@@ -87,7 +90,7 @@ def lower_cell(cell: Cell, mesh, tcfg: Optional[TrainConfig] = None):
     def serve_step(params, tokens, cache, pos):
         return model.decode_step(params, tokens, cache, pos)
 
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(
             serve_step,
             in_shardings=(param_sh, input_sh["tokens"], input_sh["cache"],
@@ -130,6 +133,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
         wire_bytes=hc.wire_bytes,
         model_flops=model_flops_for(cell.cfg, cell.shape),
         chips=rec["chips"],
+        peaks=peaks_for(TARGET_DEVICE_KIND),
     )
     mem = {}
     if ma is not None:
@@ -192,7 +196,7 @@ def run_dml_cell(*, multi_pod: bool, verbose: bool = True,
     model_fl = 2.0 * 5 * nn * pp * pp * (1 + 16) / 4  # rough; see roofline
     rl = Roofline(flops=hc.flops, hbm_bytes=hc.bytes,
                   wire_bytes=hc.wire_bytes, model_flops=model_fl,
-                  chips=rec["chips"])
+                  chips=rec["chips"], peaks=peaks_for(TARGET_DEVICE_KIND))
     mem = {}
     if ma is not None:
         mem = {"argument_bytes": int(ma.argument_size_in_bytes),

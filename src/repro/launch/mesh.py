@@ -15,16 +15,8 @@ MULTI_POD = (2, 16, 16)                # 2 pods = 512 chips
 
 
 def _mk(shape, axes, devices=None):
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    kw = ({"axis_types": (axis_type.Auto,) * len(axes)}
-          if axis_type is not None else {})
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh(shape, axes, devices=devices, **kw)
-    # jax < 0.4.35 fallback
-    import numpy as np
-    devices = devices if devices is not None else jax.devices()
-    return jax.sharding.Mesh(
-        np.asarray(devices)[: int(np.prod(shape))].reshape(shape), axes)
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

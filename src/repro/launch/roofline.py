@@ -17,7 +17,10 @@ sum per-op estimates with ring-algorithm factors (G = group size):
     all-to-all          S·(G-1)/G
     collective-permute  S
 
-Hardware model: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM, 50 GB/s/link.
+Hardware model: ``PEAKS``, published per-chip peaks keyed by the
+``device_kind`` JAX reports.  A kind missing from the table is an
+error (``peaks_for``), never a default: a roofline computed against
+another chip's peaks is a wrong number, not an approximate one.
 """
 from __future__ import annotations
 
@@ -25,9 +28,36 @@ import dataclasses
 import re
 from typing import Dict, List, Tuple
 
-PEAK_FLOPS = 197e12      # bf16 / chip
-HBM_BW = 819e9           # bytes/s / chip
-LINK_BW = 50e9           # bytes/s / ICI link
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    """Published peaks of one chip."""
+
+    flops: float      # dense bf16 FLOP/s
+    hbm_bw: float     # HBM bytes/s
+    link_bw: float    # bytes/s per chip-to-chip link
+    source: str
+
+
+PEAKS: Dict[str, ChipPeaks] = {
+    # 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of ICI
+    # over four links (= 50 GB/s each)
+    "TPU v5 lite": ChipPeaks(
+        flops=197e12, hbm_bw=819e9, link_bw=50e9,
+        source="Google Cloud documentation, 'TPU v5e'"),
+}
+
+
+def peaks_for(device_kind: str) -> ChipPeaks:
+    """The published peaks of ``device_kind`` (``jax.Device.device_kind``);
+    raises KeyError for a kind with no entry in ``PEAKS``."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for device kind {device_kind!r}; add an "
+            f"entry with its source to repro.launch.roofline.PEAKS "
+            f"(known: {sorted(PEAKS)})") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2, "bf16": 2,
@@ -131,18 +161,19 @@ class Roofline:
     wire_bytes: float       # per device
     model_flops: float      # analytic 6ND/2ND (global)
     chips: int
+    peaks: ChipPeaks        # the chip the program was compiled for
 
     @property
     def t_compute(self) -> float:
-        return self.flops / PEAK_FLOPS
+        return self.flops / self.peaks.flops
 
     @property
     def t_memory(self) -> float:
-        return self.hbm_bytes / HBM_BW
+        return self.hbm_bytes / self.peaks.hbm_bw
 
     @property
     def t_collective(self) -> float:
-        return self.wire_bytes / LINK_BW
+        return self.wire_bytes / self.peaks.link_bw
 
     @property
     def bottleneck(self) -> str:
@@ -169,7 +200,7 @@ class Roofline:
         t = self.step_time
         if t <= 0:
             return 0.0
-        return self.model_flops / (self.chips * t) / PEAK_FLOPS
+        return self.model_flops / (self.chips * t) / self.peaks.flops
 
     def row(self) -> Dict[str, float]:
         return {

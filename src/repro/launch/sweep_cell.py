@@ -100,8 +100,7 @@ def lower_sweep_cell(mesh: Mesh, cfg: CausalConfig = None,
     step = make_sweep_step(cfg, n_segments, mode)
     specs = input_specs(n, p)
     sh = row_sharding(mesh)
-    from repro.distributed.sharding import mesh_context
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(
             step,
             in_shardings=(sh["X"], sh["y"], sh["t"], sh["sids"]),

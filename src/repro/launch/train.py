@@ -27,7 +27,7 @@ from repro.configs import get_config
 from repro.data.lm_data import bigram_ce_floor, lm_batch
 from repro.data.pipeline import ShardedFeed, batch_sharding
 from repro.launch.mesh import make_host_mesh
-from repro.distributed.sharding import mesh_context, default_rules
+from repro.distributed.sharding import default_rules
 from repro.models.model import Model, build_model
 from repro.optim.adamw import AdamWState, adamw_init, adamw_update
 from repro.optim.compression import compress_decompress
@@ -183,7 +183,7 @@ def main(argv=None):
     manager = (CheckpointManager(args.ckpt_dir) if args.ckpt_dir else None)
     print(f"training {args.arch}: vocab {cfg.vocab_size}, "
           f"CE floor ≈ {bigram_ce_floor(cfg.vocab_size):.3f} nats")
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         train_loop(model, tcfg, feed, manager=manager,
                    ckpt_every=args.ckpt_every)
     feed.close()

@@ -2,14 +2,14 @@
 model that sizes chunks.
 
 ``runtime.memory`` fits an affine peak-bytes model from two compile-only
-probes (c=1 and c=8) and the scheduler trusts the interpolation to pick
+probes (c=1 and c=2) and the scheduler trusts the interpolation to pick
 chunk sizes — but until now nothing ever checked the model against the
 chunks that actually ran.  The audit joins every traced chunk to two
 ground truths:
 
   peak_ratio   affine-model predicted peak bytes at the chunk's actual
-               size vs the exact ``hlo_cost.peak_temp_bytes`` of the
-               compiled program AT that size — how good the two-probe
+               size vs the probed temporary bytes of the compiled
+               program AT that size — how good the two-probe
                interpolation is where the scheduler used it (1.0 =
                perfect; the acceptance bar is *finite*, the report makes
                drift visible);
@@ -19,17 +19,19 @@ ground truths:
                compiled HLO — the fraction-of-roofline lens the serving
                layer's latency SLOs will inherit.
 
-Hardware constants default to ``launch.roofline``'s TPU-v5e model;
-pass CPU-calibrated numbers for host-only runs (the ratios stay
-comparable across PRs either way — same constants, same shapes).
+The roofline needs the peaks of the chip the chunks ran on: pass
+``peaks=launch.roofline.peaks_for(device.device_kind)``.  Without
+peaks (a CPU run, or a chip with no published entry) the audit reports
+the memory side only — a time ratio against another chip's peaks would
+be a wrong number, so it is left out rather than defaulted.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List
+from typing import Dict, List, Optional
 
-from repro.launch.roofline import HBM_BW, PEAK_FLOPS
+from repro.launch.roofline import ChipPeaks
 
 _EPS = 1e-12
 
@@ -42,7 +44,7 @@ class ChunkAudit:
     chunk_index: int
     chunk_size: int
     predicted_peak_bytes: float  # affine memory model at chunk_size
-    probed_peak_bytes: float  # exact HLO peak at chunk_size
+    probed_peak_bytes: float  # probed temp bytes at chunk_size
     flops: float  # hlo_cost.analyze roofline FLOPs
     hbm_bytes: float  # hlo_cost.analyze HBM traffic
     measured_s: float  # span duration (block_until_ready honest)
@@ -54,24 +56,22 @@ class ChunkAudit:
             self.probed_peak_bytes, _EPS
         )
 
-    def roofline_s(self, peak_flops: float = PEAK_FLOPS, hbm_bw: float = HBM_BW):
+    def roofline_s(self, peaks: ChipPeaks) -> float:
         """Roofline lower bound for one execution of the chunk program."""
-        return max(self.flops / peak_flops, self.hbm_bytes / hbm_bw)
+        return max(self.flops / peaks.flops, self.hbm_bytes / peaks.hbm_bw)
 
-    def time_ratio(self, peak_flops: float = PEAK_FLOPS, hbm_bw: float = HBM_BW):
+    def time_ratio(self, peaks: ChipPeaks) -> float:
         """Measured / roofline seconds (>= ~1 when the model is sane)."""
-        return max(self.measured_s, _EPS) / max(
-            self.roofline_s(peak_flops, hbm_bw), _EPS
-        )
+        return max(self.measured_s, _EPS) / max(self.roofline_s(peaks), _EPS)
 
 
 class CostAudit:
     """Accumulates :class:`ChunkAudit` rows across a traced run and
-    renders them as a table / bench-JSON summary."""
+    renders them as a table / bench-JSON summary.  ``peaks`` (the
+    chip's published peaks) enables the time side of the audit."""
 
-    def __init__(self, peak_flops: float = PEAK_FLOPS, hbm_bw: float = HBM_BW):
-        self.peak_flops = float(peak_flops)
-        self.hbm_bw = float(hbm_bw)
+    def __init__(self, peaks: Optional[ChipPeaks] = None):
+        self.peaks = peaks
         self.rows: List[ChunkAudit] = []
 
     def record(self, row: ChunkAudit) -> None:
@@ -81,8 +81,9 @@ class CostAudit:
         return len(self.rows)
 
     def as_dicts(self) -> List[Dict]:
-        return [
-            {
+        rows = []
+        for r in self.rows:
+            d = {
                 "label": r.label,
                 "chunk_index": r.chunk_index,
                 "chunk_size": r.chunk_size,
@@ -92,27 +93,30 @@ class CostAudit:
                 "flops": r.flops,
                 "hbm_bytes": r.hbm_bytes,
                 "measured_s": r.measured_s,
-                "roofline_s": r.roofline_s(self.peak_flops, self.hbm_bw),
-                "time_ratio": r.time_ratio(self.peak_flops, self.hbm_bw),
             }
-            for r in self.rows
-        ]
+            if self.peaks is not None:
+                d["roofline_s"] = r.roofline_s(self.peaks)
+                d["time_ratio"] = r.time_ratio(self.peaks)
+            rows.append(d)
+        return rows
 
     def summary(self) -> Dict:
         """Rollup for BENCH_results.json's ``obs.audit`` section."""
         if not self.rows:
             return {"n_chunks": 0}
         pr = [r.peak_ratio for r in self.rows]
-        tr = [r.time_ratio(self.peak_flops, self.hbm_bw) for r in self.rows]
-        return {
+        out = {
             "n_chunks": len(self.rows),
             "labels": sorted({r.label for r in self.rows}),
             "peak_ratio_min": min(pr),
             "peak_ratio_max": max(pr),
             "peak_ratio_mean": sum(pr) / len(pr),
-            "time_ratio_min": min(tr),
-            "time_ratio_max": max(tr),
         }
+        if self.peaks is not None:
+            tr = [r.time_ratio(self.peaks) for r in self.rows]
+            out["time_ratio_min"] = min(tr)
+            out["time_ratio_max"] = max(tr)
+        return out
 
     def table(self) -> str:
         """Human-readable audit: one line per chunk, predicted vs
@@ -123,10 +127,11 @@ class CostAudit:
         )
         lines = [head, "-" * len(head)]
         for r in self.rows:
+            tx = ("not measured" if self.peaks is None
+                  else f"{r.time_ratio(self.peaks):.1f}")
             lines.append(
                 f"{r.label[:24]:<24} {r.chunk_index:>3} {r.chunk_size:>5} "
                 f"{r.predicted_peak_bytes:>10.0f} {r.probed_peak_bytes:>10.0f} "
-                f"{r.peak_ratio:>6.2f} {r.measured_s * 1e3:>8.2f} "
-                f"{r.time_ratio(self.peak_flops, self.hbm_bw):>9.1f}"
+                f"{r.peak_ratio:>6.2f} {r.measured_s * 1e3:>8.2f} {tx:>9}"
             )
         return "\n".join(lines)

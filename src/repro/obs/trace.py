@@ -77,16 +77,19 @@ class Tracer:
     ``sync=True`` (default) forces ``jax.block_until_ready`` at
     :meth:`sync` call sites so span durations are honest; set False to
     trace pure scheduling overhead without forcing device work.
+    ``peaks`` (``launch.roofline.peaks_for(device_kind)``) turns on the
+    audit's roofline time ratios.
     """
 
-    def __init__(self, *, sync: bool = True, clock=time.perf_counter_ns):
+    def __init__(self, *, sync: bool = True, clock=time.perf_counter_ns,
+                 peaks=None):
         self._clock = clock
         self.sync_enabled = bool(sync)
         self.spans: List[Span] = []  # in open order; closed in place
         self._stack: List[Span] = []
         self._next_id = 0
         self.metrics = MetricsRegistry()
-        self.audit = CostAudit()
+        self.audit = CostAudit(peaks=peaks)
 
     # -- recording ------------------------------------------------------
     @contextlib.contextmanager

@@ -175,20 +175,9 @@ def _maybe_fail() -> None:
             "injected shard failure (inject_shard_failure)")
 
 
-# -- shard_map compat (jax.shard_map landed post-0.4; the experimental ------
-# -- import is the 0.4.x spelling) ------------------------------------------
-
 def _smap(f, mesh, in_specs, out_specs):
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        try:
-            return sm(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_vma=False)
-        except TypeError:
-            return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def dist_reduce(block_fn: Callable[..., Any], arrays: Sequence[Array], *,

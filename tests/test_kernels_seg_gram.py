@@ -377,3 +377,54 @@ def test_builder_zero_rows_are_zero():
         L, R = builder(*args)
         assert np.all(np.asarray(L) == 0.0), builder.__name__
         assert np.all(np.asarray(R) == 0.0), builder.__name__
+
+
+# ---------------------------------------------------------------------------
+# Tiling: the VMEM-planned kernel grid (row blocks x segment tiles) in
+# interpret mode against the one-hot oracle, and the explicit lowering.
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("n,S,q,block_n", [
+    (333, 1, 5, 16),      # many row blocks + padded tail, S = 1
+    (333, 5, 6, 64),      # fold-sized S, one segment tile
+    (500, 200, 8, 96),    # S * qL > TILE_ROWS: two segment tiles
+    (257, 130, 3, None),  # planned block, ragged last segment tile
+])
+def test_kernel_tiling_matches_ref(n, S, q, block_n):
+    from repro.kernels.seg_gram import kernel as sg_kernel
+
+    ks = jax.random.split(jax.random.PRNGKey(3), 3)
+    U = jax.random.normal(ks[0], (n, q))
+    w = jax.random.exponential(ks[1], (n, 1))
+    seg = jax.random.randint(ks[2], (n, 1), 0, S)
+    got = sg_kernel.seg_gram_pallas(
+        sg_ref.build_pair, [U, U], seg=seg if S > 1 else None, w=w,
+        n_segments=S, interpret=True, block_n=block_n)
+    ref = sg_ref.seg_gram_ref(sg_ref.build_pair, [U, U],
+                              seg=seg if S > 1 else None, w=w, n_segments=S)
+    _close(got, ref, f"n={n} S={S} q={q}")
+
+
+def test_pallas_lowering_refuses_cpu(arrs):
+    """An explicit "pallas" lowering off the TPU raises; only
+    "interpret" interprets."""
+    a = arrs
+    with pytest.raises(RuntimeError, match="TPU only"):
+        sg_ops.design_gram(a["X"], row_block=_RB, backend="pallas")
+    with pytest.raises(RuntimeError, match="TPU only"):
+        rg_ops.residual_gram(a["y"], a["t"], a["my"], a["mt"], a["phi"],
+                             backend="pallas")
+
+
+@pytest.mark.parametrize("n", [1_000_000, 262_144, 1_000_003])
+def test_plan_prefers_a_block_that_divides_n(n):
+    """A block height that divides n leaves no padded tail (no padded
+    copy of the inputs); otherwise the tail stays under 8 rows a block."""
+    from repro.kernels.seg_gram import kernel as sg_kernel
+
+    bn = sg_kernel.plan(n, [7], 1, 8, 128)[0]
+    assert bn % 8 == 0 and bn <= sg_kernel.MAX_BLOCK_N
+    if n % 8 == 0:
+        assert n % bn == 0
+    else:
+        assert (-n) % bn < 8 * (-(-n // bn))

@@ -15,6 +15,7 @@ from repro.core.crossfit import crossfit
 from repro.core.nuisance import make_ridge
 from repro.data.causal_dgp import make_causal_data
 from repro.inference.executor import jit_miss_hook
+from repro.launch.roofline import peaks_for
 from repro.obs import (ChunkAudit, CostAudit, Histogram, MetricsRegistry,
                        Tracer, maybe_span)
 from repro.runtime import EventLog, RuntimeEvent, TaskRuntime, memory_model
@@ -169,16 +170,32 @@ def test_maybe_span_none_is_noop():
 # Cost audit
 # ---------------------------------------------------------------------------
 
+_V5E = peaks_for("TPU v5 lite")
+
+
 def test_audit_ratios_finite_even_on_zero_inputs():
     row = ChunkAudit(label="z", chunk_index=0, chunk_size=1,
                      predicted_peak_bytes=0.0, probed_peak_bytes=0.0,
                      flops=0.0, hbm_bytes=0.0, measured_s=0.0)
     assert np.isfinite(row.peak_ratio)
-    assert np.isfinite(row.time_ratio())
+    assert np.isfinite(row.time_ratio(_V5E))
+
+
+def test_peaks_unknown_kind_is_an_error():
+    with pytest.raises(KeyError, match="no published peaks"):
+        peaks_for("cpu")
+    # no peaks: the audit keeps its memory side and leaves time out
+    audit = CostAudit()
+    audit.record(ChunkAudit(label="cpu", chunk_index=0, chunk_size=1,
+                            predicted_peak_bytes=1.0, probed_peak_bytes=1.0,
+                            flops=1.0, hbm_bytes=1.0, measured_s=1.0))
+    assert "time_ratio" not in audit.as_dicts()[0]
+    assert "time_ratio_min" not in audit.summary()
+    assert "not measured" in audit.table()
 
 
 def test_audit_summary_and_table():
-    audit = CostAudit()
+    audit = CostAudit(peaks=_V5E)
     assert audit.summary() == {"n_chunks": 0}
     audit.record(ChunkAudit(label="boot", chunk_index=0, chunk_size=4,
                             predicted_peak_bytes=1000.0,
@@ -249,7 +266,7 @@ def traced_budget_run():
     base = jnp.zeros((m, m), jnp.float32)
     model = memory_model(_outer, xs, (base,), 16)
     assert model is not None
-    tr = Tracer()
+    tr = Tracer(peaks=_V5E)
     rt = TaskRuntime("vmap", memory_budget=int(model.base + 4 * model.slope),
                      tracer=tr)
     out = rt.map(_outer, xs, base, label="probe")

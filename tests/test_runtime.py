@@ -145,6 +145,29 @@ def test_memory_model_and_budget_chunking():
     assert any(e.action == "chunk" for e in rt.events)
 
 
+def test_probed_chunk_size_is_not_compiled_twice():
+    """A chunk whose size a memory-model probe compiled runs through
+    that program: the vmap executor builds no jit of its own, and the
+    result is bitwise the unchunked map."""
+    from repro.inference.executor import jit_miss_hook
+    m = 64
+
+    def outer(v, base):
+        return jnp.tanh(v[:, None] * v[None, :] + base).sum()
+
+    xs = jnp.ones((16, m), jnp.float32)
+    base = jnp.zeros((m, m), jnp.float32)
+    model = memory_model(outer, xs, (base,), 16)
+    rt = TaskRuntime("vmap", memory_budget=int(model.base + 2.5 * model.slope))
+    assert rt.plan_chunk(outer, xs, (base,), 16)[0] == 2  # a probed size
+    misses = []
+    with jit_miss_hook(misses.append):
+        out = rt.map(outer, xs, base)
+    assert misses == []
+    ref = TaskRuntime("vmap").map(outer, xs, base)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+
+
 def test_max_chunk_floors_at_one():
     model = MemoryModel(base=0.0, slope=1000.0)
     assert model.max_chunk(1, 8) == 1  # one replicate must always run

@@ -1,0 +1,123 @@
+"""Compile rehearsal of the main-path seg_gram kernels for a TPU v5e.
+
+The TPU compiler ships with jaxlib, so a described (not attached)
+``v5e:2x2`` topology lets these tests compile the Mosaic kernel at the
+widths ``chip_smoke.py`` runs — what interpret mode cannot show (VMEM
+limits, tile alignment, device-memory fit) fails here at no chip time.
+Nothing executes.
+
+The topology is described inside a module fixture, never at import:
+only one process may hold the TPU library at a time, and every xdist
+worker imports this file.
+"""
+
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.seg_gram import kernel as sg_kernel
+from repro.kernels.seg_gram import ref as sg_ref
+
+HBM_BYTES = 16e9  # one v5e chip
+N_FIT = 1_000_000  # chip_smoke.py's Fit and Sweep row count
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    # compiles for a described chip cannot be read back from the
+    # persistent cache without one; keep this module off it
+    prior = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        # a topology that cannot be described fails the module: these
+        # tests are the kernels' only compile check before the chip
+        yield topologies.get_topology_desc(platform="tpu",
+                                           topology_name="v5e:2x2")
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prior)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _spec(shape, sharding, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compile(fn, *specs):
+    compiled = jax.jit(fn).lower(*specs).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes)
+    assert total < HBM_BYTES, (total, mem)
+    return compiled
+
+
+def _kernel(builder, n_segments=1):
+    def fn(*arrays):
+        *data, seg = arrays if n_segments > 1 else (*arrays, None)
+        return sg_kernel.seg_gram_pallas(builder, list(data), seg=seg,
+                                         n_segments=n_segments,
+                                         interpret=False)
+    return fn
+
+
+def test_final_stage_residual_s1(one_chip):
+    """The DML final stage at the Fit's n (p_phi = 1)."""
+    cols = [_spec((N_FIT, 1), one_chip) for _ in range(4)]
+    _compile(_kernel(sg_ref.build_residual), *cols,
+             _spec((N_FIT, 1), one_chip))
+
+
+def test_fold_gram_paper_width(one_chip):
+    """fold_gram at the paper's Fig. 6 width: S = K = 5, q = 501."""
+    _compile(_kernel(sg_ref.build_design, 5),
+             _spec((N_FIT, 501), one_chip),
+             _spec((N_FIT, 1), one_chip, jnp.int32))
+
+
+def test_crossfit_design_gram_vmapped_over_folds(one_chip):
+    """The parallel cross-fit: the S = 1 design Gram (q = 502) vmapped
+    over the K = 5 fold-complement weight columns."""
+    def fn(D, W):
+        return jax.vmap(lambda w: sg_kernel.seg_gram_pallas(
+            sg_ref.build_design, [D], w=w, interpret=False))(W)
+    _compile(fn, _spec((N_FIT, 502), one_chip),
+             _spec((5, N_FIT, 1), one_chip))
+
+
+def test_bootstrap_fold_weighted_kron(one_chip):
+    """The bootstrap replicate's fold-weighted Gram: the kron builder
+    widens L to K·q = 2510 columns (q = 502)."""
+    _compile(_kernel(sg_ref.build_fold_weighted),
+             _spec((N_FIT, 5), one_chip), _spec((N_FIT, 502), one_chip))
+
+
+@pytest.mark.parametrize("qU,qV", [(1, 51), (52, 52), (106, 106)])
+def test_sweep_segment_outer_s320(one_chip, qU, qV):
+    """S = E·K = 320 cells at E = 64, K = 5, p = 50: the sweep's MM
+    gradient segment_outer (qU = 1) and fold Gram (q = 52), and the
+    store's final-stage Gram phi (x) [X | 1 | t | y] (q = 2·53 = 106).
+    Untiled, that last one's (320·112, 128) accumulator needs 40 MB of
+    VMEM even at 8-row blocks; the segment tiles keep it in budget."""
+    _compile(_kernel(sg_ref.build_pair, 320),
+             _spec((N_FIT, qU), one_chip), _spec((N_FIT, qV), one_chip),
+             _spec((N_FIT, 1), one_chip, jnp.int32))
+
+
+def test_iv_gram(one_chip):
+    """The instrumented augmented Gram M = [rz·φ | rt·φ | ry]."""
+    cols = [_spec((N_FIT, 1), one_chip) for _ in range(3)]
+    _compile(_kernel(sg_ref.build_iv), *cols, _spec((N_FIT, 2), one_chip))
