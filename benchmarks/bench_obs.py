@@ -13,7 +13,9 @@ Deliverables (the paper's measurement story, made durable):
     validated by data);
   * an ``obs`` payload (span rollups + audit summary + metrics
     snapshot) that ``benchmarks/run.py`` embeds into
-    ``BENCH_results.json``.
+    ``BENCH_results.json``;
+  * with ``--span-cost``, the host cost of the process tracer: µs per
+    layer span and per compile event through the compile accounting.
 
 Entries are prefixed ``obs_`` — informational, not under the >20%
 bench-regression gate (tracing is instrumentation, not a hot path).
@@ -104,12 +106,40 @@ def run(B: int = 64, n: int = N, p: int = P, k: int = K,
     }
 
 
+def span_cost(reps: int = 20_000, csv=print):
+    """µs per ``layer_span`` on the process tracer (no profiler session
+    active) and per compile event through ``jax.monitoring`` into the
+    compile accounting (a closed span and two counters)."""
+    from repro.obs import layer_span, process_tracer
+    process_tracer()
+    t0 = time.perf_counter()
+    for _ in range(reps):
+        with layer_span(None, "obs.bench_span", label="x", chunk=2):
+            pass
+    span_us = (time.perf_counter() - t0) / reps * 1e6
+    with layer_span(None, "obs.bench_compiles"):
+        t0 = time.perf_counter()
+        for _ in range(reps):
+            jax.monitoring.record_event_duration_secs(
+                "/jax/core/compile/backend_compile_duration", 1e-3,
+                fun_name="f")
+        event_us = (time.perf_counter() - t0) / reps * 1e6
+    csv(f"obs_span_cost_us,{span_us:.3f},reps={reps}")
+    csv(f"obs_compile_event_cost_us,{event_us:.3f},reps={reps}")
+    return span_us, event_us
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--B", type=int, default=64)
     ap.add_argument("--trace", default="BENCH_trace.json",
                     help="Chrome trace output path ('' disables)")
+    ap.add_argument("--span-cost", action="store_true",
+                    help="measure the process tracer's host cost only")
     args = ap.parse_args(argv)
+    if args.span_cost:
+        span_cost()
+        return
     payload = run(B=args.B, out_trace=args.trace)
     print(f"# obs rollup: {payload['spans']}")
 

@@ -98,35 +98,6 @@ FOUR_CHIP_CUT = {"fit_p": 50, "boot": 8}
 # Measurement helpers
 # ---------------------------------------------------------------------------
 
-class CompileClock:
-    """Seconds JAX spends lowering and compiling (persistent-cache loads
-    included), from jax.monitoring, so a phase's wall time splits into
-    compile and run."""
-
-    EVENTS = ("/jax/core/compile/jaxpr_to_mlir_module_duration",
-              "/jax/core/compile/backend_compile_duration")
-
-    def __init__(self, jax):
-        self.seconds = 0.0
-        self.compiles = 0
-        self.cache_hits = 0
-        jax.monitoring.register_event_duration_secs_listener(self._dur)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _dur(self, event, duration, *args, **kwargs):
-        if event in self.EVENTS:
-            self.seconds += duration
-        if event == self.EVENTS[1]:
-            self.compiles += 1
-
-    def _event(self, event, *args, **kwargs):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-
-    def mark(self):
-        return (self.seconds, self.compiles, self.cache_hits)
-
-
 def _counters():
     from repro.obs.metrics import default_registry
     snap = default_registry().snapshot()
@@ -140,19 +111,22 @@ def _delta(after, before, prefix):
 
 
 class Phase:
-    """Times one phase and collects its checks and health counters."""
+    """Times one phase and collects its checks and health counters.
+    Compile seconds, programs and persistent-cache hits come from the
+    program's compile accounting (``repro.obs.trace``: the
+    ``compile_s[...]``, ``compiles[...]`` and ``compile_cache_hits``
+    counters of the process registry)."""
 
-    def __init__(self, name, clock):
-        self.name, self.clock = name, clock
+    def __init__(self, name):
+        self.name = name
         self.info, self.checks, self.errors = {}, [], []
         self.timing = {"wall_s": 0.0, "compile_s": 0.0, "run_s": 0.0,
                        "compiles": 0, "cache_hits": 0}
         self.lowering, self.fallback, self.events = {}, {}, {}
-        self.probe_failed, self.chunks = {}, {}
+        self.probe_failed, self.chunks, self.compiled = {}, {}, {}
 
     def __enter__(self):
         self.c0, self.g0 = _counters()
-        self.k0 = self.clock.mark()
         self.t0 = time.perf_counter()
         return self
 
@@ -161,13 +135,14 @@ class Phase:
 
     def __exit__(self, et, ev, tb):
         wall = time.perf_counter() - self.t0
-        k1 = self.clock.mark()
         c1, g1 = _counters()
-        compile_s = k1[0] - self.k0[0]
+        self.compiled = _delta(c1, self.c0, "compiles[")
+        compile_s = sum(_delta(c1, self.c0, "compile_s[").values())
         self.timing = {"wall_s": wall, "compile_s": compile_s,
                        "run_s": wall - compile_s,
-                       "compiles": k1[1] - self.k0[1],
-                       "cache_hits": k1[2] - self.k0[2]}
+                       "compiles": sum(self.compiled.values()),
+                       "cache_hits": c1.get("compile_cache_hits", 0)
+                       - self.c0.get("compile_cache_hits", 0)}
         self.lowering = _delta(c1, self.c0, "seg_gram.lowering")
         self.fallback = _delta(c1, self.c0, "seg_gram.fallback")
         self.events = _delta(c1, self.c0, "runtime.events.")
@@ -197,7 +172,8 @@ class Phase:
 
     def record(self):
         return {"phase": self.name, "passed": self.passed, **self.info,
-                "timing": self.timing, "lowering": self.lowering,
+                "timing": self.timing, "compiles_by_span": self.compiled,
+                "lowering": self.lowering,
                 "fallback": self.fallback, "runtime_events": self.events,
                 "chunk_size": self.chunks, "checks": self.checks,
                 "errors": self.errors}
@@ -773,7 +749,8 @@ def main(argv=None) -> int:
     sz = REHEARSAL if args.rehearse else Sizes()
     out = pathlib.Path(args.out)
     out.mkdir(parents=True, exist_ok=True)
-    clock = CompileClock(jax)
+    from repro.obs.trace import process_tracer
+    process_tracer()  # installs the compile accounting the phases read
     device = {"platform": platform, "kind": devs[0].device_kind,
               "count": len(devs)}
     lowering = sg_ops.force_backend("interpret") if args.rehearse else None
@@ -794,7 +771,7 @@ def main(argv=None) -> int:
     phases = []
     for name, runner in runners.items():
         # each line as its phase ends, so a run cut short still reports
-        phases.append(_run(Phase(name, clock), runner))
+        phases.append(_run(Phase(name), runner))
         print(phases[-1].line(), flush=True)
     ok = bool(phases) and all(ph.passed for ph in phases)
     stamp = time.strftime("%Y%m%dT%H%M%S")

@@ -69,21 +69,22 @@ def _fold_fit_fn(nuis: Nuisance):
 
 def _crossfit_engine(nuis: Nuisance, keys: jax.Array, X: jax.Array,
                      target: jax.Array, folds: jax.Array, k: int,
-                     rules, executor) -> Tuple[jax.Array, Any]:
+                     rules, executor, tracer=None) -> Tuple[jax.Array, Any]:
     """The shared fold-fit dispatch: the fold axis (init keys + fold-
     complement weights) maps through the task runtime, so fold fits,
     tuning trials, and bootstrap replicates all run through one "how
     iterative steps run" knob — with the runtime's chunking and
     backend-downgrade ladder available to the fold axis too (pass a
-    TaskRuntime as ``executor`` to set a budget, or one carrying a
-    repro.obs Tracer to get labelled crossfit spans with the fold-fit
-    chunk spans nested inside)."""
-    from repro.obs.trace import maybe_span
+    TaskRuntime as ``executor`` to set a budget, or a repro.obs Tracer
+    as ``tracer`` — or a TaskRuntime carrying one — to get labelled
+    crossfit spans with the fold-fit chunk spans nested inside; with
+    neither, the span goes to the process tracer)."""
+    from repro.obs.trace import layer_span
     from repro.runtime import as_runtime
-    rt = as_runtime(executor, rules=rules)
+    rt = as_runtime(executor, rules=rules, tracer=tracer)
     W = fold_weights(folds, k)                      # (k, n)
     label = f"crossfit:{nuis.name}"
-    with maybe_span(rt.tracer, label, cat="crossfit", k=k,
+    with layer_span(rt.tracer, label, cat="crossfit", k=k,
                     n=int(X.shape[0]), backend=rt.name):
         preds, states = rt.map(_fold_fit_fn(nuis), {"key": keys, "w": W},
                                X, target, label=label)
@@ -95,12 +96,13 @@ def _crossfit_engine(nuis: Nuisance, keys: jax.Array, X: jax.Array,
 
 def crossfit_parallel(nuis: Nuisance, key: jax.Array, X: jax.Array,
                       target: jax.Array, folds: jax.Array, k: int,
-                      rules=None, executor="vmap") -> Tuple[jax.Array, Any]:
+                      rules=None, executor="vmap", tracer=None
+                      ) -> Tuple[jax.Array, Any]:
     """C1: all K fold-fits in ONE batched program (the Ray-tasks
     translation).  Returns (out-of-fold predictions (n,), states)."""
     keys = jax.random.split(key, k)
     return _crossfit_engine(nuis, keys, X, target, folds, k, rules,
-                            executor)
+                            executor, tracer)
 
 
 def crossfit_parallel_loo(nuis: Nuisance, key: jax.Array, X: jax.Array,
@@ -132,8 +134,8 @@ def crossfit_parallel_loo(nuis: Nuisance, key: jax.Array, X: jax.Array,
 
 
 def crossfit_sequential(nuis: Nuisance, key: jax.Array, X: jax.Array,
-                        target: jax.Array, folds: jax.Array, k: int
-                        ) -> Tuple[jax.Array, Any]:
+                        target: jax.Array, folds: jax.Array, k: int,
+                        tracer=None) -> Tuple[jax.Array, Any]:
     """EconML-style baseline: one fit per fold, strictly in sequence —
     the ``serial`` Executor (one compiled program per fold, like K
     Ray-less workers); the bespoke Python loop this function used to
@@ -142,7 +144,7 @@ def crossfit_sequential(nuis: Nuisance, key: jax.Array, X: jax.Array,
     keys = jax.vmap(lambda j: jax.random.fold_in(key, j))(
         jnp.arange(k, dtype=jnp.uint32))
     return _crossfit_engine(nuis, keys, X, target, folds, k, None,
-                            "serial")
+                            "serial", tracer)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -156,7 +158,7 @@ class CrossfitResult:
 
 def crossfit_one(nuis: Nuisance, key: jax.Array, X: jax.Array,
                  target: jax.Array, folds: jax.Array, k: int,
-                 engine: str = "parallel", rules=None
+                 engine: str = "parallel", rules=None, tracer=None
                  ) -> Tuple[jax.Array, Any]:
     """Engine dispatch for ONE cross-fit target over a fixed fold
     assignment — the unit `crossfit` composes twice and the IV
@@ -164,19 +166,21 @@ def crossfit_one(nuis: Nuisance, key: jax.Array, X: jax.Array,
     or four times.  engine: "parallel" (paper C1) maps the fold axis
     through ``vmap``; "sequential" through ``serial``; "parallel_loo"
     takes the one-pass LOO-Gram fast path; any other executor name or
-    Executor/TaskRuntime instance maps the fold axis directly."""
+    Executor/TaskRuntime instance maps the fold axis directly.
+    ``tracer`` (a repro.obs Tracer) records the fold fits' spans."""
     if engine == "parallel_loo":
         return crossfit_parallel_loo(nuis, key, X, target, folds, k, rules)
     if engine == "sequential":
-        return crossfit_sequential(nuis, key, X, target, folds, k)
+        return crossfit_sequential(nuis, key, X, target, folds, k, tracer)
     exe = "vmap" if engine == "parallel" else engine
     return crossfit_parallel(nuis, key, X, target, folds, k, rules,
-                             executor=exe)
+                             executor=exe, tracer=tracer)
 
 
 def crossfit(nuis_y: Nuisance, nuis_t: Nuisance, key: jax.Array,
              X: jax.Array, y: jax.Array, t: jax.Array, k: int,
-             engine: str = "parallel", rules=None) -> CrossfitResult:
+             engine: str = "parallel", rules=None,
+             tracer=None) -> CrossfitResult:
     """Cross-fit both nuisances.  engine: "parallel" (paper) dispatches
     the 2·K fits through the ``vmap`` Executor; "sequential" (EconML
     baseline) through ``serial``; "parallel_loo" takes the one-pass
@@ -184,7 +188,9 @@ def crossfit(nuis_y: Nuisance, nuis_t: Nuisance, key: jax.Array,
     Executor instance maps the fold axis directly."""
     kf, ky, kt = jax.random.split(key, 3)
     folds = fold_ids(kf, X.shape[0], k)
-    oof_y, st_y = crossfit_one(nuis_y, ky, X, y, folds, k, engine, rules)
-    oof_t, st_t = crossfit_one(nuis_t, kt, X, t, folds, k, engine, rules)
+    oof_y, st_y = crossfit_one(nuis_y, ky, X, y, folds, k, engine, rules,
+                               tracer)
+    oof_t, st_t = crossfit_one(nuis_t, kt, X, t, folds, k, engine, rules,
+                               tracer)
     return CrossfitResult(oof_y=oof_y, oof_t=oof_t, folds=folds,
                           states_y=st_y, states_t=st_t)

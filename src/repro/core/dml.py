@@ -13,6 +13,12 @@ Usage (mirrors the paper's §5.1 listing):
     res.ate_interval()            # B=cfg.n_bootstrap replicates, one
     res.cate_interval(X_new)      # vmapped program (repro.inference)
 
+Spans: ``dml.fit`` around the fit, with ``crossfit:<nuisance>`` and
+``dml.final_stage`` inside, and ``inference.bootstrap`` around the
+replicates; they go to ``DML(tracer=...)`` when one is given (which
+then sees the whole fit, its replicates included), else to the process
+tracer (repro.obs.trace).
+
 The fit -> inference plumbing (interval methods, replicate caching,
 analytic fallbacks) lives in the shared base layer
 ``repro.core.estimator``; this module supplies only the DML-specific
@@ -33,6 +39,7 @@ from repro.core.estimator import (SandwichEffectResult, inf_cache_field,
                                   resolve_scheme)
 from repro.core.final_stage import FinalStageResult, cate_basis, fit_final_stage
 from repro.core.nuisance import Nuisance, make_nuisance
+from repro.obs.trace import layer_span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -48,6 +55,7 @@ class FitContext:
     nuis_y: Nuisance
     nuis_t: Nuisance
     rules: Any = None
+    tracer: Any = None  # the DML's explicit repro.obs Tracer, if any
 
 
 @dataclasses.dataclass(frozen=True)
@@ -62,6 +70,10 @@ class DMLResult(SandwichEffectResult):
     _inf_cache: Dict[Any, Any] = inf_cache_field()
 
     estimator_name = "DML"
+
+    def _runtime_kwargs(self) -> Dict[str, Any]:
+        tracer = self.fit_ctx.tracer if self.fit_ctx is not None else None
+        return {**super()._runtime_kwargs(), "tracer": tracer}
 
     def _replicate_inference(self, method, n_boot, exe, alpha):
         """Replicate re-estimation through the task runtime: delete-fold
@@ -97,17 +109,19 @@ class DMLResult(SandwichEffectResult):
 class DML:
     """The estimator facade.  Nuisances default from the CausalConfig;
     pass explicit ``Nuisance`` objects to override (e.g. tuned models
-    from repro.core.tuning, or backbone-feature heads)."""
+    from repro.core.tuning, or backbone-feature heads).  ``tracer``
+    (a repro.obs Tracer) records the fit and its replicate inference."""
 
     def __init__(self, cfg: CausalConfig,
                  nuisance_y: Optional[Nuisance] = None,
                  nuisance_t: Optional[Nuisance] = None,
-                 rules=None):
+                 rules=None, tracer=None):
         self.cfg = cfg
         t_task = "clf" if cfg.discrete_treatment else "reg"
         self.nuis_y = nuisance_y or make_nuisance(cfg.nuisance_y, "reg", cfg)
         self.nuis_t = nuisance_t or make_nuisance(cfg.nuisance_t, t_task, cfg)
         self.rules = rules
+        self.tracer = tracer
 
     def fit(self, y: jax.Array, t: jax.Array, X: jax.Array,
             W: Optional[jax.Array] = None,
@@ -115,20 +129,24 @@ class DML:
         """y, t: (n,); X: (n, p) effect-relevant covariates; W: optional
         extra controls (concatenated for nuisance fitting only, exactly
         EconML's X/W split)."""
-        key = key if key is not None else jax.random.PRNGKey(0)
-        XW = X if W is None else jnp.concatenate([X, W], axis=1)
-        cf = crossfit(self.nuis_y, self.nuis_t, key, XW, y, t,
-                      self.cfg.n_folds, self.cfg.engine, self.rules)
-        phi = cate_basis(X, self.cfg.cate_features)
-        fs = fit_final_stage(y, t, cf.oof_y, cf.oof_t, phi,
-                             row_block=self.cfg.row_block,
-                             strategy=self.cfg.row_block_strategy,
-                             rules=self.rules)
-        theta_at_x = phi @ fs.theta
-        diag = compute_diagnostics(y, t, cf.oof_y, cf.oof_t, theta_at_x)
+        with layer_span(self.tracer, "dml.fit", cat="estimator",
+                        n=int(y.shape[0]), engine=self.cfg.engine):
+            key = key if key is not None else jax.random.PRNGKey(0)
+            XW = X if W is None else jnp.concatenate([X, W], axis=1)
+            cf = crossfit(self.nuis_y, self.nuis_t, key, XW, y, t,
+                          self.cfg.n_folds, self.cfg.engine, self.rules,
+                          tracer=self.tracer)
+            with layer_span(self.tracer, "dml.final_stage", cat="estimator"):
+                phi = cate_basis(X, self.cfg.cate_features)
+                fs = fit_final_stage(y, t, cf.oof_y, cf.oof_t, phi,
+                                     row_block=self.cfg.row_block,
+                                     strategy=self.cfg.row_block_strategy,
+                                     rules=self.rules)
+                diag = compute_diagnostics(y, t, cf.oof_y, cf.oof_t,
+                                           phi @ fs.theta)
         ctx = FitContext(y=y, t=t, XW=XW, phi=phi, key=key,
                          nuis_y=self.nuis_y, nuis_t=self.nuis_t,
-                         rules=self.rules)
+                         rules=self.rules, tracer=self.tracer)
         return DMLResult(theta=fs.theta, cov=fs.cov, cfg=self.cfg,
                          crossfit=cf, final=fs, diagnostics=diag,
                          fit_ctx=ctx)

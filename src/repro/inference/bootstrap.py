@@ -129,8 +129,10 @@ def dml_theta_once(nuis_y: Nuisance, nuis_t: Nuisance, n_folds: int,
     jit/vmap-compatible."""
     r = dml_residuals_once(nuis_y, nuis_t, n_folds, XW, y, t, key, w,
                            row_block=row_block)
-    theta, se = weighted_theta(r["ry"], r["rt"], phi, w, with_se=with_se,
-                               row_block=row_block, strategy=strategy)
+    with jax.named_scope("dml.final_stage"):
+        theta, se = weighted_theta(r["ry"], r["rt"], phi, w,
+                                   with_se=with_se, row_block=row_block,
+                                   strategy=strategy)
     out = {"theta": theta}
     if se is not None:
         out["se"] = se
@@ -149,11 +151,12 @@ def make_dml_replicate_fn(nuis_y: Nuisance, nuis_t: Nuisance,
     the closure object."""
 
     def replicate(kb, XW, y, t, phi):
-        kw, kfit = jax.random.split(kb)
-        w = bootstrap_weights(kw, XW.shape[0], scheme)
-        return dml_theta_once(nuis_y, nuis_t, n_folds, XW, y, t, phi,
-                              kfit, w, with_se=with_se,
-                              row_block=row_block, strategy=strategy)
+        with jax.named_scope("inference.replicate"):
+            kw, kfit = jax.random.split(kb)
+            w = bootstrap_weights(kw, XW.shape[0], scheme)
+            return dml_theta_once(nuis_y, nuis_t, n_folds, XW, y, t, phi,
+                                  kfit, w, with_se=with_se,
+                                  row_block=row_block, strategy=strategy)
 
     return replicate
 
@@ -169,23 +172,28 @@ def dml_bootstrap(nuis_y: Nuisance, nuis_t: Nuisance, *, n_folds: int,
                   mesh=None, rules=None,
                   row_block: int = 0, strategy: Optional[str] = None,
                   memory_budget: int = 0, chunk: int = 0,
-                  max_retries: int = 2) -> InferenceResult:
+                  max_retries: int = 2, tracer=None) -> InferenceResult:
     """B weighted DML refits scheduled by the task runtime: the
     replicate axis streams in memory-budgeted chunks (repro.runtime),
     each chunk retrying down the backend ladder on failure — results
-    are replicate-ordered and bit-identical across all of it."""
+    are replicate-ordered and bit-identical across all of it.  The
+    ``inference.bootstrap`` span goes to ``tracer`` (a repro.obs
+    Tracer) or the runtime's, else to the process tracer."""
+    from repro.obs.trace import layer_span
     from repro.runtime import as_runtime
     rt = as_runtime(executor, mesh=mesh, rules=rules,
                     memory_budget=memory_budget, chunk=chunk,
-                    max_retries=max_retries)
-    keys = replicate_keys(key, n_replicates)
-    replicate = make_dml_replicate_fn(nuis_y, nuis_t, n_folds,
-                                      scheme=scheme, with_se=with_se,
-                                      row_block=row_block,
-                                      strategy=strategy)
-    out = rt.map(replicate, keys, XW, y, t, phi, label="dml_bootstrap")
-    thetas = out["theta"]
-    se = jnp.std(thetas, axis=0, ddof=1)
+                    max_retries=max_retries, tracer=tracer)
+    with layer_span(rt.tracer, "inference.bootstrap", cat="inference",
+                    b=n_replicates, scheme=scheme):
+        keys = replicate_keys(key, n_replicates)
+        replicate = make_dml_replicate_fn(nuis_y, nuis_t, n_folds,
+                                          scheme=scheme, with_se=with_se,
+                                          row_block=row_block,
+                                          strategy=strategy)
+        out = rt.map(replicate, keys, XW, y, t, phi, label="dml_bootstrap")
+        thetas = out["theta"]
+        se = jnp.std(thetas, axis=0, ddof=1)
     return InferenceResult(
         method=scheme, executor=rt.name,
         point=thetas.mean(axis=0) if point is None else point,

@@ -41,8 +41,8 @@ def delete_fold_jackknife(y: jax.Array, t: jax.Array, oof_y: jax.Array,
                           point=None, point_se=None,
                           mesh=None, rules=None, ridge: float = 1e-8,
                           row_block: int = 0, memory_budget: int = 0,
-                          chunk: int = 0,
-                          max_retries: int = 2) -> InferenceResult:
+                          chunk: int = 0, max_retries: int = 2,
+                          tracer=None) -> InferenceResult:
     """Jackknife over the existing fold partition.  y, t: (n,);
     oof_y/oof_t: (n,) out-of-fold nuisance predictions from the fit;
     folds: (n,) fold ids.  The k delete-fold solves go through the task
@@ -51,7 +51,7 @@ def delete_fold_jackknife(y: jax.Array, t: jax.Array, oof_y: jax.Array,
     from repro.runtime import as_runtime
     sched = as_runtime(executor, mesh=mesh, rules=rules,
                        memory_budget=memory_budget, chunk=chunk,
-                       max_retries=max_retries)
+                       max_retries=max_retries, tracer=tracer)
     f32 = jnp.float32
     n, p = phi.shape
     ry = y.astype(f32) - oof_y

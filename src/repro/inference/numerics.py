@@ -46,36 +46,33 @@ import jax.numpy as jnp
 from repro.core import moments
 
 
+def _gauss_jordan_step(i, M):
+    """Eliminate column ``i`` of the augmented system ``M``."""
+    piv = M[i] / M[i, i]
+    factors = M[:, i].at[i].set(0.0)
+    M = M - factors[:, None] * piv[None, :]
+    return M.at[i].set(piv)
+
+
 def det_solve(A: jax.Array, b: jax.Array) -> jax.Array:
     """Deterministic (p,p) @ x = (p,) solve via Gauss-Jordan without
     pivoting.  Elementwise broadcast updates only — bit-identical under
     any number of leading vmap axes.  Requires A SPD-ish (ridge added by
-    every caller)."""
-    M = jnp.concatenate([A, b[:, None]], axis=1)
-
-    def elim(i, M):
-        piv = M[i] / M[i, i]
-        factors = M[:, i].at[i].set(0.0)
-        M = M - factors[:, None] * piv[None, :]
-        return M.at[i].set(piv)
-
-    M = jax.lax.fori_loop(0, A.shape[0], elim, M)
-    return M[:, -1]
+    every caller).  Its ops carry the ``det_solve`` scope."""
+    with jax.named_scope("det_solve"):
+        M = jnp.concatenate([A, b[:, None]], axis=1)
+        M = jax.lax.fori_loop(0, A.shape[0], _gauss_jordan_step, M)
+        return M[:, -1]
 
 
 def det_inv(A: jax.Array) -> jax.Array:
-    """Gauss-Jordan inverse (same invariance properties as det_solve)."""
-    p = A.shape[0]
-    M = jnp.concatenate([A, jnp.eye(p, dtype=A.dtype)], axis=1)
-
-    def elim(i, M):
-        piv = M[i] / M[i, i]
-        factors = M[:, i].at[i].set(0.0)
-        M = M - factors[:, None] * piv[None, :]
-        return M.at[i].set(piv)
-
-    M = jax.lax.fori_loop(0, p, elim, M)
-    return M[:, p:]
+    """Gauss-Jordan inverse (same invariance properties as det_solve;
+    its ops carry the ``det_inv`` scope)."""
+    with jax.named_scope("det_inv"):
+        p = A.shape[0]
+        M = jnp.concatenate([A, jnp.eye(p, dtype=A.dtype)], axis=1)
+        M = jax.lax.fori_loop(0, p, _gauss_jordan_step, M)
+        return M[:, p:]
 
 
 def _aug(X: jax.Array) -> jax.Array:

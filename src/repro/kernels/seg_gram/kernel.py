@@ -139,6 +139,13 @@ def _pad_cols(a: Array, pad: int) -> Array:
     return jnp.concatenate([a, jnp.zeros((a.shape[0], pad), a.dtype)], axis=1)
 
 
+def kernel_name(builder, n_segments: int) -> str:
+    """``seg_gram_<form>``, ``_seg`` appended when S > 1: the name the
+    compiled kernel carries, so a profile tells the forms apart."""
+    form = builder.__name__.removeprefix("build_")
+    return f"seg_gram_{form}" + ("_seg" if n_segments > 1 else "")
+
+
 def seg_gram_pallas(
     builder,
     arrays: Sequence[Array],
@@ -255,6 +262,7 @@ def seg_gram_pallas(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
+        name=kernel_name(builder, S),
     )(*operands)
     if S == 1:
         return g[:qL, :qR]

@@ -99,7 +99,8 @@ _PROBE_CACHE: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
 def _compile(fn, xs: Any, args: Tuple[Any, ...], chunk: int) -> Tuple[Any, str, int]:
     """The compiled ``chunk``-replicate vmapped program, its
     post-optimization HLO and its temporary bytes (compile-only, no
-    execution; cached per closure)."""
+    execution; cached per closure).  Each probe compiled counts as
+    ``runtime.probe_compiles`` on the process registry."""
     elem = _element_spec(xs)
     key = (_signature(elem, args), int(chunk))
     per_fn = _PROBE_CACHE.setdefault(fn, {})
@@ -113,6 +114,7 @@ def _compile(fn, xs: Any, args: Tuple[Any, ...], chunk: int) -> Tuple[Any, str, 
         return jax.vmap(lambda x_: fn(x_, *a))(xs_)
 
     compiled = jax.jit(batched).lower(xs_spec, *_spec(args)).compile()
+    default_registry().counter("runtime.probe_compiles").inc()
     text = compiled.as_text()
     peak = peak_temp_bytes(text)
     mem = compiled.memory_analysis()

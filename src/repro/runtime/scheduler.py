@@ -48,7 +48,7 @@ import jax.numpy as jnp
 from repro.inference.executor import Executor, jit_miss_hook, make_executor
 from repro.obs.audit import ChunkAudit
 from repro.obs.metrics import default_registry
-from repro.obs.trace import Tracer, maybe_span
+from repro.obs.trace import Tracer, layer_span, maybe_span
 from repro.runtime.future import TaskFuture, TaskGraph, resolve
 from repro.runtime.memory import (MemoryModel, compiled_chunk, memory_model,
                                   probe_chunk_cost)
@@ -175,8 +175,10 @@ class TaskRuntime:
                    latency histograms, downgrade/retry/jit-miss
                    counters, and the predicted-vs-measured cost audit
                    joining each chunk to its hlo_cost probes.  None (the
-                   default) records nothing and forces nothing — the
-                   same compiled programs run either way.
+                   default) puts the ``runtime.map`` / ``runtime.plan``
+                   / ``runtime.chunk`` spans on the process tracer and
+                   forces nothing — the same compiled programs run
+                   either way.
     events_maxlen  ring-buffer capacity of the always-on RuntimeEvent
                    tail (EventLog; the tracer is the unbounded record).
     """
@@ -320,7 +322,11 @@ class TaskRuntime:
             try:
                 tr = self.tracer
                 if tr is None:
-                    return self._exec(exe, run_fn, xs_c, args)
+                    with layer_span(None, "runtime.chunk", label=label,
+                                    chunk_index=index,
+                                    chunk_size=_leading_dim(xs_c),
+                                    backend=exe.name):
+                        return self._exec(exe, run_fn, xs_c, args)
                 return self._run_chunk_traced(
                     tr, exe, run_fn, xs_c, args, label, index, model
                 )
@@ -409,17 +415,23 @@ class TaskRuntime:
         b = _leading_dim(xs)
         if b == 0:
             return _empty_like_mapped(fn, xs, args)
-        chunk, model = self.plan_chunk(fn, xs, args, b)
-        if model is not None:
-            tag = f"[{label}]" if label else ""
-            default_registry().gauge(f"runtime.chunk_size{tag}").set(chunk)
         tr = self.tracer
-        with maybe_span(
-            tr, "runtime.map", cat="runtime", label=label, b=b, chunk=chunk,
+        tag = f"[{label}]" if label else ""
+        with layer_span(
+            tr, "runtime.map", cat="runtime", label=label, b=b,
             backend=self._primary.name,
-        ):
+        ) as sp:
+            probes = default_registry().counter("runtime.probe_compiles")
+            probes0 = probes.value
+            with layer_span(tr, "runtime.plan", cat="runtime",
+                            label=label) as ps:
+                chunk, model = self.plan_chunk(fn, xs, args, b)
+                ps.attrs.update(chunk=chunk,
+                                probes_compiled=probes.value - probes0)
+            sp.attrs["chunk"] = chunk
+            if model is not None:
+                default_registry().gauge(f"runtime.chunk_size{tag}").set(chunk)
             if tr is not None and model is not None:
-                tag = f"[{label}]" if label else ""
                 tr.metrics.gauge(f"runtime.chunk_size{tag}").set(chunk)
                 tr.metrics.gauge(f"runtime.predicted_peak_bytes{tag}").set(
                     model.peak(chunk)

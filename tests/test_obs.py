@@ -3,6 +3,7 @@ rollups), metrics registry, predicted-vs-measured cost audit, the
 EventLog ring buffer, and the two integration contracts — the traced
 span tree covers runtime chunks / sweep columns / crossfit targets, and
 ``tracer=None`` changes nothing (bit-identity, no recompiles)."""
+import contextlib
 import json
 
 import jax
@@ -12,12 +13,17 @@ import pytest
 
 from repro.config import CausalConfig
 from repro.core.crossfit import crossfit
+from repro.core.dml import DML
 from repro.core.nuisance import make_ridge
 from repro.data.causal_dgp import make_causal_data
+from repro.inference.bootstrap import make_dml_replicate_fn
 from repro.inference.executor import jit_miss_hook
+from repro.inference.numerics import det_inv, det_solve
 from repro.launch.roofline import peaks_for
 from repro.obs import (ChunkAudit, CostAudit, Histogram, MetricsRegistry,
-                       Tracer, maybe_span)
+                       Span, Tracer, default_registry, layer_span, maybe_span,
+                       process_tracer, reset_process_tracer)
+from repro.obs.trace import PROCESS_MAX_SPANS
 from repro.runtime import EventLog, RuntimeEvent, TaskRuntime, memory_model
 from repro.sweep import SweepSpec, sweep
 
@@ -380,3 +386,270 @@ def test_sweep_and_crossfit_span_coverage():
     # the whole tree exports as valid Chrome-trace JSON
     doc = json.loads(json.dumps(tr.chrome_trace()))
     assert len(doc["traceEvents"]) == len(tr.spans)
+
+
+# ---------------------------------------------------------------------------
+# The process tracer: layer spans of the fit path, the ring, compile
+# accounting, the profiler mirror, and that none of it changes a result
+# or adds a compile
+# ---------------------------------------------------------------------------
+
+FIT_SPANS = {"dml.fit", "crossfit:ridge", "dml.final_stage",
+             "inference.bootstrap", "runtime.map", "runtime.plan",
+             "runtime.chunk"}
+
+
+@pytest.fixture
+def fresh_process_tracer():
+    reset_process_tracer()
+    yield process_tracer()
+    reset_process_tracer()
+
+
+@pytest.fixture(scope="module")
+def tiny_fit_data():
+    return make_causal_data(jax.random.PRNGKey(0), 2048, 8, effect=1.0)
+
+
+def _tiny_dml(row_block=512, **kw):
+    return DML(CausalConfig(n_folds=3, nuisance_y="ridge",
+                            nuisance_t="ridge", inference="bootstrap",
+                            n_bootstrap=4, row_block=row_block,
+                            runtime_memory_budget=64 << 20), **kw)
+
+
+def _fit_and_boot(est, d, seed=0):
+    res = est.fit(d.y, d.t, d.X, key=jax.random.PRNGKey(seed))
+    inf = res.inference()
+    return res, inf
+
+
+class _OffTracer(Tracer):
+    """The process tracer stubbed out: records nothing, marks nothing."""
+
+    def span(self, name, cat="runtime", **attrs):
+        return contextlib.nullcontext(Span(-1, name, cat, 0, attrs={}))
+
+    def add_span(self, name, start_ns, end_ns, cat="runtime", **attrs):
+        return Span(-1, name, cat, start_ns, end_ns)
+
+
+def test_process_tracer_records_the_fit_span_tree(fresh_process_tracer,
+                                                   tiny_fit_data):
+    pt = fresh_process_tracer
+    assert not pt.sync_enabled and pt.spans.maxlen == PROCESS_MAX_SPANS
+    _fit_and_boot(_tiny_dml(), tiny_fit_data)
+    spans = [s for s in pt.spans if not s.name.startswith("compile.")]
+    by_id = {s.span_id: s for s in pt.spans}
+    parent = {s.span_id: by_id[s.parent_id].name if s.parent_id >= 0
+              else None for s in spans}
+    assert FIT_SPANS <= {s.name for s in spans}
+    for s in spans:
+        want = {"dml.fit": None, "inference.bootstrap": None,
+                "crossfit:ridge": "dml.fit", "dml.final_stage": "dml.fit",
+                "runtime.plan": "runtime.map",
+                "runtime.chunk": "runtime.map"}.get(s.name)
+        if s.name in ("dml.fit", "inference.bootstrap"):
+            assert parent[s.span_id] is None, s
+        elif want is not None:
+            assert parent[s.span_id] == want, s
+    maps = [s for s in spans if s.name == "runtime.map"]
+    assert sorted(parent[s.span_id] for s in maps) == [
+        "crossfit:ridge", "crossfit:ridge", "inference.bootstrap"]
+    boot_plan = next(s for s in spans if s.name == "runtime.plan"
+                     and s.attrs["label"] == "dml_bootstrap")
+    assert boot_plan.attrs["probes_compiled"] == 2  # the memory model's
+    assert boot_plan.attrs["chunk"] >= 1
+    assert all(not s.open and s.end_ns >= s.start_ns for s in pt.spans)
+
+
+@pytest.mark.parametrize("max_spans,dropped", [(3, 2), (None, 0)])
+def test_tracer_ring_drops_oldest_and_counts(max_spans, dropped):
+    tr = Tracer(max_spans=max_spans)
+    for i in range(5):
+        with tr.span(f"s{i}"):
+            pass
+    assert tr.recorded == 5 and tr.dropped == dropped
+    assert tr.span_names() == [f"s{i}" for i in range(dropped, 5)]
+    assert [s.span_id for s in tr.spans] == list(range(dropped, 5))
+
+
+@pytest.mark.parametrize("explicit", [False, True])
+def test_compile_inside_a_span_is_accounted(fresh_process_tracer, explicit):
+    tr = Tracer() if explicit else fresh_process_tracer
+    x = jnp.arange(37, dtype=jnp.float32)
+
+    @jax.jit
+    def inner(v):
+        return jnp.sin(v) * 3.0
+
+    @jax.jit
+    def outer(v):
+        return inner(v).sum() + 1.0
+
+    with layer_span(tr if explicit else None, "first") as first:
+        jax.block_until_ready(outer(x))
+    with layer_span(tr if explicit else None, "again"):
+        jax.block_until_ready(outer(x))
+    comp = [s for s in tr.spans if s.name.startswith("compile.")
+            and s.start_ns >= first.start_ns]  # not the input's
+    backend = [s for s in comp if s.name == "compile.backend"]
+    assert backend and all(s.parent_id == first.span_id for s in backend)
+    assert all(s.cat == "compile" and not s.open for s in comp)
+    # the inner function's trace nests inside the outer one's
+    traces = {s.attrs["fun_name"]: s for s in comp if s.name == "compile.trace"}
+    assert traces["inner"].parent_id == traces["outer"].span_id
+    counters = default_registry().snapshot()["counters"]
+    assert counters["compiles[first]"] == len(backend)
+    top = [s for s in comp if s.parent_id == first.span_id]
+    assert counters["compile_s[first]"] == pytest.approx(
+        sum(s.duration_s for s in top), rel=1e-6)
+    assert "compiles[again]" not in counters
+    assert "compile_s[again]" not in counters
+    assert (fresh_process_tracer.recorded == 0) == explicit
+
+
+def test_profiler_trace_holds_the_program_spans(fresh_process_tracer,
+                                                tiny_fit_data, tmp_path):
+    from jax.profiler import ProfileData
+    est = _tiny_dml()
+    _fit_and_boot(est, tiny_fit_data)  # compiles outside the profile
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        _fit_and_boot(est, tiny_fit_data, seed=1)
+    finally:
+        jax.profiler.stop_trace()
+    files = sorted(tmp_path.rglob("*.xplane.pb"))
+    assert files
+    names = {e.name for plane in ProfileData.from_file(str(files[-1])).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events}
+    assert FIT_SPANS <= names
+
+
+@pytest.mark.parametrize("mode", ["process", "explicit"])
+def test_fit_is_bitwise_identical_across_tracers(tiny_fit_data, monkeypatch,
+                                                 mode):
+    """theta, SE and the bootstrap replicates are the same bits with the
+    process tracer stubbed out, recording, or an explicit Tracer()."""
+    from repro.obs import trace
+    d = tiny_fit_data
+    est = _tiny_dml()
+    with monkeypatch.context() as m:
+        m.setattr(trace, "process_tracer", _OffTracer)
+        ref, ref_inf = _fit_and_boot(est, d)
+    tracer = Tracer() if mode == "explicit" else None
+    res, inf = _fit_and_boot(
+        _tiny_dml(nuisance_y=est.nuis_y, nuisance_t=est.nuis_t,
+                  tracer=tracer), d)
+    for got, want in ((res.theta, ref.theta), (res.stderr, ref.stderr),
+                      (inf.replicates, ref_inf.replicates),
+                      (inf.se, ref_inf.se)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    if tracer is not None:  # the explicit tracer saw the whole fit
+        assert FIT_SPANS <= set(tracer.span_names())
+
+
+@pytest.mark.parametrize("row_block", [0, 512])
+def test_tracing_adds_no_compile_to_a_warm_fit(tiny_fit_data, monkeypatch,
+                                               row_block):
+    """A warm point fit run with the process tracer stubbed out, with it
+    recording, and with an explicit Tracer(sync=False) compiles the same
+    programs: tracing adds none.  At row_block=0 a warm fit compiles
+    nothing at all."""
+    from repro.obs import trace
+    d = tiny_fit_data
+    est = _tiny_dml(row_block=row_block)
+
+    def compiles_of(tracer=None, off=False):
+        fit = est if tracer is None else _tiny_dml(
+            row_block=row_block, nuisance_y=est.nuis_y,
+            nuisance_t=est.nuis_t, tracer=tracer)
+        before = default_registry().snapshot()["counters"]
+        with monkeypatch.context() as m:
+            if off:
+                m.setattr(trace, "process_tracer", _OffTracer)
+            jax.block_until_ready(fit.fit(d.y, d.t, d.X,
+                                          key=jax.random.PRNGKey(5)).theta)
+        after = default_registry().snapshot()["counters"]
+        return {k: v - before.get(k, 0) for k, v in after.items()
+                if k.startswith("compiles[") and v != before.get(k, 0)}
+
+    compiles_of()  # warm-up
+    off = sum(compiles_of(off=True).values())  # no span open: "(root)"
+    recorded = compiles_of()
+    assert compiles_of(Tracer(sync=False)) == recorded
+    assert sum(recorded.values()) == off
+    if row_block == 0:
+        assert off == 0
+
+
+@pytest.mark.parametrize("scope,build", [
+    ("det_solve", lambda: (jax.vmap(det_solve),
+                           (jnp.eye(4)[None].repeat(3, 0) * 2.0,
+                            jnp.ones((3, 4))))),
+    ("det_inv", lambda: (jax.vmap(det_inv), (jnp.eye(4)[None].repeat(3, 0),))),
+    ("inference.replicate", lambda: _replicate_case()),
+    ("dml.final_stage", lambda: _replicate_case()),
+])
+def test_scopes_reach_the_hlo_op_metadata(scope, build):
+    import re
+    fn, args = build()
+    text = jax.jit(lambda *a: fn(*a)).lower(*args).compile().as_text()
+    names = re.findall(r'op_name="([^"]*)"', text)
+    # a scope under vmap reads "vmap(<scope>)"
+    pat = re.compile(r"(^|/|\()" + re.escape(scope) + r"(\)|/|$)")
+    assert any(pat.search(n) for n in names), names[:5]
+
+
+def _replicate_case():
+    d = make_causal_data(jax.random.PRNGKey(0), 256, 4, effect=1.0)
+    rep = make_dml_replicate_fn(make_ridge(), make_ridge(), 3)
+    phi = jnp.ones((256, 1), jnp.float32)
+    keys = jax.random.split(jax.random.PRNGKey(1), 2)
+    return (jax.vmap(lambda k, X, y, t, p: rep(k, X, y, t, p),
+                     in_axes=(0, None, None, None, None)),
+            (keys, d.X, d.y, d.t, phi))
+
+
+@pytest.mark.parametrize("n_segments,name", [
+    (1, "seg_gram_fold_weighted"), (320, "seg_gram_fold_weighted_seg")])
+def test_seg_gram_kernel_is_named_by_form(n_segments, name):
+    from repro.kernels.seg_gram import ref
+    from repro.kernels.seg_gram.kernel import kernel_name
+    assert kernel_name(ref.build_fold_weighted, n_segments) == name
+
+
+def test_tracer_threads_nest_on_their_own_stacks():
+    """More threads than cores on one tracer, switching every few µs: no
+    span id is lost or shared, and every span nests under its own
+    thread's parent."""
+    import sys
+    import threading
+    tr, n_threads, reps = Tracer(max_spans=None), 16, 200
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        def work(i):
+            for _ in range(reps):
+                with tr.span(f"outer{i}"):
+                    with tr.span(f"inner{i}"):
+                        pass
+
+        threads = [threading.Thread(target=work, args=(i,))
+                   for i in range(n_threads)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(old)
+    assert tr.recorded == len(tr.spans) == 2 * n_threads * reps
+    assert sorted(s.span_id for s in tr.spans) == list(range(tr.recorded))
+    by_id = {s.span_id: s for s in tr.spans}
+    for s in tr.spans:
+        if s.name.startswith("inner"):
+            assert by_id[s.parent_id].name == "outer" + s.name[5:]
+        else:
+            assert s.parent_id == -1 and s.depth == 0

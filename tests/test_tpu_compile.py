@@ -75,17 +75,20 @@ def _kernel(builder, n_segments=1):
 
 
 def test_final_stage_residual_s1(one_chip):
-    """The DML final stage at the Fit's n (p_phi = 1)."""
+    """The DML final stage at the Fit's n (p_phi = 1); the kernel op
+    carries the form's name and stays a ``tpu_custom_call``."""
     cols = [_spec((N_FIT, 1), one_chip) for _ in range(4)]
-    _compile(_kernel(sg_ref.build_residual), *cols,
-             _spec((N_FIT, 1), one_chip))
+    compiled = _compile(_kernel(sg_ref.build_residual), *cols,
+                        _spec((N_FIT, 1), one_chip))
+    assert "%seg_gram_residual." in compiled.as_text()
 
 
 def test_fold_gram_paper_width(one_chip):
     """fold_gram at the paper's Fig. 6 width: S = K = 5, q = 501."""
-    _compile(_kernel(sg_ref.build_design, 5),
-             _spec((N_FIT, 501), one_chip),
-             _spec((N_FIT, 1), one_chip, jnp.int32))
+    compiled = _compile(_kernel(sg_ref.build_design, 5),
+                        _spec((N_FIT, 501), one_chip),
+                        _spec((N_FIT, 1), one_chip, jnp.int32))
+    assert "%seg_gram_design_seg." in compiled.as_text()
 
 
 def test_crossfit_design_gram_vmapped_over_folds(one_chip):
