@@ -14,8 +14,15 @@ that ARE invariant under an added batch axis:
     elementwise producer into it, so gradients are read off augmented
     Grams instead);
   * elementwise ops, plain sums, ``fold_in``/``permutation`` PRNG;
-  * Gauss-Jordan elimination written as broadcast updates (fori_loop of
-    rank-1 outer products) — no LAPACK, no pivot-order ambiguity.
+  * Gauss-Jordan elimination written as broadcast updates — no LAPACK,
+    no pivot-order ambiguity.  Past ``_UNBLOCKED_MAX`` unknowns it runs
+    in panels of ``_NB`` columns with a delayed update: a panel's rank-1
+    updates are found on its column slab and pivot rows, then applied to
+    the whole system as one elementwise chain of multiply-subtracts.
+    Every entry sees the same multiply-subtracts, in the same order, as
+    the column-at-a-time loop, and no reduction adds two of them (the
+    only reductions pick one entry by a max over -inf), so the bits
+    equal that loop's under any batch axes.
 
 Every function here is built ONLY from that vocabulary.  The mat-vec
 RHS of the normal equations is folded into an *augmented* Gram (append
@@ -44,6 +51,16 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import moments
+from repro.obs.metrics import default_registry
+
+
+# Columns per panel of the delayed-update elimination, and the most
+# unknowns the unblocked loop keeps (the final stage's p_phi = 1, the
+# sweep's cells).  Timed on a v5e: panels of 16 beat 32 and 64 at every
+# size tried; the loop wins up to about 96 unknowns over a batch of 320
+# systems, the panels from about 24 over a batch of 10.
+_NB = 16
+_UNBLOCKED_MAX = 64
 
 
 def _gauss_jordan_step(i, M):
@@ -54,25 +71,149 @@ def _gauss_jordan_step(i, M):
     return M.at[i].set(piv)
 
 
+def _gauss_jordan(M: jax.Array, p: int) -> jax.Array:
+    """Gauss-Jordan elimination without pivoting of the augmented
+    ``(p, p + r)`` system ``M``; returns the reduced right-hand block
+    ``(p, r)``.  Systems with more than ``_UNBLOCKED_MAX`` unknowns go
+    through ``_panels``, the rest through the unblocked loop; the choice
+    is counted as ``det_solve.path[panelled|unblocked]`` at trace
+    time."""
+    panelled = p > _UNBLOCKED_MAX
+    default_registry().counter(
+        f"det_solve.path[{'panelled' if panelled else 'unblocked'}]").inc()
+    if not panelled:
+        return jax.lax.fori_loop(0, p, _gauss_jordan_step, M)[:, p:]
+    # pad the p x p block to a multiple of _NB with an identity block,
+    # the right-hand columns after it.  The padded rows and columns stay
+    # +0 off the identity, so a padded step subtracts +0 x +0 from every
+    # original entry: exact
+    q = -(-p // _NB) * _NB
+    if q > p:
+        r = M.shape[1] - p
+        M = jnp.concatenate([
+            jnp.concatenate([M[:, :p], jnp.zeros((p, q - p), M.dtype),
+                             M[:, p:]], axis=1),
+            jnp.concatenate([jnp.zeros((q - p, p), M.dtype),
+                             jnp.eye(q - p, dtype=M.dtype),
+                             jnp.zeros((q - p, r), M.dtype)], axis=1)])
+    return _panels(M[None])[0, :p, q:]
+
+
+@jax.custom_batching.custom_vmap
+def _panels(M: jax.Array) -> jax.Array:
+    """Panelled elimination of the padded systems ``M`` (B, q, w).
+
+    Per panel of ``_NB`` columns, an inner loop eliminates the panel's
+    columns on the column slab and the pivot-row block alone, and
+    records each step's factors ``f_s`` and pivot row ``piv_s``; then
+    every row gets the panel's multiply-subtracts
+    ``((M - f_0 piv_0) - f_1 piv_1) ...`` in one elementwise chain, and
+    the pivot rows are written back.  Every entry sees the same
+    multiply-subtracts in the same order as the unblocked loop, and no
+    reduction sums two of them, so the bits are the same.
+
+    The panel's pivot rows and columns are always the first ``_NB``
+    (each panel ends by rotating them to the back), and the step's
+    column and pivot row are picked and written through masks: dynamic
+    offsets into both of M's matrix axes make XLA lay the batch axes
+    minor (on a TPU, a (2, 128) tile over a (2, 5) batch), and under
+    vmap a dynamic index becomes a gather or scatter.  Written in lax
+    with one explicit batch axis (``_panels_vmap`` folds vmapped axes
+    into it) to keep the traced program small: the replicate's memory
+    probes lower it on every fit."""
+    lax = jax.lax
+    B, q, w = M.shape
+    nb = _NB
+    iota_nb = lax.iota(jnp.int32, nb)
+    iota_q = lax.iota(jnp.int32, q)
+    zeros_q = jnp.zeros((B, q), M.dtype)
+
+    def bcast(x, shape, dims):
+        return lax.broadcast_in_dim(x, shape, dims)
+
+    def step(s, carry):
+        slab, rows, F, P = carry                   # (B, q, nb), (B, nb, w)
+        at = iota_nb == s
+        col = iota_q == s
+        m_w = bcast(at, (B, nb, w), (1,))
+        row = _select(m_w, rows, 1)                                 # (B, w)
+        d = _select(bcast(at, (B, nb), (1,)),
+                    lax.slice_in_dim(row, 0, nb, axis=1), 1)        # (B,)
+        piv = lax.div(row, bcast(d, (B, w), (0,)))
+        f = _select(bcast(at, (B, q, nb), (2,)), slab, 2)           # (B, q)
+        f = lax.select(bcast(col, (B, q), (1,)), zeros_q, f)
+        piv_q = bcast(lax.slice_in_dim(piv, 0, nb, axis=1), (B, q, nb),
+                      (0, 2))
+        slab = lax.select(bcast(col, (B, q, nb), (1,)), piv_q,
+                          lax.sub(slab, lax.mul(bcast(f, (B, q, nb), (0, 1)),
+                                                piv_q)))
+        piv_w = bcast(piv, (B, nb, w), (0, 2))
+        f_w = bcast(lax.slice_in_dim(f, 0, nb, axis=1), (B, nb, w), (0, 1))
+        rows = lax.select(m_w, piv_w, lax.sub(rows, lax.mul(f_w, piv_w)))
+        # F and P keep the step axis first, for the chain's split
+        return (slab, rows,
+                lax.select(bcast(at, (nb, B, q), (0,)),
+                           bcast(f, (nb, B, q), (1, 2)), F),
+                lax.select(bcast(at, (nb, B, w), (0,)),
+                           bcast(piv, (nb, B, w), (1, 2)), P))
+
+    def panel(_, M):
+        slab = lax.slice_in_dim(M, 0, nb, axis=2)
+        rows = lax.slice_in_dim(M, 0, nb, axis=1)
+        carry = (slab, rows, jnp.zeros((nb, B, q), M.dtype),
+                 jnp.zeros((nb, B, w), M.dtype))
+        _, rows, F, P = lax.fori_loop(0, nb, step, carry)
+        # the delayed update, one elementwise chain over M
+        shape = (nb, B, q, w)
+        Fs = lax.split(bcast(F, shape, (0, 1, 2)), (1,) * nb)
+        Ps = lax.split(bcast(P, shape, (0, 1, 3)), (1,) * nb)
+        M = lax.reshape(M, (1, B, q, w))
+        for f_s, p_s in zip(Fs, Ps):
+            M = lax.sub(M, lax.mul(f_s, p_s))
+        M = lax.reshape(M, (B, q, w))
+        M = lax.concatenate([lax.slice_in_dim(M, nb, q, axis=1), rows], 1)
+        return lax.concatenate([lax.slice_in_dim(M, nb, q, axis=2),
+                                lax.slice_in_dim(M, 0, nb, axis=2),
+                                lax.slice_in_dim(M, q, w, axis=2)], 2)
+
+    return lax.fori_loop(0, q // nb, panel, M)
+
+
+@_panels.def_vmap
+def _panels_vmap(axis_size, in_batched, M):
+    # fold the vmapped axis into the explicit batch axis, so the loops
+    # are traced once at the full batch and never batched by vmap (the
+    # rule only runs with M batched: it is the one argument)
+    folded = _panels(M.reshape((-1,) + M.shape[2:]))
+    return folded.reshape(M.shape), True
+
+
+def _select(mask: jax.Array, X: jax.Array, axis: int) -> jax.Array:
+    """The slice of ``X`` along ``axis`` where ``mask`` (X's shape)
+    holds: a max over the others set to -inf, so exact for every value,
+    -0 and nan included."""
+    return jax.lax.reduce_max(
+        jax.lax.select(mask, X, jnp.full_like(X, -jnp.inf)), (axis,))
+
+
 def det_solve(A: jax.Array, b: jax.Array) -> jax.Array:
     """Deterministic (p,p) @ x = (p,) solve via Gauss-Jordan without
-    pivoting.  Elementwise broadcast updates only — bit-identical under
-    any number of leading vmap axes.  Requires A SPD-ish (ridge added by
-    every caller).  Its ops carry the ``det_solve`` scope."""
+    pivoting, panelled for large p (``_gauss_jordan``).  Elementwise
+    broadcast updates only — bit-identical under any number of leading
+    vmap axes, and to the unblocked loop.  Requires A SPD-ish (ridge
+    added by every caller).  Its ops carry the ``det_solve`` scope."""
     with jax.named_scope("det_solve"):
         M = jnp.concatenate([A, b[:, None]], axis=1)
-        M = jax.lax.fori_loop(0, A.shape[0], _gauss_jordan_step, M)
-        return M[:, -1]
+        return _gauss_jordan(M, A.shape[0])[:, 0]
 
 
 def det_inv(A: jax.Array) -> jax.Array:
-    """Gauss-Jordan inverse (same invariance properties as det_solve;
-    its ops carry the ``det_inv`` scope)."""
+    """Gauss-Jordan inverse (the same elimination and invariance
+    properties as det_solve; its ops carry the ``det_inv`` scope)."""
     with jax.named_scope("det_inv"):
         p = A.shape[0]
         M = jnp.concatenate([A, jnp.eye(p, dtype=A.dtype)], axis=1)
-        M = jax.lax.fori_loop(0, p, _gauss_jordan_step, M)
-        return M[:, p:]
+        return _gauss_jordan(M, p)
 
 
 def _aug(X: jax.Array) -> jax.Array:
