@@ -46,6 +46,12 @@ zero-padded in registers to the (8, 128) fp32 tile and segments to a
 whole number of tiles (sliced off the output).  Interpret mode runs the
 same tiling, so CPU certification covers the compiled block structure.
 
+Lane-major form (``seg_gram_lanes``, S = 1): row-shaped inputs come
+transposed, (d, n), so narrow operands are lane-dense in HBM; the
+builder returns (Lᵀ, Rᵀ) and may read resident tables (the segmented
+sweep's MM step reads its (E·K, q) coefficients so, and forms the
+cohort one-hot and the residuals in registers).
+
 The Gram matmul runs at ``PRECISION``: the moments are sums over
 millions of rows that the estimators solve against, so they need f32
 products, not a single bf16 pass (the MXU default for f32 operands).
@@ -267,3 +273,79 @@ def seg_gram_pallas(
     if S == 1:
         return g[:qL, :qR]
     return g.reshape(n_tiles * st, qlp, qrp)[:S, :qL, :qR]
+
+
+LANE_BLOCK = 8192  # rows (lanes) per grid step of ``seg_gram_lanes``
+
+
+def lane_block(n: int, block_n: Optional[int] = None) -> int:
+    """Rows per grid step of the lane-major kernel: every row when they
+    fit one block, else a multiple of 128 lanes within a factor 2 of
+    ``LANE_BLOCK`` that divides n (no padded copy), else ``LANE_BLOCK``."""
+    if block_n is not None:
+        return int(block_n)
+    if n <= LANE_BLOCK:
+        return n
+    return next((b for b in range(LANE_BLOCK, LANE_BLOCK // 2, -LANES)
+                 if n % b == 0), LANE_BLOCK)
+
+
+def seg_gram_lanes(
+    builder,
+    arrays: Sequence[Array],
+    *,
+    interpret: bool,
+    block_n: Optional[int] = None,
+) -> Array:
+    """Fused S = 1 Gram over lane-major operands: row-shaped inputs come
+    transposed, (d, n) with the rows on lanes, so an array of a few
+    columns is lane-dense in HBM (an (n, d < 128) array pads every row
+    to 128 lanes, 512 bytes); any input of another width is resident,
+    one whole block at every grid step.  The builder maps (d, r) blocks
+    to (Lᵀ (qL, r), Rᵀ (qR, r)) and the kernel accumulates
+    ``g += Lᵀ (Rᵀ)ᵀ`` (qL, qR) at ``PRECISION``.  Zero-padded rows must
+    give zero Lᵀ or Rᵀ columns, as for ``seg_gram_pallas``."""
+    n = max(a.shape[1] for a in arrays)
+
+    def resident(a: Array) -> bool:
+        return a.shape[1] != n
+
+    qL, qR = jax.eval_shape(
+        builder,
+        *[jax.ShapeDtypeStruct(a.shape if resident(a) else (a.shape[0], LANES),
+                               a.dtype) for a in arrays],
+    )
+    qL, qR = qL.shape[0], qR.shape[0]
+    bn = lane_block(n, block_n)
+    pad = (-n) % bn
+    operands = [a if resident(a) else
+                jnp.pad(a, ((0, 0), (0, pad))) if pad else a for a in arrays]
+
+    def _spec(a: Array) -> pl.BlockSpec:
+        if resident(a):
+            return pl.BlockSpec(a.shape, lambda i: (0, 0))
+        return pl.BlockSpec((a.shape[0], bn), lambda i: (0, i))
+
+    def kern(*refs):
+        *in_refs, g_ref = refs
+
+        @pl.when(pl.program_id(0) == 0)
+        def _init():
+            g_ref[...] = jnp.zeros_like(g_ref)
+
+        Lt, Rt = builder(*[r[...] for r in in_refs])
+        g_ref[...] += lax.dot_general(
+            Lt, Rt, (((1,), (1,)), ((), ())), precision=PRECISION,
+            preferred_element_type=jnp.float32)
+
+    return pl.pallas_call(
+        kern,
+        grid=((n + pad) // bn,),
+        in_specs=[_spec(a) for a in arrays],
+        out_specs=pl.BlockSpec((qL, qR), lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((qL, qR), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",), vmem_limit_bytes=VMEM_LIMIT),
+        interpret=interpret,
+        name=kernel_name(builder, 1),
+    )(*operands)

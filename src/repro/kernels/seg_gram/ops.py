@@ -519,3 +519,41 @@ def segment_outer(
         backend=backend,
         init=init,
     )
+
+
+def lane_gram(builder, arrays: Sequence[Array], *, backend: str = "") -> Array:
+    """``G = Σ_n L_n (x) R_n`` (qL, qR) over lane-major operands: the
+    row-shaped inputs transposed, (d, n), and resident tables of any
+    other width (``kernel.seg_gram_lanes``).  The builder maps (d, r)
+    blocks to (Lᵀ, Rᵀ).  "ref" and "scatter" run it whole-array and
+    contract once; "pallas" and "interpret" run the kernel."""
+    be = backend or default_backend()
+    if be not in ("ref", "scatter", "pallas", "interpret"):
+        raise ValueError(f"unknown seg_gram backend {be!r}")
+    if be == "pallas" and jax.default_backend() != "tpu":
+        raise RuntimeError(
+            "seg_gram: the 'pallas' lowering compiles with Mosaic and runs "
+            f"on TPU only (the backend is {jax.default_backend()!r}); use "
+            "'interpret' to run the kernel in interpret mode, or 'scatter'")
+    default_registry().counter(f"seg_gram.lowering[{be}]").inc()
+    arrays = [a.astype(_F32) for a in arrays]
+    if be in ("ref", "scatter"):
+        Lt, Rt = builder(*arrays)
+        return lax.dot_general(Lt, Rt, (((1,), (1,)), ((), ())),
+                               precision=lax.Precision.HIGHEST,
+                               preferred_element_type=_F32)
+    return _kernel.seg_gram_lanes(builder, arrays, interpret=be == "interpret")
+
+
+def mm_logistic_grad(xa_t: Array, meta_t: Array, coef: Array, *,
+                     backend: str = "") -> Array:
+    """(E, K, q) complement gradient of the segmented sweep's MM
+    logistic step, ``Σ_{cohort e, fold ≠ k} (sigmoid(xa·coef[e, k]) - t)
+    xa``, in one pass over the lane-major rows ``xa_t`` = [X | 1]ᵀ
+    (q, n) and ``meta_t`` = [t; cohort + 1; fold] (3, n), with the
+    (E, K, q) table ``coef`` resident (``ref.build_mm_logistic``)."""
+    E, K, q = coef.shape
+    table = jnp.transpose(coef, (1, 2, 0)).reshape(K * q, E)
+    G = lane_gram(_ref.build_mm_logistic, [xa_t, meta_t, table],
+                  backend=backend)
+    return G.reshape(E, K, q)

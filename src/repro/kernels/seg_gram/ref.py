@@ -21,6 +21,7 @@ in every accumulator).
 
 from __future__ import annotations
 
+import functools
 from typing import Optional, Sequence, Tuple
 
 import jax
@@ -125,6 +126,54 @@ def build_iv_meat(
         e = w * e
     m = e * (rz * phi)
     return m, m
+
+
+def _dot(a: Array, b: Array) -> Array:
+    return jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+
+def build_mm_logistic(xa: Array, meta: Array, coef: Array) -> Pair:
+    """The segmented sweep's MM logistic gradient, lane-major (rows on
+    lanes, see ``ops.lane_gram``).  ``xa`` (q, r) is [X | 1]ᵀ, ``meta``
+    (3, r) is [t; cohort + 1; fold] (cohort 0 marks a padded row), and
+    the resident ``coef`` (K·q, E) holds every (cohort, fold) model,
+    cohort e's fold-k model in column e, rows k·q .. k·q + q - 1.  Per
+    row the cohort's one-hot picks its K models, and the logits, mu - t
+    and the fold complement (the row's own fold zeroed) form in
+    registers:
+
+        Lᵀ = onehot(cohort) (E, r),   Rᵀ[k·q + j] = rc[k] · xa[j],
+
+    so ``L^T R`` reshapes to the (E, K, q) complement gradient
+    ``Σ_{cohort e, fold ≠ k} (mu_k - t) xa``: the one-hot path's t1 - t2
+    as one sum, with no (n, K) or (n, K, q) array in HBM.  The products
+    with 0/1 matrices (the pick, the tiling, the per-model sums) run at
+    HIGHEST, so they are exact; the real products stay elementwise in
+    float32.  Zero rows give zero Lᵀ (cohort 0) and zero Rᵀ (xa = 0)."""
+    q, r = xa.shape
+    kq, E = coef.shape
+    K = kq // q
+    iota = jax.lax.broadcasted_iota
+    t = meta[0:1]
+    cohort = meta[1:2].astype(jnp.int32)
+    fold = meta[2:3].astype(jnp.int32)
+    L = (cohort == iota(jnp.int32, (E, r), 0) + 1).astype(jnp.float32)
+    # tile (K·q, q): xa repeated per fold model; blk (K, K·q) sums each
+    # model's q rows, blk_t (K·q, K) spreads a model's residual over them
+    c, j = iota(jnp.int32, (kq, q), 0), iota(jnp.int32, (kq, q), 1)
+    tile = functools.reduce(
+        jnp.logical_or, [c == j + m * q for m in range(K)]).astype(jnp.float32)
+    m, c = iota(jnp.int32, (K, kq), 0), iota(jnp.int32, (K, kq), 1)
+    blk = ((c >= m * q) & (c < m * q + q)).astype(jnp.float32)
+    c, m = iota(jnp.int32, (kq, K), 0), iota(jnp.int32, (kq, K), 1)
+    blk_t = ((c >= m * q) & (c < m * q + q)).astype(jnp.float32)
+    xk = _dot(tile, xa)  # (K·q, r)
+    logit = _dot(blk, xk * _dot(coef, L))  # (K, r)
+    res = jax.nn.sigmoid(logit) - t
+    res = jnp.where(fold == iota(jnp.int32, (K, r), 0), 0.0, res)
+    return L, _dot(blk_t, res) * xk
 
 
 def seg_gram_ref(

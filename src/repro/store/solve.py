@@ -5,7 +5,7 @@ sufficient statistics — no data pass:
 
   1. Cross-fit ridge nuisances come from the fold-complement of the
      nuisance Gram (the leave-one-out identity of
-     ``sweep.segmented._segment_fold_ridge``, same scaling: complement
+     ``sweep.segmented._complement`` and ``_ridge``, same scaling: complement
      Gram / n_eff + λI).
   2. Residuals are linear forms of the design, ``r = cᵀ dn`` with
      coefficient vectors like ``c_y = [-β_y | 1 at the y column]``, so

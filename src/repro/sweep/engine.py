@@ -54,7 +54,8 @@ from repro.config import CausalConfig
 from repro.core.estimator import resolve_scheme
 from repro.core.final_stage import cate_basis
 from repro.core.registry import EstimatorSpec, get_spec, nuisance_signature
-from repro.obs.trace import maybe_span
+from repro.obs.metrics import default_registry
+from repro.obs.trace import layer_span
 from repro.sweep.panel import ColumnResult, EffectPanel
 from repro.sweep.spec import SweepSpec, segment_counts
 
@@ -159,6 +160,19 @@ def _column_ci(cell, cfg: CausalConfig, rt, xs, data, key, col_index: int):
     )
 
 
+def _count_column(path: str, cfg: CausalConfig, n_segments: int,
+                  mm_steps: int = 0) -> None:
+    """Counters on the process registry, per column run: the path it
+    took (``sweep.path[segmented|cells|shared]``), its (segment, fold)
+    nuisance cells (``sweep.cells``, E·K) and its MM logistic steps
+    (``sweep.mm_steps``, segmented columns with a binary treatment)."""
+    reg = default_registry()
+    reg.counter(f"sweep.path[{path}]").inc()
+    reg.counter("sweep.cells").inc(n_segments * cfg.n_folds)
+    if mm_steps:
+        reg.counter("sweep.mm_steps").inc(mm_steps)
+
+
 def _events(rt, start_total: int = 0) -> Tuple[str, ...]:
     # EventLog.since is drop-safe: start_total is an events.total
     # checkpoint, valid even if the ring dropped older entries
@@ -258,7 +272,8 @@ def _run_column(
         "sid": jnp.arange(n_segments, dtype=jnp.int32),
     }
     rt = _runtime(cfg, executor, tracer, data_mesh)
-    with maybe_span(
+    _count_column("cells", cfg, n_segments)
+    with layer_span(
         rt.tracer, f"sweep.column[{col_index}]", cat="sweep",
         estimator=rspec.name, segments=n_segments,
     ):
@@ -304,7 +319,7 @@ def _run_shared_group(
     rt = _runtime(cfg0, executor, tracer, data_mesh)
     # the shared residual pass is group-fatal by design (every member
     # consumes it); everything after is isolated per member
-    with maybe_span(
+    with layer_span(
         rt.tracer, f"sweep.group:{rspec.name}", cat="sweep",
         members=len(members), segments=n_segments,
     ):
@@ -347,7 +362,8 @@ def _shared_member_column(
     ev_start: int,
 ) -> ColumnResult:
     data = _column_data(base_data, cfg)
-    with maybe_span(
+    _count_column("shared", cfg, sid.shape[0])
+    with layer_span(
         rt.tracer, f"sweep.column[{col_index}]", cat="sweep",
         estimator=rspec.name, shared_nuisance=col_index != first_idx,
     ):
@@ -405,21 +421,33 @@ def _segmented_or_cells(
             rspec, cfg, col_index, base_data, n_segments, key, executor,
             with_ci, tracer, data_mesh,
         )
-    with maybe_span(
+    mm_steps = 2 * cfg.newton_iters if cfg.discrete_treatment else 0
+    _count_column("segmented", cfg, n_segments, mm_steps)
+    with layer_span(
         tracer, f"sweep.column[{col_index}]", cat="sweep",
         estimator=rspec.name, segmented=True,
-    ) as sp:
-        out = segmented_column(
-            cfg, base_data, n_segments, jax.random.fold_in(key, col_index)
-        )
-        if tracer is not None and sp is not None:
+    ):
+        with layer_span(tracer, "sweep.segmented", cat="sweep",
+                        segments=n_segments, folds=cfg.n_folds,
+                        mm_steps=mm_steps):
+            out = segmented_column(
+                cfg, base_data, n_segments, jax.random.fold_in(key, col_index)
+            )
+        if tracer is not None:
             tracer.sync(out)
+        # the (E, K) row counts come back to the host: the column
+        # returns once its device work is done
+        empty = int(jnp.sum(out["cell_rows"] == 0))
+    default_registry().counter("sweep.empty_cells").inc(empty)
     return ColumnResult(
         estimator=rspec.name,
         cfg=cfg,
         thetas=out["theta"],
         ates=out["ate"],
         ses=out.get("se"),
+        beta_y=out["beta_y"],
+        beta_t=out["beta_t"],
+        cell_rows=out["cell_rows"],
         key_index=col_index,
         events=("segmented",),
     )

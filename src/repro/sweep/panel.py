@@ -32,6 +32,11 @@ class ColumnResult:
     ci_lo: Optional[jax.Array] = None  # (E,) replicate ATE CI
     ci_hi: Optional[jax.Array] = None  # (E,)
     replicates: Optional[jax.Array] = None  # (E, B, p_phi)
+    # segmented columns only: every (segment, fold-complement) nuisance
+    # model, intercept last, and the rows of each (segment, fold) cell
+    beta_y: Optional[jax.Array] = None  # (E, K, p + 1)
+    beta_t: Optional[jax.Array] = None  # (E, K, p + 1)
+    cell_rows: Optional[jax.Array] = None  # (E, K)
     key_index: int = 0  # column index of the key lineage
     shared_nuisance: bool = False  # residuals reused from key_index
     events: Tuple[str, ...] = ()  # runtime chunk/downgrade events

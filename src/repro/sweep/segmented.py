@@ -20,13 +20,18 @@ per-segment one-hot Grams over the residuals.
 
 Everything streams through ``core.moments`` (``fold_gram`` honors
 ``cfg.row_block``), so no per-segment data copy and no (E, n) weight
-tensor ever materializes.  ``cfg.row_block_strategy="pallas"`` swaps
-the one-hot einsums (the fold Grams, the MM gradient terms, the
+tensor ever materializes.  ONE fold Gram pass over [X | 1 | y] serves
+both nuisances: the y ridge solves and the logistic majorizer's design
+Gram are blocks of it.  ``cfg.row_block_strategy="pallas"`` swaps the
+one-hot einsums (the fold Grams, the MM gradient terms, the
 per-segment final stage) for the fused segment-Gram kernels of
 ``repro.kernels.seg_gram`` — the (n, E·k) masks never materialize at
-all, which is the measured CPU/TPU win on the MM hot loop.  This is the "software that estimates many
-effects cheaply" execution (Wong 2020): benchmarks/bench_sweep.py
-measures ~10x over the serial loop at E=64 on CPU.
+all — and runs each MM step as one lane-major kernel pass with the
+(E, k, q) coefficients resident (``ops.mm_logistic_grad``), so no
+(n, k) residual or (n, k, q) per-row coefficients reach HBM either.
+This is the "software that estimates many effects cheaply" execution
+(Wong 2020): benchmarks/bench_sweep.py measures ~10x over the serial
+loop at E=64 on CPU.
 
 Contract: a *different execution* of the same estimator, not the same
 bits — like ``engine="parallel_loo"`` vs ``"parallel"``, it shares one
@@ -63,14 +68,10 @@ def segmented_supported(rspec: EstimatorSpec, cfg: CausalConfig) -> bool:
     return rspec.name.startswith("dml") and cfg.nuisance_y == "ridge" and t_kind_ok
 
 
-def _aug(X: jax.Array) -> jax.Array:
-    return jnp.concatenate([X, jnp.ones((X.shape[0], 1), X.dtype)], axis=1)
-
-
-def _segment_fold_ridge(X, target, comb, n_segments, k, lam, row_block, strategy):
-    """EXACT per-(segment, fold-complement) ridge via the LOO identity:
-    one fold_gram pass over the combined segment×fold id (the target
-    rides as an appended design column), then E·K tiny solves."""
+def _fold_grams(X, target, comb, n_segments, k, row_block, strategy):
+    """One fold_gram pass over the combined segment×fold id with the
+    target riding as an appended design column: the (E, k, q + 1, q + 1)
+    held-out Grams of [X | 1 | target] and the (E, k) row counts."""
     q = X.shape[1] + 1
     Gh, counts = moments.fold_gram(
         X,
@@ -81,65 +82,78 @@ def _segment_fold_ridge(X, target, comb, n_segments, k, lam, row_block, strategy
         row_block=row_block,
         strategy=strategy,
     )
-    Gh = Gh.reshape(n_segments, k, q + 1, q + 1)
-    counts = counts.reshape(n_segments, k)
-    Gseg = Gh.sum(axis=1)
-    A_aug = Gseg[:, None] - Gh  # complement Grams
+    return Gh.reshape(n_segments, k, q + 1, q + 1), counts.reshape(n_segments, k)
+
+
+def _complement(Gh, counts):
+    """LOO identity: fold-complement Grams and their row counts."""
+    Gc = Gh.sum(axis=1, keepdims=True) - Gh
     n_eff = jnp.maximum(counts.sum(1, keepdims=True) - counts, 1.0)
-    A = A_aug[..., :q, :q] / n_eff[..., None, None] + lam * jnp.eye(q, dtype=_F32)
-    b = A_aug[..., :q, q] / n_eff[..., None]
-    beta = jax.vmap(jax.vmap(det_solve))(A, b)  # (E, k, q)
-    return beta, n_eff
+    return Gc, n_eff
 
 
-def _segment_fold_logistic(
-    Xa, tt, sids, folds, comb, n_segments, k, lam, iters, row_block, strategy
-):
+def _ridge(Gc, n_eff, lam):
+    """EXACT per-(segment, fold-complement) ridge: E·K tiny solves of
+    the complement normal equations (target in the last column)."""
+    q = Gc.shape[-1] - 1
+    A = Gc[..., :q, :q] / n_eff[..., None, None] + lam * jnp.eye(q, dtype=_F32)
+    b = Gc[..., :q, q] / n_eff[..., None]
+    return jax.vmap(jax.vmap(det_solve))(A, b)  # (E, k, q)
+
+
+def _oof_predict(xa_t, beta, comb):
+    """(n,) out-of-fold predictions: each row read once by its own
+    (segment, fold) model, Σ_j xa[j] · β[comb, j] in float32.  Lane-major
+    (``xa_t`` is [X | 1]ᵀ), one (n,) gather per coefficient: an (n, q)
+    gather would pad each row to 128 lanes in HBM."""
+    E, k, q = beta.shape
+    table = beta.reshape(E * k, q).T
+    return sum(xa_t[j] * table[j][comb] for j in range(q))
+
+
+def _segment_fold_logistic(xa_t, tt, sids, folds, Gc, n_eff, lam, iters, strategy):
     """Per-(segment, fold-complement) logistic via the Böhning-Lindsay
-    fixed majorizer: H0 factored from one segmented Gram pass, then
-    ``iters`` MM steps of segment-gathered matvecs (each step reads the
-    data once — no per-cell Gram rebuilds)."""
-    q = Xa.shape[1]
-    GhX, counts = moments.fold_gram(
-        Xa, comb, n_segments * k, row_block=row_block, strategy=strategy
-    )
-    GhX = GhX.reshape(n_segments, k, q, q)
-    counts = counts.reshape(n_segments, k)
-    GsegX = GhX.sum(axis=1)
-    n_eff = jnp.maximum(counts.sum(1, keepdims=True) - counts, 1.0)
-    H0 = (GsegX[:, None] - GhX) / (4.0 * n_eff[..., None, None]) + lam * jnp.eye(
+    fixed majorizer: H0 = Gram/4 + λI from the complement Grams of the
+    design (no pass of its own), then ``iters`` MM steps, each one pass
+    over the rows for the complement gradient.
+
+    Under strategy="pallas" a step is ONE seg_gram pass
+    (``mm_logistic_grad``) over lane-major rows, [X | 1]ᵀ and [t;
+    cohort + 1; fold], with the (E, k, q) coefficients resident in VMEM:
+    the logits, mu - t and the fold complement form in registers, so no
+    (n, k) residual or (n, k, q) per-row coefficients ever reach HBM.
+    Otherwise the one-hot einsums: held-in sums per segment (t1) minus
+    own-fold sums (t2)."""
+    n_segments, k, q = Gc.shape[0], Gc.shape[1], xa_t.shape[0]
+    H0 = Gc[..., :q, :q] / (4.0 * n_eff[..., None, None]) + lam * jnp.eye(
         q, dtype=_F32
     )
     if strategy == "pallas":
-        # the fused segment-outer kernels replace the one-hot einsums:
-        # neither the (n, E) nor the (n, E·k) mask ever materializes.
-        # In-loop calls run whole-array (row_block=0): the transient
-        # (n, k·q) outer is SMALLER than the (n, E·k) one-hot it
-        # replaces, and the MM loop is the measured sweep hot spot.
         from repro.kernels.seg_gram import ops as sg_ops
 
-        def grad_terms(r, rr):
-            t1 = sg_ops.segment_outer(r, Xa, sids, n_segments)
-            t2 = sg_ops.segment_outer(rr[:, None], Xa, comb, n_segments * k)
-            return t1, t2.reshape(n_segments, k, q)
+        # lane-major, packed once per sweep: 64 + 32 bytes a row in HBM
+        # where an (n, q + 3) operand would pad each row to 512
+        meta_t = jnp.stack([tt, (sids + 1).astype(_F32), folds.astype(_F32)])
+
+        def grad(beta):
+            return sg_ops.mm_logistic_grad(xa_t, meta_t, beta)
 
     else:
+        Xa = xa_t.T
         oh_seg = jax.nn.one_hot(sids, n_segments, dtype=_F32)  # (n, E)
-        oh_comb = jax.nn.one_hot(comb, n_segments * k, dtype=_F32)  # (n, E·k)
+        oh_comb = jax.nn.one_hot(sids * k + folds, n_segments * k, dtype=_F32)
 
-        def grad_terms(r, rr):
+        def grad(beta):
+            bs = beta[sids]  # (n, k, q)
+            mu = jax.nn.sigmoid(jnp.einsum("np,nkp->nk", Xa, bs))
+            r = mu - tt[:, None]  # (n, k)
+            rr = jnp.take_along_axis(r, folds[:, None], axis=1)[:, 0]
             t1 = jnp.einsum("ns,nk,np->skp", oh_seg, r, Xa)
             t2 = jnp.einsum("nc,n,np->cp", oh_comb, rr, Xa)
-            return t1, t2.reshape(n_segments, k, q)
+            return t1 - t2.reshape(n_segments, k, q)
 
     def _step(_, beta):  # beta: (E, k, q)
-        bs = beta[sids]  # (n, k, q)
-        mu = jax.nn.sigmoid(jnp.einsum("np,nkp->nk", Xa, bs))
-        r = mu - tt[:, None]  # (n, k)
-        # held-in sums per segment minus own-fold sums = complement
-        rr = jnp.take_along_axis(r, folds[:, None], axis=1)[:, 0]
-        t1, t2 = grad_terms(r, rr)
-        g = (t1 - t2) / n_eff[..., None] + lam * beta
+        g = grad(beta) / n_eff[..., None] + lam * beta
         return beta - jax.vmap(jax.vmap(det_solve))(H0, g)
 
     return jax.lax.fori_loop(0, iters, _step, jnp.zeros((n_segments, k, q), _F32))
@@ -173,7 +187,8 @@ def _segment_final_stage(
     else:
         meat = jnp.einsum("ns,ni,nj->sij", oh_seg, me, me)
     ainv = jax.vmap(det_inv)(a)
-    cov = jnp.einsum("sia,sab,sbj->sij", ainv, meat, ainv)
+    cov = jnp.einsum("sia,sab,sbj->sij", ainv, meat, ainv,
+                     precision=jax.lax.Precision.HIGHEST)
     se = jnp.sqrt(jnp.clip(jnp.diagonal(cov, axis1=1, axis2=2), 0.0, None))
     return theta, se
 
@@ -189,7 +204,10 @@ def segmented_dml_sweep(
 ) -> Dict[str, jax.Array]:
     """All E per-segment DML fits from one segmented pass: shared fold
     assignment, LOO-identity ridge + MM logistic nuisances, per-segment
-    final stage.  Returns {"theta" (E, p), "se" (E, p), "ate" (E,)}."""
+    final stage.  Returns {"theta" (E, p), "se" (E, p), "ate" (E,),
+    "beta_y", "beta_t" (E, K, p + 1): every (segment, fold-complement)
+    nuisance model, intercept last, "cell_rows" (E, K): rows per
+    (segment, fold)}."""
     n = X.shape[0]
     k = cfg.n_folds
     lam = cfg.ridge_lambda
@@ -197,29 +215,31 @@ def segmented_dml_sweep(
     folds = fold_ids(key, n, k)
     comb = sids * k + folds  # (n,) in [0, E·k)
 
-    beta_y, _ = _segment_fold_ridge(X, y, comb, n_segments, k, lam, rb, st)
-    xa = _aug(X.astype(_F32))
+    # one pass serves the y ridge and the logistic majorizer's design Gram
+    Gh, counts = _fold_grams(X, y, comb, n_segments, k, rb, st)
+    Gc, n_eff = _complement(Gh, counts)
+    beta_y = _ridge(Gc, n_eff, lam)
+    xa_t = jnp.concatenate([X.astype(_F32).T, jnp.ones((1, n), _F32)])  # [X | 1]ᵀ
     tt = t.astype(_F32)
     mm_iters = 2 * cfg.newton_iters  # MM trades per-step cost for steps
     if cfg.discrete_treatment:
         beta_t = _segment_fold_logistic(
-            xa, tt, sids, folds, comb, n_segments, k, lam, mm_iters, rb, st
+            xa_t, tt, sids, folds, Gc, n_eff, lam, mm_iters, st
         )
-        mt = jax.nn.sigmoid(jnp.einsum("np,np->n", xa, beta_t[sids, folds]))
+        mt = jax.nn.sigmoid(_oof_predict(xa_t, beta_t, comb))
     else:
-        beta_t, _ = _segment_fold_ridge(X, t, comb, n_segments, k, lam, rb, st)
-        mt = jnp.einsum("np,np->n", xa, beta_t[sids, folds])
-
-    # out-of-fold predictions: each row read once by its own
-    # (segment, fold) model — a gather, not an (E, n) prediction matrix
-    my = jnp.einsum("np,np->n", xa, beta_y[sids, folds])
+        grams = _fold_grams(X, t, comb, n_segments, k, rb, st)
+        beta_t = _ridge(*_complement(*grams), lam)
+        mt = _oof_predict(xa_t, beta_t, comb)
+    my = _oof_predict(xa_t, beta_y, comb)
     ry = y.astype(_F32) - my
     rt = tt - mt
     phi = cate_basis(X, cfg.cate_features)
     theta, se = _segment_final_stage(
         ry, rt, phi, sids, n_segments, row_block=rb, strategy=st
     )
-    return {"theta": theta, "se": se, "ate": theta[:, 0]}
+    return {"theta": theta, "se": se, "ate": theta[:, 0], "beta_y": beta_y,
+            "beta_t": beta_t, "cell_rows": counts}
 
 
 _JITTED: Dict[Any, Any] = {}

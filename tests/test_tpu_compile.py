@@ -145,3 +145,23 @@ def test_bootstrap_det_solve_panelled(one_chip):
     assert "tpu_custom_call" not in compiled.as_text()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 4 * mem.argument_size_in_bytes, mem
+
+
+def test_sweep_mm_step_fused(one_chip):
+    """One MM logistic step of the binary uplift sweep (n = 4,194,304,
+    p = 12, E = 64 cohorts x K = 5 folds): the lane-major kernel reads
+    [X | 1]ᵀ and [t; cohort + 1; fold] with the (E, K, q) coefficients
+    resident.  Its temp stays under the n·K·q floats of per-row
+    coefficients, which the gather ``beta[sids]`` it replaces took
+    (16 GB in (8, 128) tiles)."""
+    n, E, K, q = 4_194_304, 64, 5, 13
+
+    def step(xa_t, meta_t, coef):
+        table = jnp.transpose(coef, (1, 2, 0)).reshape(K * q, E)
+        return sg_kernel.seg_gram_lanes(sg_ref.build_mm_logistic,
+                                        [xa_t, meta_t, table], interpret=False)
+
+    compiled = _compile(step, _spec((q, n), one_chip), _spec((3, n), one_chip),
+                        _spec((E, K, q), one_chip))
+    assert "%seg_gram_mm_logistic." in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < n * K * q * 4
