@@ -56,6 +56,9 @@ class FitContext:
     nuis_t: Nuisance
     rules: Any = None
     tracer: Any = None  # the DML's explicit repro.obs Tracer, if any
+    # the DML's replicate closures (dml_bootstrap's ``replicate_fns``)
+    replicate_fns: Optional[Dict[Any, Any]] = dataclasses.field(
+        default=None, repr=False, compare=False)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -96,7 +99,8 @@ class DMLResult(SandwichEffectResult):
             n_replicates=n_boot, scheme=resolve_scheme(method),
             executor=exe, point=self.theta, point_se=self.stderr,
             rules=ctx.rules, row_block=self.cfg.row_block,
-            strategy=self.cfg.row_block_strategy, **rt_kw)
+            strategy=self.cfg.row_block_strategy,
+            replicate_fns=ctx.replicate_fns, **rt_kw)
 
     def _summary_extra(self):
         d = self.diagnostics
@@ -110,7 +114,12 @@ class DML:
     """The estimator facade.  Nuisances default from the CausalConfig;
     pass explicit ``Nuisance`` objects to override (e.g. tuned models
     from repro.core.tuning, or backbone-feature heads).  ``tracer``
-    (a repro.obs Tracer) records the fit and its replicate inference."""
+    (a repro.obs Tracer) records the fit and its replicate inference.
+
+    The estimator keeps the bootstrap's replicate closures for its own
+    life (``_replicate_fns``, keyed by what each closure bakes in), so
+    every fit after the first reuses the memory model and the compiled
+    chunk programs the runtime cached on them."""
 
     def __init__(self, cfg: CausalConfig,
                  nuisance_y: Optional[Nuisance] = None,
@@ -122,6 +131,7 @@ class DML:
         self.nuis_t = nuisance_t or make_nuisance(cfg.nuisance_t, t_task, cfg)
         self.rules = rules
         self.tracer = tracer
+        self._replicate_fns: Dict[Any, Any] = {}
 
     def fit(self, y: jax.Array, t: jax.Array, X: jax.Array,
             W: Optional[jax.Array] = None,
@@ -146,7 +156,8 @@ class DML:
                                            phi @ fs.theta)
         ctx = FitContext(y=y, t=t, XW=XW, phi=phi, key=key,
                          nuis_y=self.nuis_y, nuis_t=self.nuis_t,
-                         rules=self.rules, tracer=self.tracer)
+                         rules=self.rules, tracer=self.tracer,
+                         replicate_fns=self._replicate_fns)
         return DMLResult(theta=fs.theta, cov=fs.cov, cfg=self.cfg,
                          crossfit=cf, final=fs, diagnostics=diag,
                          fit_ctx=ctx)

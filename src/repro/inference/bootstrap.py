@@ -146,9 +146,13 @@ def make_dml_replicate_fn(nuis_y: Nuisance, nuis_t: Nuisance,
     """The bootstrap replicate closure: (key, XW, y, t, phi) ->
     {theta[, se]}.  The data tensors arrive as executor pass-through
     arguments (not closure constants) so compiled programs take them as
-    real inputs; build the closure ONCE and reuse it across
-    executor.map calls — executors key their compiled-program caches on
-    the closure object."""
+    real inputs.  The runtime keys its memory probes, its compiled
+    chunk programs and the executors' jit caches on the closure object,
+    so the closure is built once per estimator and reused by every fit:
+    ``DML`` owns a dict of them (handed to ``dml_bootstrap`` as
+    ``replicate_fns`` through its ``FitContext``), and the closures,
+    with the programs cached on them, live as long as the estimator or
+    one of its results does."""
 
     def replicate(kb, XW, y, t, phi):
         with jax.named_scope("inference.replicate"):
@@ -172,25 +176,45 @@ def dml_bootstrap(nuis_y: Nuisance, nuis_t: Nuisance, *, n_folds: int,
                   mesh=None, rules=None,
                   row_block: int = 0, strategy: Optional[str] = None,
                   memory_budget: int = 0, chunk: int = 0,
-                  max_retries: int = 2, tracer=None) -> InferenceResult:
+                  max_retries: int = 2, tracer=None,
+                  replicate_fns: Optional[dict] = None) -> InferenceResult:
     """B weighted DML refits scheduled by the task runtime: the
     replicate axis streams in memory-budgeted chunks (repro.runtime),
     each chunk retrying down the backend ladder on failure — results
     are replicate-ordered and bit-identical across all of it.  The
     ``inference.bootstrap`` span goes to ``tracer`` (a repro.obs
-    Tracer) or the runtime's, else to the process tracer."""
+    Tracer) or the runtime's, else to the process tracer.
+
+    ``replicate_fns`` is the caller's cache of replicate closures (the
+    estimator's, so a warm fit finds its memory model and compiled
+    chunks in the runtime's caches); without it every call builds a
+    fresh closure.  Each call counts ``inference.replicate_fn[built]``
+    or ``[reused]`` on the process registry, and the span carries the
+    same word as ``replicate_fn``."""
+    from repro.obs.metrics import default_registry
     from repro.obs.trace import layer_span
     from repro.runtime import as_runtime
     rt = as_runtime(executor, mesh=mesh, rules=rules,
                     memory_budget=memory_budget, chunk=chunk,
                     max_retries=max_retries, tracer=tracer)
     with layer_span(rt.tracer, "inference.bootstrap", cat="inference",
-                    b=n_replicates, scheme=scheme):
+                    b=n_replicates, scheme=scheme) as sp:
         keys = replicate_keys(key, n_replicates)
-        replicate = make_dml_replicate_fn(nuis_y, nuis_t, n_folds,
-                                          scheme=scheme, with_se=with_se,
-                                          row_block=row_block,
-                                          strategy=strategy)
+        # everything the closure bakes in
+        fn_key = (nuis_y, nuis_t, n_folds, scheme, with_se, row_block,
+                  strategy)
+        replicate = (replicate_fns or {}).get(fn_key)
+        status = "built" if replicate is None else "reused"
+        if replicate is None:
+            replicate = make_dml_replicate_fn(nuis_y, nuis_t, n_folds,
+                                              scheme=scheme,
+                                              with_se=with_se,
+                                              row_block=row_block,
+                                              strategy=strategy)
+            if replicate_fns is not None:
+                replicate_fns[fn_key] = replicate
+        default_registry().counter(f"inference.replicate_fn[{status}]").inc()
+        sp.attrs["replicate_fn"] = status
         out = rt.map(replicate, keys, XW, y, t, phi, label="dml_bootstrap")
         thetas = out["theta"]
         se = jnp.std(thetas, axis=0, ddof=1)

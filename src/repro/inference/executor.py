@@ -77,11 +77,26 @@ def jit_miss_hook(cb: Callable[[Any], None]):
         _JIT_MISS_HOOKS.remove(cb)
 
 
+def _weak_call(fn):
+    """``fn`` called through a weak reference, under ``fn``'s name (the
+    jitted program keeps it).  A cached wrapper built on ``fn`` itself
+    would pin its own weak key, and the closure, with everything it
+    captures, could never be freed."""
+    ref = weakref.ref(fn)
+
+    def call(*a):
+        return ref()(*a)
+
+    call.__name__ = getattr(fn, "__name__", call.__name__)
+    call.__qualname__ = getattr(fn, "__qualname__", call.__qualname__)
+    return call
+
+
 class _JitCache:
     """Per-executor compiled-program reuse: ``map(fn, ...)`` called twice
     with the SAME closure object hits the same jit wrapper (and thus its
     compilation cache) instead of re-tracing.  Weak keys let dead
-    closures drop out."""
+    closures drop out: the wrapper reaches ``fn`` only weakly."""
 
     def __init__(self):
         self._cache = weakref.WeakKeyDictionary()
@@ -92,7 +107,7 @@ class _JitCache:
             if _JIT_MISS_HOOKS:
                 for hook in tuple(_JIT_MISS_HOOKS):
                     hook(fn)
-            f = build(fn)
+            f = build(_weak_call(fn))
             self._cache[fn] = f
         return f
 

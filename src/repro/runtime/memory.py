@@ -24,8 +24,11 @@ The probe batches stay small: a probe that does not fit the device
 fails to compile (and then the map runs unsized), so the model is
 fitted where the program fits and extrapolated from there.  Probes
 are compile-only (no execution) and cached per (closure, input
-signature), so repeated ``map`` calls with the same closure — the hot
-pattern everywhere in this codebase — lower at most twice.
+signature), so repeated ``map`` calls with the same closure lower at
+most twice.  The caller owns the closure and decides how long it and
+its probes live: the caches here hold it weakly.  ``DML`` keeps its
+bootstrap closures for the estimator's life, so only its first fit
+probes; a closure built per call is probed on every call.
 """
 
 from __future__ import annotations
