@@ -4,9 +4,7 @@ runtime at growing data scales.
 On this host the mesh is one CPU device, so the measured speedup isolates
 the paper's MECHANISM — K sequential fit programs vs one batched
 fold-parallel program (dispatch overhead, compile reuse, shared data
-passes) — rather than multi-node scaling, which the dry-run covers
-(benchmarks/bench_dryrun.py renders the 256-chip roofline for the same
-workload).
+passes) — rather than multi-node scaling.
 
 Defaults are CPU-friendly; ``--full`` runs the paper's exact
 10k/100k/1M x 500 sweep.

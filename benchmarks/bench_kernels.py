@@ -1,7 +1,7 @@
-"""Kernel micro-benchmarks: wall time of the jnp reference paths on this
-host (the Pallas variants are TPU-target; their interpret-mode execution
-measures Python, not hardware, so we report the ref path + derived
-bandwidth/intensity numbers that feed the §Roofline discussion)."""
+"""Kernel micro-benchmark: wall time of the ``residual_gram`` jnp
+reference path on this host (the Pallas variant is TPU-target; its
+interpret-mode execution measures Python, not hardware, so we report the
+ref path + the derived streaming bandwidth)."""
 from __future__ import annotations
 
 import time
@@ -9,9 +9,7 @@ import time
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.flash_attention import ops as fa_ops
 from repro.kernels.residual_gram import ops as rg_ops
-from repro.kernels.ssm_scan import ops as gla_ops
 
 
 def _timeit(f, *args, reps=5):
@@ -22,31 +20,6 @@ def _timeit(f, *args, reps=5):
         out = f(*args)
     jax.block_until_ready(out)
     return (time.perf_counter() - t0) / reps
-
-
-def bench_flash(csv=print):
-    B, S, H, KV, D = 1, 1024, 8, 2, 64
-    key = jax.random.PRNGKey(0)
-    q = jax.random.normal(key, (B, S, H, D), jnp.float32)
-    k = jax.random.normal(key, (B, S, KV, D), jnp.float32)
-    v = jax.random.normal(key, (B, S, KV, D), jnp.float32)
-    f = jax.jit(lambda q, k, v: fa_ops.flash_attention(q, k, v, causal=True,
-                                                       backend="ref"))
-    t = _timeit(f, q, k, v)
-    flops = 4 * B * H * S * S * D  # qk + pv
-    csv(f"flash_attention_ref_S{S},{t*1e6:.0f},gflops={flops/t/1e9:.1f}")
-
-
-def bench_gla(csv=print):
-    B, H, T, Dk, Dv = 1, 8, 2048, 64, 64
-    key = jax.random.PRNGKey(0)
-    q = jax.random.normal(key, (B, H, T, Dk))
-    k = jax.random.normal(key, (B, H, T, Dk))
-    v = jax.random.normal(key, (B, H, T, Dv))
-    w = 0.5 + 0.5 * jax.random.uniform(key, (B, H, T, Dk))
-    f = jax.jit(lambda *a: gla_ops.gla(*a, chunk=16)[0])
-    t = _timeit(f, q, k, v, w)
-    csv(f"gla_scan_ref_T{T},{t*1e6:.0f},tokens_per_s={B*T/t:.0f}")
 
 
 def bench_residual_gram(csv=print):
@@ -63,8 +36,6 @@ def bench_residual_gram(csv=print):
 
 
 def main(csv=print):
-    bench_flash(csv)
-    bench_gla(csv)
     bench_residual_gram(csv)
 
 

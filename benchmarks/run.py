@@ -185,10 +185,6 @@ def main(argv=None):
         from benchmarks import bench_kernels
         bench_kernels.main(csv=rec)
 
-        print("# --- multi-pod dry-run roofline (deliverable e/g) ---")
-        from benchmarks import bench_dryrun
-        bench_dryrun.main([], csv=rec)
-
     if args.json:
         payload = {
             "meta": {

@@ -1,52 +1,3 @@
-"""Architecture registry: ``get_config("<arch-id>")`` -> ModelConfig.
-
-One module per assigned architecture (exact public-literature configs)
-plus the paper's own synthetic-DML study config (``dml_synthetic``).
-"""
-from __future__ import annotations
-
-import importlib
-from typing import Dict, List
-
-from repro.config import ModelConfig, smoke_variant
-
-ARCH_IDS: List[str] = [
-    "yi-34b",
-    "granite-3-2b",
-    "phi4-mini-3.8b",
-    "chatglm3-6b",
-    "pixtral-12b",
-    "zamba2-1.2b",
-    "arctic-480b",
-    "deepseek-v3-671b",
-    "whisper-tiny",
-    "rwkv6-3b",
-]
-
-_MODULES: Dict[str, str] = {
-    "yi-34b": "yi_34b",
-    "granite-3-2b": "granite_3_2b",
-    "phi4-mini-3.8b": "phi4_mini_3_8b",
-    "chatglm3-6b": "chatglm3_6b",
-    "pixtral-12b": "pixtral_12b",
-    "zamba2-1.2b": "zamba2_1_2b",
-    "arctic-480b": "arctic_480b",
-    "deepseek-v3-671b": "deepseek_v3_671b",
-    "whisper-tiny": "whisper_tiny",
-    "rwkv6-3b": "rwkv6_3b",
-}
-
-
-def get_config(arch: str) -> ModelConfig:
-    name = arch[:-len("-smoke")] if arch.endswith("-smoke") else arch
-    if name not in _MODULES:
-        raise KeyError(f"unknown arch {arch!r}; known: {sorted(_MODULES)}")
-    mod = importlib.import_module(f"repro.configs.{_MODULES[name]}")
-    cfg: ModelConfig = mod.CONFIG
-    if arch.endswith("-smoke"):
-        return smoke_variant(cfg)
-    return cfg
-
-
-def all_configs() -> Dict[str, ModelConfig]:
-    return {a: get_config(a) for a in ARCH_IDS}
+"""The paper's synthetic study configurations: ``dml_synthetic`` (DML,
+Fig. 6), ``iv_synthetic`` (OrthoIV / DRIV) and ``sweep_synthetic`` (the
+E = 64 segment sweep).  Import the module for its config."""

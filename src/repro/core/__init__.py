@@ -22,7 +22,7 @@ panels in ``repro.sweep``; incremental refresh in ``repro.store``.
 #   crossfit.py     C1 fold-parallel cross-fitting (+ sequential baseline)
 #   tuning.py       C2 population-axis hyper-parameter search
 #   dml.py          the estimator facade (DML / DML_Ray translation)
-#   nuisance.py     MXU-native nuisance zoo (ridge/logistic/MLP/backbone)
+#   nuisance.py     MXU-native nuisance zoo (ridge/logistic/MLP)
 #   final_stage.py  orthogonal moment via the fused residual_gram kernel
 #   iv.py           orthogonal-IV family (OrthoIV / DRIV) on the same
 #                   moments + crossfit + runtime substrate

@@ -30,7 +30,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.nuisance import Nuisance
-from repro.distributed.sharding import constrain
 
 
 def fold_ids(key: jax.Array, n: int, k: int) -> jax.Array:
@@ -69,7 +68,7 @@ def _fold_fit_fn(nuis: Nuisance):
 
 def _crossfit_engine(nuis: Nuisance, keys: jax.Array, X: jax.Array,
                      target: jax.Array, folds: jax.Array, k: int,
-                     rules, executor, tracer=None) -> Tuple[jax.Array, Any]:
+                     executor, tracer=None) -> Tuple[jax.Array, Any]:
     """The shared fold-fit dispatch: the fold axis (init keys + fold-
     complement weights) maps through the task runtime, so fold fits,
     tuning trials, and bootstrap replicates all run through one "how
@@ -81,7 +80,7 @@ def _crossfit_engine(nuis: Nuisance, keys: jax.Array, X: jax.Array,
     neither, the span goes to the process tracer)."""
     from repro.obs.trace import layer_span
     from repro.runtime import as_runtime
-    rt = as_runtime(executor, rules=rules, tracer=tracer)
+    rt = as_runtime(executor, tracer=tracer)
     W = fold_weights(folds, k)                      # (k, n)
     label = f"crossfit:{nuis.name}"
     with layer_span(rt.tracer, label, cat="crossfit", k=k,
@@ -90,24 +89,22 @@ def _crossfit_engine(nuis: Nuisance, keys: jax.Array, X: jax.Array,
                                X, target, label=label)
         if rt.tracer is not None:
             rt.tracer.sync((preds, states))
-    preds = constrain(preds, ("fold", "batch"), rules)
     return _oof_select(preds, folds), states
 
 
 def crossfit_parallel(nuis: Nuisance, key: jax.Array, X: jax.Array,
                       target: jax.Array, folds: jax.Array, k: int,
-                      rules=None, executor="vmap", tracer=None
+                      executor="vmap", tracer=None
                       ) -> Tuple[jax.Array, Any]:
     """C1: all K fold-fits in ONE batched program (the Ray-tasks
     translation).  Returns (out-of-fold predictions (n,), states)."""
     keys = jax.random.split(key, k)
-    return _crossfit_engine(nuis, keys, X, target, folds, k, rules,
-                            executor, tracer)
+    return _crossfit_engine(nuis, keys, X, target, folds, k, executor, tracer)
 
 
 def crossfit_parallel_loo(nuis: Nuisance, key: jax.Array, X: jax.Array,
                           target: jax.Array, folds: jax.Array, k: int,
-                          rules=None, mm_iters: int = 32):
+                          mm_iters: int = 32):
     """C1+ (beyond-paper, EXPERIMENTS §Perf): the leave-one-out Gram
     identity collapses the K complement fits to ONE fold-segmented
     moments pass over X (row-blocked when the nuisance carries a
@@ -127,9 +124,8 @@ def crossfit_parallel_loo(nuis: Nuisance, key: jax.Array, X: jax.Array,
         states = logistic_fit_folds(lam, mm_iters, X, target, folds, k,
                                     row_block=rb, strategy=st)
     else:
-        return crossfit_parallel(nuis, key, X, target, folds, k, rules)
+        return crossfit_parallel(nuis, key, X, target, folds, k)
     preds = jax.vmap(nuis.predict, in_axes=(0, None))(states, X)
-    preds = constrain(preds, ("fold", "batch"), rules)
     return _oof_select(preds, folds), states
 
 
@@ -143,8 +139,7 @@ def crossfit_sequential(nuis: Nuisance, key: jax.Array, X: jax.Array,
     ``fold_in(key, j)`` lineage."""
     keys = jax.vmap(lambda j: jax.random.fold_in(key, j))(
         jnp.arange(k, dtype=jnp.uint32))
-    return _crossfit_engine(nuis, keys, X, target, folds, k, None,
-                            "serial", tracer)
+    return _crossfit_engine(nuis, keys, X, target, folds, k, "serial", tracer)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -158,7 +153,7 @@ class CrossfitResult:
 
 def crossfit_one(nuis: Nuisance, key: jax.Array, X: jax.Array,
                  target: jax.Array, folds: jax.Array, k: int,
-                 engine: str = "parallel", rules=None, tracer=None
+                 engine: str = "parallel", tracer=None
                  ) -> Tuple[jax.Array, Any]:
     """Engine dispatch for ONE cross-fit target over a fixed fold
     assignment — the unit `crossfit` composes twice and the IV
@@ -169,18 +164,17 @@ def crossfit_one(nuis: Nuisance, key: jax.Array, X: jax.Array,
     Executor/TaskRuntime instance maps the fold axis directly.
     ``tracer`` (a repro.obs Tracer) records the fold fits' spans."""
     if engine == "parallel_loo":
-        return crossfit_parallel_loo(nuis, key, X, target, folds, k, rules)
+        return crossfit_parallel_loo(nuis, key, X, target, folds, k)
     if engine == "sequential":
         return crossfit_sequential(nuis, key, X, target, folds, k, tracer)
     exe = "vmap" if engine == "parallel" else engine
-    return crossfit_parallel(nuis, key, X, target, folds, k, rules,
+    return crossfit_parallel(nuis, key, X, target, folds, k,
                              executor=exe, tracer=tracer)
 
 
 def crossfit(nuis_y: Nuisance, nuis_t: Nuisance, key: jax.Array,
              X: jax.Array, y: jax.Array, t: jax.Array, k: int,
-             engine: str = "parallel", rules=None,
-             tracer=None) -> CrossfitResult:
+             engine: str = "parallel", tracer=None) -> CrossfitResult:
     """Cross-fit both nuisances.  engine: "parallel" (paper) dispatches
     the 2·K fits through the ``vmap`` Executor; "sequential" (EconML
     baseline) through ``serial``; "parallel_loo" takes the one-pass
@@ -188,9 +182,7 @@ def crossfit(nuis_y: Nuisance, nuis_t: Nuisance, key: jax.Array,
     Executor instance maps the fold axis directly."""
     kf, ky, kt = jax.random.split(key, 3)
     folds = fold_ids(kf, X.shape[0], k)
-    oof_y, st_y = crossfit_one(nuis_y, ky, X, y, folds, k, engine, rules,
-                               tracer)
-    oof_t, st_t = crossfit_one(nuis_t, kt, X, t, folds, k, engine, rules,
-                               tracer)
+    oof_y, st_y = crossfit_one(nuis_y, ky, X, y, folds, k, engine, tracer)
+    oof_t, st_t = crossfit_one(nuis_t, kt, X, t, folds, k, engine, tracer)
     return CrossfitResult(oof_y=oof_y, oof_t=oof_t, folds=folds,
                           states_y=st_y, states_t=st_t)

@@ -54,7 +54,6 @@ class FitContext:
     key: jax.Array
     nuis_y: Nuisance
     nuis_t: Nuisance
-    rules: Any = None
     tracer: Any = None  # the DML's explicit repro.obs Tracer, if any
     # the DML's replicate closures (dml_bootstrap's ``replicate_fns``)
     replicate_fns: Optional[Dict[Any, Any]] = dataclasses.field(
@@ -90,7 +89,7 @@ class DMLResult(SandwichEffectResult):
             return delete_fold_jackknife(
                 ctx.y, ctx.t, cf.oof_y, cf.oof_t, cf.folds, ctx.phi,
                 self.cfg.n_folds, alpha=alpha, executor=exe,
-                point=self.theta, point_se=self.stderr, rules=ctx.rules,
+                point=self.theta, point_se=self.stderr,
                 row_block=self.cfg.row_block, **rt_kw)
         return dml_bootstrap(
             ctx.nuis_y, ctx.nuis_t, n_folds=self.cfg.n_folds,
@@ -98,7 +97,7 @@ class DMLResult(SandwichEffectResult):
             key=jax.random.fold_in(ctx.key, 0x0b00), alpha=alpha,
             n_replicates=n_boot, scheme=resolve_scheme(method),
             executor=exe, point=self.theta, point_se=self.stderr,
-            rules=ctx.rules, row_block=self.cfg.row_block,
+            row_block=self.cfg.row_block,
             strategy=self.cfg.row_block_strategy,
             replicate_fns=ctx.replicate_fns, **rt_kw)
 
@@ -113,8 +112,8 @@ class DMLResult(SandwichEffectResult):
 class DML:
     """The estimator facade.  Nuisances default from the CausalConfig;
     pass explicit ``Nuisance`` objects to override (e.g. tuned models
-    from repro.core.tuning, or backbone-feature heads).  ``tracer``
-    (a repro.obs Tracer) records the fit and its replicate inference.
+    from repro.core.tuning).  ``tracer`` (a repro.obs Tracer) records
+    the fit and its replicate inference.
 
     The estimator keeps the bootstrap's replicate closures for its own
     life (``_replicate_fns``, keyed by what each closure bakes in), so
@@ -123,13 +122,11 @@ class DML:
 
     def __init__(self, cfg: CausalConfig,
                  nuisance_y: Optional[Nuisance] = None,
-                 nuisance_t: Optional[Nuisance] = None,
-                 rules=None, tracer=None):
+                 nuisance_t: Optional[Nuisance] = None, tracer=None):
         self.cfg = cfg
         t_task = "clf" if cfg.discrete_treatment else "reg"
         self.nuis_y = nuisance_y or make_nuisance(cfg.nuisance_y, "reg", cfg)
         self.nuis_t = nuisance_t or make_nuisance(cfg.nuisance_t, t_task, cfg)
-        self.rules = rules
         self.tracer = tracer
         self._replicate_fns: Dict[Any, Any] = {}
 
@@ -144,19 +141,18 @@ class DML:
             key = key if key is not None else jax.random.PRNGKey(0)
             XW = X if W is None else jnp.concatenate([X, W], axis=1)
             cf = crossfit(self.nuis_y, self.nuis_t, key, XW, y, t,
-                          self.cfg.n_folds, self.cfg.engine, self.rules,
+                          self.cfg.n_folds, self.cfg.engine,
                           tracer=self.tracer)
             with layer_span(self.tracer, "dml.final_stage", cat="estimator"):
                 phi = cate_basis(X, self.cfg.cate_features)
                 fs = fit_final_stage(y, t, cf.oof_y, cf.oof_t, phi,
                                      row_block=self.cfg.row_block,
-                                     strategy=self.cfg.row_block_strategy,
-                                     rules=self.rules)
+                                     strategy=self.cfg.row_block_strategy)
                 diag = compute_diagnostics(y, t, cf.oof_y, cf.oof_t,
                                            phi @ fs.theta)
         ctx = FitContext(y=y, t=t, XW=XW, phi=phi, key=key,
                          nuis_y=self.nuis_y, nuis_t=self.nuis_t,
-                         rules=self.rules, tracer=self.tracer,
+                         tracer=self.tracer,
                          replicate_fns=self._replicate_fns)
         return DMLResult(theta=fs.theta, cov=fs.cov, cfg=self.cfg,
                          crossfit=cf, final=fs, diagnostics=diag,

@@ -16,8 +16,7 @@ All sufficient statistics come from the streaming moments engine
                   the dense Z and the residual vector never
                   materialize — peak temporaries are O(R·p_phi), which
                   is what lets n exceed a single-allocation budget
-                  (paper §5.3 "industrial scale").  Each block is
-                  constrained on the ``rows`` mesh axis; the (p,p)
+                  (paper §5.3 "industrial scale").  The (p,p)
                   moments are the only thing reduced — the same shape
                   as Ray's driver-side aggregation but executed as one
                   psum.  ``strategy="pallas"`` keeps the same two-pass
@@ -66,8 +65,8 @@ class FinalStageResult:
 def fit_final_stage(y: jax.Array, t: jax.Array, my: jax.Array,
                     mt: jax.Array, phi: jax.Array, *,
                     ridge: float = 1e-8, backend: str = "",
-                    row_block: int = 0, strategy: Optional[str] = None,
-                    rules=None) -> FinalStageResult:
+                    row_block: int = 0, strategy: Optional[str] = None
+                    ) -> FinalStageResult:
     """Solve the orthogonal moment.  y,t,my,mt: (n,); phi: (n, p_phi).
 
     ``row_block > 0`` streams every moment in fixed-order row blocks
@@ -77,13 +76,11 @@ def fit_final_stage(y: jax.Array, t: jax.Array, my: jax.Array,
     r = moments.resolve_row_block(n, row_block)
     if r > 0:
         G, b = moments.residual_moments(y, t, my, mt, phi, row_block=r,
-                                        strategy=strategy, rules=rules,
-                                        backend=backend)
+                                        strategy=strategy, backend=backend)
         A = G + ridge * n * jnp.eye(p, dtype=jnp.float32)
         theta = jnp.linalg.solve(A, b)
         meat = moments.residual_meat(y, t, my, mt, phi, theta,
-                                     row_block=r, strategy=strategy,
-                                     rules=rules)
+                                     row_block=r, strategy=strategy)
         Ainv = jnp.linalg.inv(A)
         cov = Ainv @ meat @ Ainv
         return FinalStageResult(theta=theta, cov=cov, gram=G / n, n=n)

@@ -71,16 +71,16 @@ class IVCrossfitResult:
 
 def iv_crossfit(nuis_y: Nuisance, nuis_t: Nuisance, nuis_z: Nuisance,
                 key: jax.Array, X: jax.Array, y: jax.Array, t: jax.Array,
-                z: jax.Array, k: int, engine: str = "parallel",
-                rules=None) -> IVCrossfitResult:
+                z: jax.Array, k: int, engine: str = "parallel"
+                ) -> IVCrossfitResult:
     """Cross-fit the three IV nuisances over ONE fold assignment — three
     ``crossfit_one`` dispatches through whichever engine cfg selects
     (parallel / sequential / parallel_loo / an Executor instance)."""
     kf, ky, kt, kz = jax.random.split(key, 4)
     folds = fold_ids(kf, X.shape[0], k)
-    oof_y, st_y = crossfit_one(nuis_y, ky, X, y, folds, k, engine, rules)
-    oof_t, st_t = crossfit_one(nuis_t, kt, X, t, folds, k, engine, rules)
-    oof_z, st_z = crossfit_one(nuis_z, kz, X, z, folds, k, engine, rules)
+    oof_y, st_y = crossfit_one(nuis_y, ky, X, y, folds, k, engine)
+    oof_t, st_t = crossfit_one(nuis_t, kt, X, t, folds, k, engine)
+    oof_z, st_z = crossfit_one(nuis_z, kz, X, z, folds, k, engine)
     return IVCrossfitResult(oof_y=oof_y, oof_t=oof_t, oof_z=oof_z,
                             folds=folds, states_y=st_y, states_t=st_t,
                             states_z=st_z)
@@ -105,7 +105,7 @@ class IVFinalStageResult:
 def fit_iv_final_stage(ry: jax.Array, rt: jax.Array, rz: jax.Array,
                        phi: jax.Array, *, w: Optional[jax.Array] = None,
                        ridge: float = 1e-8, row_block: int = 0,
-                       strategy: Optional[str] = None, rules=None
+                       strategy: Optional[str] = None
                        ) -> IVFinalStageResult:
     """Solve the instrumented orthogonal moment Jθ = b with HC0
     sandwich covariance — all statistics off one ``iv_gram`` pass plus
@@ -116,15 +116,13 @@ def fit_iv_final_stage(ry: jax.Array, rt: jax.Array, rz: jax.Array,
     f32 = jnp.float32
     ws = jnp.ones((n,), f32) if w is None else w.astype(f32)
     Gaug, n_eff = moments.iv_gram(ry, rt, rz, phi, ws,
-                                  row_block=row_block, strategy=strategy,
-                                  rules=rules)
+                                  row_block=row_block, strategy=strategy)
     J, b, _, _ = moments.iv_slices(Gaug, p)
     n_eff = jnp.maximum(n_eff, 1.0)
     A = J + ridge * n_eff * jnp.eye(p, dtype=f32)
     theta = det_solve(A, b)
     meat = moments.iv_meat(ry, rt, rz, phi, theta, w=w,
-                           row_block=row_block, strategy=strategy,
-                           rules=rules)
+                           row_block=row_block, strategy=strategy)
     Ainv = det_inv(A)
     cov = jnp.einsum("ia,ab,bj->ij", Ainv, meat, Ainv)
     return IVFinalStageResult(theta=theta, cov=cov, j_gram=J / n, n=n)
@@ -149,7 +147,6 @@ class IVFitContext:
     nuis_t: Nuisance
     nuis_z: Nuisance
     compliance: Optional[Nuisance] = None   # DRIV's β(x) model
-    rules: Any = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -179,7 +176,7 @@ class OrthoIVResult(SandwichEffectResult):
                 ctx.y, ctx.t, ctx.z, cf.oof_y, cf.oof_t, cf.oof_z,
                 cf.folds, ctx.phi, self.cfg.n_folds, alpha=alpha,
                 executor=exe, point=self.theta, point_se=self.stderr,
-                rules=ctx.rules, row_block=self.cfg.row_block, **rt_kw)
+                row_block=self.cfg.row_block, **rt_kw)
         return iv_bootstrap(
             ctx.nuis_y, ctx.nuis_t, ctx.nuis_z,
             n_folds=self.cfg.n_folds, XW=ctx.XW, y=ctx.y, t=ctx.t,
@@ -187,7 +184,7 @@ class OrthoIVResult(SandwichEffectResult):
             key=jax.random.fold_in(ctx.key, 0x1b00), alpha=alpha,
             n_replicates=n_boot, scheme=resolve_scheme(method),
             executor=exe, point=self.theta, point_se=self.stderr,
-            rules=ctx.rules, row_block=self.cfg.row_block, **rt_kw)
+            row_block=self.cfg.row_block, **rt_kw)
 
     def _summary_extra(self):
         d = self.diagnostics
@@ -210,8 +207,7 @@ class OrthoIV:
     def __init__(self, cfg: CausalConfig,
                  nuisance_y: Optional[Nuisance] = None,
                  nuisance_t: Optional[Nuisance] = None,
-                 nuisance_z: Optional[Nuisance] = None,
-                 rules=None):
+                 nuisance_z: Optional[Nuisance] = None):
         self.cfg = cfg
         t_task = "clf" if cfg.discrete_treatment else "reg"
         z_task = "clf" if cfg.discrete_instrument else "reg"
@@ -220,7 +216,6 @@ class OrthoIV:
         self.nuis_y = nuisance_y or make_nuisance(cfg.nuisance_y, "reg", cfg)
         self.nuis_t = nuisance_t or make_nuisance(cfg.nuisance_t, t_task, cfg)
         self.nuis_z = nuisance_z or make_nuisance(z_kind, z_task, cfg)
-        self.rules = rules
 
     def fit(self, y: jax.Array, t: jax.Array, z: jax.Array,
             X: jax.Array, W: Optional[jax.Array] = None,
@@ -230,8 +225,7 @@ class OrthoIV:
         key = key if key is not None else jax.random.PRNGKey(0)
         XW = X if W is None else jnp.concatenate([X, W], axis=1)
         cf = iv_crossfit(self.nuis_y, self.nuis_t, self.nuis_z, key, XW,
-                         y, t, z, self.cfg.n_folds, self.cfg.engine,
-                         self.rules)
+                         y, t, z, self.cfg.n_folds, self.cfg.engine)
         f32 = jnp.float32
         ry = y.astype(f32) - cf.oof_y
         rt = t.astype(f32) - cf.oof_t
@@ -239,13 +233,12 @@ class OrthoIV:
         phi = cate_basis(X, self.cfg.cate_features)
         fs = fit_iv_final_stage(ry, rt, rz, phi,
                                 row_block=self.cfg.row_block,
-                                strategy=self.cfg.row_block_strategy,
-                                rules=self.rules)
+                                strategy=self.cfg.row_block_strategy)
         e = ry - (rt[:, None] * phi * fs.theta[None, :]).sum(axis=1)
         diag = compute_iv_diagnostics(t, z, cf.oof_t, cf.oof_z, e)
         ctx = IVFitContext(y=y, t=t, z=z, XW=XW, phi=phi, key=key,
                            nuis_y=self.nuis_y, nuis_t=self.nuis_t,
-                           nuis_z=self.nuis_z, rules=self.rules)
+                           nuis_z=self.nuis_z)
         return OrthoIVResult(theta=fs.theta, cov=fs.cov, cfg=self.cfg,
                              crossfit=cf, final=fs, diagnostics=diag,
                              fit_ctx=ctx)
@@ -306,7 +299,7 @@ class DRIVResult(PseudoOutcomeEffectResult):
             alpha=alpha, n_replicates=n_boot,
             scheme=resolve_scheme(method), executor=exe,
             cov_clip=cfg.iv_cov_clip, point=self.theta,
-            ate_point=self.ate, rules=ctx.rules,
+            ate_point=self.ate,
             row_block=cfg.row_block, **self._runtime_kwargs())
 
 
@@ -318,17 +311,15 @@ class DRIV:
                  nuisance_y: Optional[Nuisance] = None,
                  nuisance_t: Optional[Nuisance] = None,
                  nuisance_z: Optional[Nuisance] = None,
-                 compliance: Optional[Nuisance] = None,
-                 rules=None):
+                 compliance: Optional[Nuisance] = None):
         self.cfg = cfg
-        base = OrthoIV(cfg, nuisance_y, nuisance_t, nuisance_z, rules)
+        base = OrthoIV(cfg, nuisance_y, nuisance_t, nuisance_z)
         self.nuis_y, self.nuis_t, self.nuis_z = (base.nuis_y, base.nuis_t,
                                                  base.nuis_z)
         # β(x) = E[rt·rz|X] is a regression whatever Z/T are
         self.compliance = compliance or make_ridge(
             cfg.ridge_lambda, row_block=cfg.row_block,
             strategy=cfg.row_block_strategy)
-        self.rules = rules
 
     def fit(self, y: jax.Array, t: jax.Array, z: jax.Array,
             X: jax.Array, W: Optional[jax.Array] = None,
@@ -339,7 +330,7 @@ class DRIV:
         n = X.shape[0]
         f32 = jnp.float32
         cf = iv_crossfit(self.nuis_y, self.nuis_t, self.nuis_z, key, XW,
-                         y, t, z, cfg.n_folds, cfg.engine, self.rules)
+                         y, t, z, cfg.n_folds, cfg.engine)
         ry = y.astype(f32) - cf.oof_y
         rt = t.astype(f32) - cf.oof_t
         rz = z.astype(f32) - cf.oof_z
@@ -347,16 +338,14 @@ class DRIV:
         # compliance nuisance on the SAME folds: β(x) = E[rt·rz | X]
         kb = jax.random.fold_in(key, 0xbe7a)
         oof_b, _ = crossfit_one(self.compliance, kb, XW, rt * rz,
-                                cf.folds, cfg.n_folds, cfg.engine,
-                                self.rules)
+                                cf.folds, cfg.n_folds, cfg.engine)
         beta = clip_compliance(oof_b, cfg.iv_cov_clip)
 
         # preliminary constant OrthoIV estimate (same moment, phi = 1)
         ones = jnp.ones((n, 1), f32)
         pre = fit_iv_final_stage(ry, rt, rz, ones,
                                  row_block=cfg.row_block,
-                                 strategy=cfg.row_block_strategy,
-                                 rules=self.rules)
+                                 strategy=cfg.row_block_strategy)
         theta_pre = pre.theta[0]
 
         psi = theta_pre + (ry - theta_pre * rt) * rz / beta
@@ -381,8 +370,7 @@ class DRIV:
         diag = compute_iv_diagnostics(t, z, cf.oof_t, cf.oof_z, e)
         ctx = IVFitContext(y=y, t=t, z=z, XW=XW, phi=phi, key=key,
                            nuis_y=self.nuis_y, nuis_t=self.nuis_t,
-                           nuis_z=self.nuis_z, compliance=self.compliance,
-                           rules=self.rules)
+                           nuis_z=self.nuis_z, compliance=self.compliance)
         return DRIVResult(ate=ate, stderr=se, theta=theta, pseudo=psi,
                           theta_pre=float(theta_pre), diagnostics=diag,
                           cfg=cfg, fit_ctx=ctx)

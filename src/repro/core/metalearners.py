@@ -144,7 +144,7 @@ def meta_bootstrap(core: Callable, *, y: jax.Array, t: jax.Array,
                    X: jax.Array, key: jax.Array, n_replicates: int = 200,
                    scheme: str = "pairs", executor="vmap",
                    alpha: float = 0.05, ate_point: Optional[float] = None,
-                   mesh=None, rules=None, memory_budget: int = 0,
+                   mesh=None, memory_budget: int = 0,
                    chunk: int = 0, max_retries: int = 2):
     """B weighted metalearner refits through the task runtime (chunked,
     fault-tolerant, replicate-ordered — same scheduling as
@@ -154,7 +154,7 @@ def meta_bootstrap(core: Callable, *, y: jax.Array, t: jax.Array,
     from repro.inference import InferenceResult
     from repro.inference.bootstrap import bootstrap_weights, replicate_keys
     from repro.runtime import as_runtime
-    rt = as_runtime(executor, mesh=mesh, rules=rules,
+    rt = as_runtime(executor, mesh=mesh,
                     memory_budget=memory_budget, chunk=chunk,
                     max_retries=max_retries)
     keys = replicate_keys(key, n_replicates)

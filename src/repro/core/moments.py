@@ -25,8 +25,7 @@ Memory model
                           (an unbatched per-block lax.map + an ordered
                           fold) — peak memory ~ O(n·q + B·q²);
       strategy "chunked"  ``lax.scan`` streams one dynamic-sliced
-                          block at a time, each block constrained on
-                          the ``rows`` mesh axis — peak memory
+                          block at a time — peak memory
                           ~ O(R·q + q²).  n is no longer bounded by a
                           single dense allocation: the actual
                           "industrial scale" claim.
@@ -74,8 +73,6 @@ from typing import Any, Callable, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
-
-from repro.distributed.sharding import constrain
 
 Array = jax.Array
 
@@ -139,7 +136,7 @@ def design(X: Array, *, intercept: bool = False,
 
 def blocked_reduce(block_fn: Callable[..., Any], arrays: Sequence[Array],
                    *, row_block: int = 0, strategy: Optional[str] = None,
-                   rules=None, pad_values: Optional[Sequence] = None,
+                   pad_values: Optional[Sequence] = None,
                    init: Optional[Any] = None, form: str = "") -> Any:
     """Reduce ``block_fn`` over row blocks of the leading axis.
 
@@ -209,10 +206,7 @@ def blocked_reduce(block_fn: Callable[..., Any], arrays: Sequence[Array],
             for a, v in zip(arrays, pv))
     nb = (n + pad) // r
     if strategy == "whole":
-        blocks = tuple(
-            constrain(a.reshape((nb, r) + a.shape[1:]),
-                      ("row_block", "rows") + (None,) * (a.ndim - 1), rules)
-            for a in arrays)
+        blocks = tuple(a.reshape((nb, r) + a.shape[1:]) for a in arrays)
         # lax.map, NOT vmap: each block partial comes from the SAME
         # unbatched per-block graph the chunked strategy traces, so
         # chunked ≡ whole is structural — a vmapped block program's
@@ -231,10 +225,8 @@ def blocked_reduce(block_fn: Callable[..., Any], arrays: Sequence[Array],
                          "(expected whole | chunked | pallas)")
 
     def step(acc, i):
-        blks = tuple(
-            constrain(lax.dynamic_slice_in_dim(a, i * r, r, axis=0),
-                      ("rows",) + (None,) * (a.ndim - 1), rules)
-            for a in arrays)
+        blks = tuple(lax.dynamic_slice_in_dim(a, i * r, r, axis=0)
+                     for a in arrays)
         return tmap(jnp.add, acc, block_fn(*blks)), None
 
     if init is None:
@@ -254,7 +246,7 @@ def blocked_reduce(block_fn: Callable[..., Any], arrays: Sequence[Array],
 
 def weighted_gram(X: Array, w: Array, *, intercept: bool = False,
                   append: Optional[Array] = None, row_block: int = 0,
-                  strategy: Optional[str] = None, rules=None
+                  strategy: Optional[str] = None
                   ) -> Tuple[Array, Array]:
     """``G = Σ_n w_n d_n d_nᵀ`` over ``d = [X | 1? | append?]`` plus
     ``n_eff = Σ_n w_n`` from the same blocked reduction.  With
@@ -269,8 +261,7 @@ def weighted_gram(X: Array, w: Array, *, intercept: bool = False,
             ws = wb.astype(jnp.float32)
             return jnp.einsum("ni,n,nj->ij", D, ws, D), ws.sum()
         return blocked_reduce(block, (X, w), row_block=row_block,
-                              strategy=strategy, rules=rules,
-                              form="weighted_gram")
+                              strategy=strategy, form="weighted_gram")
 
     def block(Xb, ab, wb):
         D = design(Xb, intercept=intercept, append=ab)
@@ -278,13 +269,12 @@ def weighted_gram(X: Array, w: Array, *, intercept: bool = False,
         return jnp.einsum("ni,n,nj->ij", D, ws, D), ws.sum()
 
     return blocked_reduce(block, (X, append, w), row_block=row_block,
-                          strategy=strategy, rules=rules,
-                          form="weighted_gram")
+                          strategy=strategy, form="weighted_gram")
 
 
 def weighted_gram_and_vec(X: Array, wg: Array, v: Array, *,
                           intercept: bool = False, row_block: int = 0,
-                          strategy: Optional[str] = None, rules=None
+                          strategy: Optional[str] = None
                           ) -> Tuple[Array, Array, Array]:
     """One blocked pass returning ``(G = Σ wg_n d_n d_nᵀ,
     u = Σ v_n d_n, n_eff = Σ wg_n)`` — Gram and cross-moment with
@@ -335,8 +325,7 @@ def weighted_gram_and_vec(X: Array, wg: Array, v: Array, *,
                 ws.sum())
 
     return blocked_reduce(block, (X, wg, v), row_block=row_block,
-                          strategy=strategy, rules=rules,
-                          form="weighted_gram_and_vec")
+                          strategy=strategy, form="weighted_gram_and_vec")
 
 
 # ---------------------------------------------------------------------------
@@ -346,7 +335,7 @@ def weighted_gram_and_vec(X: Array, wg: Array, v: Array, *,
 
 def fold_gram(X: Array, folds: Array, k: int, *, intercept: bool = False,
               append: Optional[Array] = None, row_block: int = 0,
-              strategy: Optional[str] = None, rules=None
+              strategy: Optional[str] = None
               ) -> Tuple[Array, Array]:
     """One-pass fold-segmented Gram: ``Gh[k] = Σ_{n in fold k} d_n d_nᵀ``
     (k, q, q) plus per-fold row counts (k,).  Integer fold ids are
@@ -365,13 +354,13 @@ def fold_gram(X: Array, folds: Array, k: int, *, intercept: bool = False,
     arrays = (X, folds) + (() if append is None else (append,))
     pad_values = (0, -1) + (() if append is None else (0,))
     return blocked_reduce(block, arrays, row_block=row_block,
-                          strategy=strategy, rules=rules,
+                          strategy=strategy,
                           pad_values=pad_values, form="fold_gram")
 
 
 def fold_weighted_gram(X: Array, Wk: Array, *, intercept: bool = False,
                        append: Optional[Array] = None, row_block: int = 0,
-                       strategy: Optional[str] = None, rules=None
+                       strategy: Optional[str] = None
                        ) -> Tuple[Array, Array]:
     """``G[k] = Σ_n Wk[k,n] d_n d_nᵀ`` (k, q, q) plus per-fold
     ``n_eff = Σ_n Wk[k,n]`` — the replicate-invariant
@@ -397,7 +386,7 @@ def fold_weighted_gram(X: Array, Wk: Array, *, intercept: bool = False,
 
     arrays = (X, Wk.T) + (() if append is None else (append,))
     G = blocked_reduce(block, arrays, row_block=r, strategy=strategy,
-                       rules=rules, form="fold_weighted_gram")
+                       form="fold_weighted_gram")
     return G, n_eff
 
 
@@ -408,7 +397,7 @@ def fold_weighted_gram(X: Array, Wk: Array, *, intercept: bool = False,
 
 def residual_moments(y: Array, t: Array, my: Array, mt: Array, phi: Array,
                      *, row_block: int = 0, strategy: Optional[str] = None,
-                     rules=None, backend: str = ""
+                     backend: str = ""
                      ) -> Tuple[Array, Array]:
     """(G (p,p), b (p,)) of the orthogonal moment, fp32.  row_block=0
     delegates to the fused ``residual_gram`` kernel dispatch (Pallas on
@@ -438,13 +427,12 @@ def residual_moments(y: Array, t: Array, my: Array, mt: Array, phi: Array,
             return Gaug[:p, :p], Gaug[:p, p]
 
     return blocked_reduce(block, (y, t, my, mt, phi), row_block=r,
-                          strategy=strategy, rules=rules,
-                          form="residual_moments")
+                          strategy=strategy, form="residual_moments")
 
 
 def residual_weighted_gram(ry: Array, rt: Array, phi: Array, w: Array,
                            *, row_block: int = 0,
-                           strategy: Optional[str] = None, rules=None
+                           strategy: Optional[str] = None
                            ) -> Tuple[Array, Array]:
     """Weighted augmented residual Gram ``Σ_n w_n m_n m_nᵀ`` with
     ``m = [rt·phi | ry]`` plus ``n_eff = Σ w`` — the replicate-invariant
@@ -463,8 +451,7 @@ def residual_weighted_gram(ry: Array, rt: Array, phi: Array, w: Array,
         return jnp.einsum("ni,n,nj->ij", M, ws, M), ws.sum()
 
     return blocked_reduce(block, (ry, rt, phi, w), row_block=row_block,
-                          strategy=strategy, rules=rules,
-                          form="residual_weighted_gram")
+                          strategy=strategy, form="residual_weighted_gram")
 
 
 def _meat_gram(score: Array, e: Array, p: int) -> Array:
@@ -488,8 +475,7 @@ def _meat_gram(score: Array, e: Array, p: int) -> Array:
 
 def residual_meat(y: Array, t: Array, my: Array, mt: Array, phi: Array,
                   theta: Array, *, w: Optional[Array] = None,
-                  row_block: int = 0, strategy: Optional[str] = None,
-                  rules=None) -> Array:
+                  row_block: int = 0, strategy: Optional[str] = None) -> Array:
     """HC0 meat ``Σ_n (w_n e_n)² z_n z_nᵀ`` with ``e = ry - <z, theta>``
     streamed per block — the dense (n, p) moment matrix ``z`` and the
     residual vector never materialize on the blocked path.  The inner
@@ -512,8 +498,7 @@ def residual_meat(y: Array, t: Array, my: Array, mt: Array, phi: Array,
 
     arrays = (y, t, my, mt, phi) + (() if w is None else (w,))
     return blocked_reduce(block, arrays, row_block=row_block,
-                          strategy=strategy, rules=rules,
-                          form="residual_meat")
+                          strategy=strategy, form="residual_meat")
 
 
 # ---------------------------------------------------------------------------
@@ -531,8 +516,8 @@ def residual_meat(y: Array, t: Array, my: Array, mt: Array, phi: Array,
 # ---------------------------------------------------------------------------
 
 def iv_gram(ry: Array, rt: Array, rz: Array, phi: Array, w: Array, *,
-            row_block: int = 0, strategy: Optional[str] = None,
-            rules=None) -> Tuple[Array, Array]:
+            row_block: int = 0, strategy: Optional[str] = None
+            ) -> Tuple[Array, Array]:
     """Weighted instrumented augmented Gram ``Σ_n w_n m_n m_nᵀ`` with
     ``m = [rz·phi | rt·phi | ry]`` ((2p+1, 2p+1)) plus ``n_eff = Σ w``.
     Point fits pass w = 1; bootstrap replicates their resampling
@@ -553,7 +538,7 @@ def iv_gram(ry: Array, rt: Array, rz: Array, phi: Array, w: Array, *,
 
     return blocked_reduce(block, (ry, rt, rz, phi, w),
                           row_block=row_block, strategy=strategy,
-                          rules=rules, form="iv_gram")
+                          form="iv_gram")
 
 
 def iv_slices(Gaug: Array, p: int) -> Tuple[Array, Array, Array, Array]:
@@ -565,7 +550,7 @@ def iv_slices(Gaug: Array, p: int) -> Tuple[Array, Array, Array, Array]:
 
 def iv_meat(ry: Array, rt: Array, rz: Array, phi: Array, theta: Array,
             *, w: Optional[Array] = None, row_block: int = 0,
-            strategy: Optional[str] = None, rules=None) -> Array:
+            strategy: Optional[str] = None) -> Array:
     """HC0 meat of the instrumented moment: ``Σ_n (w_n e_n)² zc_n zc_nᵀ``
     with score ``zc = rz·phi`` and residual ``e = ry - <rt·phi, theta>``,
     streamed per block (neither the (n, p) score matrix nor the residual
@@ -598,13 +583,12 @@ def iv_meat(ry: Array, rt: Array, rz: Array, phi: Array, theta: Array,
 
     arrays = (ry, rt, rz, phi) + (() if w is None else (w,))
     return blocked_reduce(block, arrays, row_block=row_block,
-                          strategy=strategy, rules=rules,
-                          form="iv_meat")
+                          strategy=strategy, form="iv_meat")
 
 
 def fold_iv_gram(ry: Array, rt: Array, rz: Array, phi: Array,
                  folds: Array, k: int, *, row_block: int = 0,
-                 strategy: Optional[str] = None, rules=None
+                 strategy: Optional[str] = None
                  ) -> Tuple[Array, Array]:
     """Fold-segmented instrumented Gram ``Gh[j] = Σ_{n in fold j}
     m_n m_nᵀ`` ((k, 2p+1, 2p+1)) plus per-fold row counts — the
@@ -627,5 +611,5 @@ def fold_iv_gram(ry: Array, rt: Array, rz: Array, phi: Array,
 
     return blocked_reduce(block, (ry, rt, rz, phi, folds),
                           row_block=row_block, strategy=strategy,
-                          rules=rules, pad_values=(0, 0, 0, 0, -1),
+                          pad_values=(0, 0, 0, 0, -1),
                           form="fold_iv_gram")

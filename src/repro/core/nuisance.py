@@ -8,9 +8,8 @@ row of X is then read once and used by K-1 fits — strictly less data
 movement than Ray's K independent tasks re-reading the dataset.
 
 The zoo is MXU-native (DESIGN.md §2, §9): closed-form ridge, Newton
-logistic, MLPs, and pooled LM-backbone features with a linear head —
-replacing EconML's RandomForest defaults, which do not map to systolic
-arrays.  The DML estimator is agnostic to the nuisance family as long as
+logistic and MLPs — replacing EconML's RandomForest defaults, which do
+not map to systolic arrays.  The DML estimator is agnostic to the nuisance family as long as
 it is consistent; tests verify the same ATE recovery.
 """
 from __future__ import annotations
@@ -198,27 +197,6 @@ def make_mlp(task: str, hidden: Tuple[int, ...] = (256, 256),
 
 
 # ---------------------------------------------------------------------------
-# LM-backbone features (the Dream11 scenario: event-sequence confounders)
-# ---------------------------------------------------------------------------
-
-def backbone_features(model, params, tokens: jax.Array,
-                      batch_size: int = 0, extras: Optional[Dict] = None
-                      ) -> jax.Array:
-    """Pooled (n, d_model) features from a repro Model over user event
-    sequences.  The backbone is frozen; nuisance heads (ridge/logistic)
-    are cross-fit on top — so C1/C2 apply to all 10 assigned archs."""
-    extras = extras or {}
-    if not batch_size or tokens.shape[0] <= batch_size:
-        return model.features(params, {"tokens": tokens, **extras})
-    chunks = []
-    for i in range(0, tokens.shape[0], batch_size):
-        sl = {k: v[i:i + batch_size] for k, v in extras.items()}
-        chunks.append(model.features(
-            params, {"tokens": tokens[i:i + batch_size], **sl}))
-    return jnp.concatenate(chunks, axis=0)
-
-
-# ---------------------------------------------------------------------------
 # Factory
 # ---------------------------------------------------------------------------
 
@@ -231,14 +209,8 @@ def make_nuisance(kind: str, task: str, cfg: CausalConfig) -> Nuisance:
                              row_block=rb, strategy=st)
     if kind == "mlp":
         return make_mlp(task, cfg.mlp_hidden, cfg.mlp_steps, cfg.mlp_lr)
-    if kind == "backbone":
-        # heads over precomputed backbone features; same linear math
-        return (make_logistic(cfg.ridge_lambda, cfg.newton_iters,
-                              row_block=rb, strategy=st)
-                if task == "clf" else make_ridge(cfg.ridge_lambda,
-                                                 row_block=rb,
-                                                 strategy=st))
-    raise ValueError(f"unknown nuisance kind {kind!r}")
+    raise ValueError(f"unknown nuisance kind {kind!r} "
+                     "(expected ridge | logistic | mlp)")
 
 
 # ---------------------------------------------------------------------------

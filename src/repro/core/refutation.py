@@ -65,12 +65,12 @@ class RefutationReport:
                 f"[{'PASS' if self.passed else 'FAIL'}]")
 
 
-def _run_replicates(est, fn, key, n_reps: int, executor, *arrays,
+def _run_replicates(fn, key, n_reps: int, executor, *arrays,
                     label: str = "refute") -> Tuple[float, ...]:
     """Dispatch ``n_reps`` refit replicates through the task runtime and
     extract the leading (ATE) coefficient of each — shared by the DML
     refuters (y, t, X, phi) and the IV refuters (y, t, z, X, phi)."""
-    rt = as_runtime(executor, rules=est.rules)
+    rt = as_runtime(executor)
     thetas = rt.map(fn, replicate_keys(key, n_reps), *arrays,
                     label=label)["theta"]
     return tuple(float(a) for a in thetas[:, 0])
@@ -89,7 +89,7 @@ def placebo_treatment(est: DML, y, t, X, *, original_ate: float,
                               X_, y_, t_fake, phi_, kr, ones,
                               with_se=False)
 
-    ates = _run_replicates(est, refit, key, n_reps, executor, y, t, X, phi)
+    ates = _run_replicates(refit, key, n_reps, executor, y, t, X, phi)
     return RefutationReport("placebo_treatment", original_ate, ates, "zero")
 
 
@@ -107,7 +107,7 @@ def random_common_cause(est: DML, y, t, X, *, original_ate: float,
         return dml_theta_once(est.nuis_y, est.nuis_t, est.cfg.n_folds,
                               Xr, y_, t_, phi_, kr, ones, with_se=False)
 
-    ates = _run_replicates(est, refit, key, n_reps, executor, y, t, X, phi)
+    ates = _run_replicates(refit, key, n_reps, executor, y, t, X, phi)
     return RefutationReport("random_common_cause", original_ate, ates,
                             "stable")
 
@@ -128,7 +128,7 @@ def data_subset(est: DML, y, t, X, *, original_ate: float,
         return dml_theta_once(est.nuis_y, est.nuis_t, est.cfg.n_folds,
                               X_, y_, t_, phi_, kr, w, with_se=False)
 
-    ates = _run_replicates(est, refit, key, n_reps, executor, y, t, X, phi)
+    ates = _run_replicates(refit, key, n_reps, executor, y, t, X, phi)
     return RefutationReport("data_subset", original_ate, ates, "stable")
 
 
@@ -200,7 +200,7 @@ def placebo_instrument(est, y, t, z, X, *, original_ate: float,
                              est.cfg.n_folds, X_, y_, t_, z_fake, phi_,
                              kr, ones, with_se=False)
 
-    ates = _run_replicates(est, refit, key, n_reps, executor, y, t, z,
+    ates = _run_replicates(refit, key, n_reps, executor, y, t, z,
                            X, phi, label="placebo_instrument")
     return RefutationReport("placebo_instrument", original_ate, ates,
                             "zero")
@@ -216,8 +216,7 @@ def run_all(cfg: CausalConfig, y, t, X, *, key=None, executor="vmap"
     est = DML(cfg)
     base = est.fit(y, t, X, key=key)
     a0 = base.ate
-    rt = as_runtime(executor, rules=est.rules,
-                    memory_budget=cfg.runtime_memory_budget,
+    rt = as_runtime(executor, memory_budget=cfg.runtime_memory_budget,
                     chunk=cfg.runtime_chunk,
                     max_retries=cfg.runtime_max_retries)
     p = rt.call(lambda: placebo_treatment(

@@ -1,3 +1,2 @@
 from repro.data.causal_dgp import CausalData, make_causal_data  # noqa: F401
-from repro.data.lm_data import lm_batch_stream, synthetic_tokens  # noqa: F401
 from repro.data.pipeline import ShardedFeed  # noqa: F401

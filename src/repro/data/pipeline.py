@@ -14,7 +14,7 @@ import threading
 from typing import Callable, Dict, Iterator, Optional
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import NamedSharding
 
 
 class ShardedFeed:
@@ -76,8 +76,3 @@ class ShardedFeed:
             pass
         self._thread.join(timeout=5)
 
-
-def batch_sharding(mesh: Mesh, multi_pod: bool = False) -> NamedSharding:
-    """Batch-dim sharding over the DP axes of the production mesh."""
-    dp = ("pod", "data") if multi_pod and "pod" in mesh.axis_names else "data"
-    return NamedSharding(mesh, P(dp))

@@ -173,7 +173,7 @@ def dml_bootstrap(nuis_y: Nuisance, nuis_t: Nuisance, *, n_folds: int,
                   with_se: bool = True,
                   point: Optional[jax.Array] = None,
                   point_se: Optional[jax.Array] = None,
-                  mesh=None, rules=None,
+                  mesh=None,
                   row_block: int = 0, strategy: Optional[str] = None,
                   memory_budget: int = 0, chunk: int = 0,
                   max_retries: int = 2, tracer=None,
@@ -194,7 +194,7 @@ def dml_bootstrap(nuis_y: Nuisance, nuis_t: Nuisance, *, n_folds: int,
     from repro.obs.metrics import default_registry
     from repro.obs.trace import layer_span
     from repro.runtime import as_runtime
-    rt = as_runtime(executor, mesh=mesh, rules=rules,
+    rt = as_runtime(executor, mesh=mesh,
                     memory_budget=memory_budget, chunk=chunk,
                     max_retries=max_retries, tracer=tracer)
     with layer_span(rt.tracer, "inference.bootstrap", cat="inference",
@@ -276,14 +276,14 @@ def iv_bootstrap(nuis_y: Nuisance, nuis_t: Nuisance, nuis_z: Nuisance,
                  alpha: float = 0.05, with_se: bool = True,
                  point: Optional[jax.Array] = None,
                  point_se: Optional[jax.Array] = None,
-                 mesh=None, rules=None, row_block: int = 0,
+                 mesh=None, row_block: int = 0,
                  memory_budget: int = 0, chunk: int = 0,
                  max_retries: int = 2) -> InferenceResult:
     """B weighted OrthoIV refits through the task runtime — the same
     chunked, fault-tolerant, replicate-ordered scheduling as
     dml_bootstrap."""
     from repro.runtime import as_runtime
-    rt_ = as_runtime(executor, mesh=mesh, rules=rules,
+    rt_ = as_runtime(executor, mesh=mesh,
                      memory_budget=memory_budget, chunk=chunk,
                      max_retries=max_retries)
     keys = replicate_keys(key, n_replicates)
@@ -357,14 +357,14 @@ def driv_bootstrap(nuis_y: Nuisance, nuis_t: Nuisance, nuis_z: Nuisance,
                    point: Optional[jax.Array] = None,
                    point_se: Optional[jax.Array] = None,
                    ate_point: Optional[float] = None,
-                   mesh=None, rules=None, row_block: int = 0,
+                   mesh=None, row_block: int = 0,
                    memory_budget: int = 0, chunk: int = 0,
                    max_retries: int = 2) -> InferenceResult:
     """B weighted DRIV refits through the task runtime; the LATE
     functional's own draws ride along (ate_interval centers on mean ψ,
     not theta[0], exactly like dr_bootstrap)."""
     from repro.runtime import as_runtime
-    rt_ = as_runtime(executor, mesh=mesh, rules=rules,
+    rt_ = as_runtime(executor, mesh=mesh,
                      memory_budget=memory_budget, chunk=chunk,
                      max_retries=max_retries)
     keys = replicate_keys(key, n_replicates)
@@ -436,13 +436,12 @@ def dr_bootstrap(outcome: Nuisance, propensity: Nuisance, *, n_folds: int,
                  point: Optional[jax.Array] = None,
                  point_se: Optional[jax.Array] = None,
                  ate_point: Optional[float] = None,
-                 mesh=None, rules=None,
-                 row_block: int = 0, memory_budget: int = 0,
+                 mesh=None, row_block: int = 0, memory_budget: int = 0,
                  chunk: int = 0, max_retries: int = 2) -> InferenceResult:
     """B weighted AIPW refits through the task runtime (same chunked,
     fault-tolerant scheduling as dml_bootstrap)."""
     from repro.runtime import as_runtime
-    rt = as_runtime(executor, mesh=mesh, rules=rules,
+    rt = as_runtime(executor, mesh=mesh,
                     memory_budget=memory_budget, chunk=chunk,
                     max_retries=max_retries)
     keys = replicate_keys(key, n_replicates)

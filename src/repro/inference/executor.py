@@ -10,9 +10,9 @@ leading *replicate* axis:
              the EconML/Ray-less baseline every benchmark compares to;
   vmap       all replicates stacked and batched into ONE program — the
              single-host translation of Ray's task pool (paper C1/C2);
-  shard_map  the replicate axis sharded over the ``data`` mesh axis via
-             distributed/sharding.py rules — replicates spread across
-             devices, each shard running the vmapped program locally.
+  shard_map  the replicate axis sharded over the ``data`` mesh axis —
+             replicates spread across devices, each shard running the
+             vmapped program locally.
 
 ``serial`` and ``vmap`` are *bit-identical* per replicate when the
 closure is built from the replicate-invariant vocabulary in
@@ -223,12 +223,10 @@ class ShardMapExecutor:
 _DEFAULT_EXECUTORS: dict = {}
 
 
-def make_executor(name, *, mesh: Optional[Mesh] = None,
-                  rules=None) -> Executor:
+def make_executor(name, *, mesh: Optional[Mesh] = None) -> Executor:
     """Factory.  ``name`` may already be an Executor (passed through).
-    For ``shard_map`` the mesh axis defaults to the one the sharding
-    rules assign to the logical ``replicate`` axis (falling back to
-    ``data``) — the same rule table that shards DML rows."""
+    ``shard_map`` spreads the replicate axis over the mesh's ``data``
+    axis."""
     if isinstance(name, (SerialExecutor, VmapExecutor, ShardMapExecutor)):
         return name
     if not isinstance(name, str) and isinstance(name, Executor):
@@ -238,13 +236,6 @@ def make_executor(name, *, mesh: Optional[Mesh] = None,
     if name == "vmap":
         return _DEFAULT_EXECUTORS.setdefault("vmap", VmapExecutor())
     if name == "shard_map":
-        axis = "data"
-        if rules is not None:
-            mapped = rules.get("replicate")
-            if isinstance(mapped, (tuple, list)):
-                mapped = mapped[-1] if mapped else None
-            if isinstance(mapped, str):
-                axis = mapped
-        return ShardMapExecutor(mesh=mesh, axis=axis)
+        return ShardMapExecutor(mesh=mesh)
     raise ValueError(f"unknown executor {name!r} "
                      "(expected serial | vmap | shard_map)")

@@ -39,7 +39,7 @@ def delete_fold_jackknife(y: jax.Array, t: jax.Array, oof_y: jax.Array,
                           phi: jax.Array, n_folds: int, *,
                           alpha: float = 0.05, executor="vmap",
                           point=None, point_se=None,
-                          mesh=None, rules=None, ridge: float = 1e-8,
+                          mesh=None, ridge: float = 1e-8,
                           row_block: int = 0, memory_budget: int = 0,
                           chunk: int = 0, max_retries: int = 2,
                           tracer=None) -> InferenceResult:
@@ -49,7 +49,7 @@ def delete_fold_jackknife(y: jax.Array, t: jax.Array, oof_y: jax.Array,
     runtime like bootstrap replicates (chunking is moot at k solves,
     but the fault-tolerance ladder still applies)."""
     from repro.runtime import as_runtime
-    sched = as_runtime(executor, mesh=mesh, rules=rules,
+    sched = as_runtime(executor, mesh=mesh,
                        memory_budget=memory_budget, chunk=chunk,
                        max_retries=max_retries, tracer=tracer)
     f32 = jnp.float32
@@ -65,7 +65,7 @@ def delete_fold_jackknife(y: jax.Array, t: jax.Array, oof_y: jax.Array,
         return jnp.einsum("nk,ni,nj->kij", oh, M, M), oh.sum(0)
 
     Gh, counts = moments.blocked_reduce(
-        block, (ry, rt, phi, folds), row_block=row_block, rules=rules,
+        block, (ry, rt, phi, folds), row_block=row_block,
         pad_values=(0, 0, 0, -1))
     G_tot = Gh.sum(0)
     n_eff = jnp.maximum(n - counts, 1.0)                     # (k,)
@@ -100,7 +100,7 @@ def delete_fold_jackknife_iv(y: jax.Array, t: jax.Array, z: jax.Array,
                              phi: jax.Array, n_folds: int, *,
                              alpha: float = 0.05, executor="vmap",
                              point=None, point_se=None, mesh=None,
-                             rules=None, ridge: float = 1e-8,
+                             ridge: float = 1e-8,
                              row_block: int = 0, memory_budget: int = 0,
                              chunk: int = 0,
                              max_retries: int = 2) -> InferenceResult:
@@ -111,7 +111,7 @@ def delete_fold_jackknife_iv(y: jax.Array, t: jax.Array, z: jax.Array,
     deterministic solve — no nuisance refits, exactly the DML
     jackknife's cost structure on the IV moment."""
     from repro.runtime import as_runtime
-    sched = as_runtime(executor, mesh=mesh, rules=rules,
+    sched = as_runtime(executor, mesh=mesh,
                        memory_budget=memory_budget, chunk=chunk,
                        max_retries=max_retries)
     f32 = jnp.float32
@@ -120,7 +120,7 @@ def delete_fold_jackknife_iv(y: jax.Array, t: jax.Array, z: jax.Array,
     rt = t.astype(f32) - oof_t
     rz = z.astype(f32) - oof_z
     Gh, counts = moments.fold_iv_gram(ry, rt, rz, phi, folds, n_folds,
-                                      row_block=row_block, rules=rules)
+                                      row_block=row_block)
     G_tot = Gh.sum(0)
     n_eff = jnp.maximum(n - counts, 1.0)
 

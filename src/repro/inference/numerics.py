@@ -229,8 +229,7 @@ def _aug(X: jax.Array) -> jax.Array:
 
 def ridge_fit_folds_w(lam: jax.Array, X: jax.Array, y: jax.Array,
                       Wk: jax.Array, *, row_block: int = 0,
-                      strategy: Optional[str] = None,
-                      rules=None) -> jax.Array:
+                      strategy: Optional[str] = None) -> jax.Array:
     """Weighted per-fold ridge, one augmented fold-weighted Gram from
     the moments engine.  Returns beta (k, p+1) (intercept last,
     matching nuisance.make_ridge's column order)."""
@@ -239,8 +238,7 @@ def ridge_fit_folds_w(lam: jax.Array, X: jax.Array, y: jax.Array,
     Gaug, n_eff = moments.fold_weighted_gram(X, Wk, intercept=True,
                                              append=y,
                                              row_block=row_block,
-                                             strategy=strategy,
-                                             rules=rules)
+                                             strategy=strategy)
     n_eff = jnp.maximum(n_eff, 1.0)                             # (k,)
     A = Gaug[:, :p, :p] / n_eff[:, None, None] \
         + lam * jnp.eye(p, dtype=f32)[None]
@@ -250,8 +248,8 @@ def ridge_fit_folds_w(lam: jax.Array, X: jax.Array, y: jax.Array,
 
 def logistic_fit_folds_w(lam: jax.Array, iters: int, X: jax.Array,
                          t: jax.Array, Wk: jax.Array, *,
-                         row_block: int = 0, strategy: Optional[str] = None,
-                         rules=None) -> jax.Array:
+                         row_block: int = 0, strategy: Optional[str] = None
+                         ) -> jax.Array:
     """Weighted per-fold Newton/IRLS logistic (same math as
     nuisance.make_logistic, fold axis explicit).  Returns beta (k, p+1).
 
@@ -274,11 +272,11 @@ def logistic_fit_folds_w(lam: jax.Array, iters: int, X: jax.Array,
         s = jnp.clip(mu * (1.0 - mu), 1e-6, None) * Wk
         Gr, _ = moments.fold_weighted_gram(
             Xa, Wk * (mu - tt[None, :]), append=ones,
-            row_block=row_block, strategy=strategy, rules=rules)
+            row_block=row_block, strategy=strategy)
         g = Gr[:, :p, p] / n_eff[:, None] + lam * beta
         H, _ = moments.fold_weighted_gram(X, s, intercept=True,
                                           row_block=row_block,
-                                          strategy=strategy, rules=rules)
+                                          strategy=strategy)
         H = H / n_eff[:, None, None] + lam_eye[None]
         return beta - jax.vmap(det_solve)(H, g)
 
@@ -303,7 +301,7 @@ def predict_folds_logistic(beta: jax.Array, X: jax.Array) -> jax.Array:
 def weighted_theta(ry: jax.Array, rt: jax.Array, phi: jax.Array,
                    w: jax.Array, *, ridge: float = 1e-8,
                    with_se: bool = True, row_block: int = 0,
-                   strategy: Optional[str] = None, rules=None
+                   strategy: Optional[str] = None
                    ) -> Tuple[jax.Array, Optional[jax.Array]]:
     """Solve the weighted orthogonal moment
     ``theta = argmin Σ w_i (ry_i - <theta, phi_i> rt_i)²`` and (optionally)
@@ -316,8 +314,7 @@ def weighted_theta(ry: jax.Array, rt: jax.Array, phi: jax.Array,
     p = phi.shape[1]
     Gaug, n_eff = moments.residual_weighted_gram(ry, rt, phi, w,
                                                  row_block=row_block,
-                                                 strategy=strategy,
-                                                 rules=rules)
+                                                 strategy=strategy)
     n_eff = jnp.maximum(n_eff, 1.0)
     A = Gaug[:p, :p] + ridge * n_eff * jnp.eye(p, dtype=f32)
     theta = det_solve(A, Gaug[:p, p])
@@ -327,8 +324,7 @@ def weighted_theta(ry: jax.Array, rt: jax.Array, phi: jax.Array,
     # (no mat-vec: (Z * theta).sum over the tiny p_phi axis is invariant)
     meat = moments.residual_meat(ry, rt, jnp.zeros_like(ry),
                                  jnp.zeros_like(rt), phi, theta, w=w,
-                                 row_block=row_block, strategy=strategy,
-                                 rules=rules)
+                                 row_block=row_block, strategy=strategy)
     Ainv = det_inv(A)
     cov = jnp.einsum("ia,ab,bj->ij", Ainv, meat, Ainv)
     se = jnp.sqrt(jnp.clip(jnp.diagonal(cov), 0.0, None))
@@ -338,8 +334,7 @@ def weighted_theta(ry: jax.Array, rt: jax.Array, phi: jax.Array,
 def weighted_iv_theta(ry: jax.Array, rt: jax.Array, rz: jax.Array,
                       phi: jax.Array, w: jax.Array, *,
                       ridge: float = 1e-8, with_se: bool = True,
-                      row_block: int = 0, strategy: Optional[str] = None,
-                      rules=None
+                      row_block: int = 0, strategy: Optional[str] = None
                       ) -> Tuple[jax.Array, Optional[jax.Array]]:
     """Solve the weighted instrumented orthogonal moment
     ``Σ w_i rz_i φ_i (ry_i - <theta, φ_i> rt_i) = 0`` (the residual-on-
@@ -355,7 +350,7 @@ def weighted_iv_theta(ry: jax.Array, rt: jax.Array, rz: jax.Array,
     p = phi.shape[1]
     Gaug, n_eff = moments.iv_gram(ry, rt, rz, phi, w,
                                   row_block=row_block,
-                                  strategy=strategy, rules=rules)
+                                  strategy=strategy)
     J, b, _, _ = moments.iv_slices(Gaug, p)
     n_eff = jnp.maximum(n_eff, 1.0)
     # J = Σ w·rz·rt·φφᵀ is symmetric (a signed-weight Gram) but not
@@ -367,8 +362,7 @@ def weighted_iv_theta(ry: jax.Array, rt: jax.Array, rz: jax.Array,
     if not with_se:
         return theta, None
     meat = moments.iv_meat(ry, rt, rz, phi, theta, w=w,
-                           row_block=row_block, strategy=strategy,
-                           rules=rules)
+                           row_block=row_block, strategy=strategy)
     Ainv = det_inv(A)
     cov = jnp.einsum("ia,ab,bj->ij", Ainv, meat, Ainv)
     se = jnp.sqrt(jnp.clip(jnp.diagonal(cov), 0.0, None))

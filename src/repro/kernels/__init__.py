@@ -10,13 +10,11 @@ reached from estimation code via
 registers, and accumulates per-segment augmented Grams — with a
 ``pallas → chunked → whole`` fallback ladder (counter-instrumented on
 ``repro.obs.metrics.default_registry``) for forms without a fused
-builder.  ``flash_attention`` and ``ssm_scan`` serve the LM-backbone
-nuisances.
+builder.  ``residual_gram`` is the ``row_block=0`` final stage.
 """
 # Each kernel directory ships kernel.py (pl.pallas_call + BlockSpec),
 # ops.py (jit'd dispatch wrapper) and ref.py (pure-jnp oracle).
 #
 # Hot spots covered (see DESIGN.md §6):
-#   flash_attention/  tiled online-softmax attention (prefill/train)
 #   residual_gram/    fused residualize->Gram for the DML final stage
-#   ssm_scan/         chunked gated-linear-attention scan (mamba2/rwkv6)
+#   seg_gram/         fused segment-Gram family (moments, sweep, MM step)

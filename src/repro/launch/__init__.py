@@ -1,1 +1,2 @@
-# Launch layer: production mesh, dry-run, roofline, train/serve drivers.
+# Launch layer: cell lowerings, HLO cost and roofline, elastic sweeps, the
+# two-process distributed smoke.

@@ -1,12 +1,11 @@
-"""The paper's workloads as dry-run cells: fold-parallel DML (5-fold
-ridge + logistic cross-fit, orthogonal final stage) and its
+"""The paper's workloads as single lowered programs: fold-parallel DML
+(5-fold ridge + logistic cross-fit, orthogonal final stage) and its
 orthogonal-IV sibling (three cross-fit nuisances + the instrumented
-final stage), at the §5.3 scale — n = 1M rows x p = 500 covariates —
-lowered against the production mesh with rows sharded over every chip.
+final stage), at the §5.3 scale by default — n = 1M rows x p = 500
+covariates — lowered against a mesh with rows sharded over every chip.
 
-These are the cells "most representative of the paper's technique" for
-the §Perf hillclimb: C1's K simultaneous fold-fits appear as a leading
-vmap axis; the Gram/Newton reductions are the collectives.  The IV cell
+C1's K simultaneous fold-fits appear as a leading vmap axis; the
+Gram/Newton reductions are the collectives.  The IV cell
 lowers the SAME shared engines (crossfit_one ×3 + moments.iv_gram), so
 the two estimands differ only in which moments the final stage reads.
 """
@@ -29,8 +28,7 @@ N_ROWS = 1_048_576  # the paper's "1 Million", padded to 2^20 so rows
 N_COVARIATES = 500
 
 
-def make_dml_step(cfg: CausalConfig, engine: str = "parallel",
-                  rules=None):
+def make_dml_step(cfg: CausalConfig, engine: str = "parallel"):
     """One full DML fit as a single jittable program, lowering the SAME
     shared estimation engine the host estimator runs (no inline
     re-implementation of cross-fitting).  Fold assignment comes in as
@@ -53,18 +51,16 @@ def make_dml_step(cfg: CausalConfig, engine: str = "parallel",
         key = jax.random.PRNGKey(0)
         cf = (crossfit_parallel_loo if engine == "parallel_loo"
               else crossfit_parallel)
-        my, _ = cf(ridge, key, X, y, folds, k, rules)
-        mt, _ = cf(logit, key, X, t, folds, k, rules)
+        my, _ = cf(ridge, key, X, y, folds, k)
+        mt, _ = cf(logit, key, X, t, folds, k)
         phi = cate_basis(X, cfg.cate_features)
-        fs = fit_final_stage(y, t, my, mt, phi,
-                             row_block=cfg.row_block, rules=rules)
+        fs = fit_final_stage(y, t, my, mt, phi, row_block=cfg.row_block)
         return fs.theta, fs.cov
 
     return dml_fit
 
 
-def make_iv_step(cfg: CausalConfig, engine: str = "parallel",
-                 rules=None):
+def make_iv_step(cfg: CausalConfig, engine: str = "parallel"):
     """One full OrthoIV fit as a single jittable program: the same
     shared crossfit engine run for THREE nuisances (E[Y|X], E[T|X],
     E[Z|X]) plus the instrumented final stage (moments.iv_gram /
@@ -82,9 +78,9 @@ def make_iv_step(cfg: CausalConfig, engine: str = "parallel",
         key = jax.random.PRNGKey(0)
         cf = (crossfit_parallel_loo if engine == "parallel_loo"
               else crossfit_parallel)
-        my, _ = cf(ridge, key, X, y, folds, k, rules)
-        mt, _ = cf(logit_t, key, X, t, folds, k, rules)
-        mz, _ = cf(logit_z, key, X, z, folds, k, rules)
+        my, _ = cf(ridge, key, X, y, folds, k)
+        mt, _ = cf(logit_t, key, X, t, folds, k)
+        mz, _ = cf(logit_z, key, X, z, folds, k)
         f32 = jnp.float32
         ry = y.astype(f32) - my
         rt = t.astype(f32) - mt
@@ -92,8 +88,7 @@ def make_iv_step(cfg: CausalConfig, engine: str = "parallel",
         phi = cate_basis(X, cfg.cate_features)
         fs = fit_iv_final_stage(ry, rt, rz, phi,
                                 row_block=cfg.row_block,
-                                strategy=cfg.row_block_strategy,
-                                rules=rules)
+                                strategy=cfg.row_block_strategy)
         return fs.theta, fs.cov
 
     return iv_fit
@@ -131,9 +126,9 @@ def row_sharding(mesh: Mesh, with_instrument: bool = False
 
 def lower_dml_cell(mesh: Mesh, cfg: CausalConfig = None,
                    n: int = N_ROWS, p: int = N_COVARIATES,
-                   engine: str = "parallel", rules=None):
+                   engine: str = "parallel"):
     cfg = cfg or CausalConfig(n_folds=5, cate_features=1)
-    step = make_dml_step(cfg, engine, rules)
+    step = make_dml_step(cfg, engine)
     specs = input_specs(n, p)
     sh = row_sharding(mesh)
     with jax.set_mesh(mesh):
@@ -146,11 +141,11 @@ def lower_dml_cell(mesh: Mesh, cfg: CausalConfig = None,
 
 def lower_iv_cell(mesh: Mesh, cfg: CausalConfig = None,
                   n: int = N_ROWS, p: int = N_COVARIATES,
-                  engine: str = "parallel", rules=None):
-    """The OrthoIV workload against the production mesh: identical row
+                  engine: str = "parallel"):
+    """The OrthoIV workload against ``mesh``: the DML cell's row
     sharding plus the instrument column."""
     cfg = cfg or CausalConfig(n_folds=5, cate_features=1)
-    step = make_iv_step(cfg, engine, rules)
+    step = make_iv_step(cfg, engine)
     specs = input_specs(n, p, with_instrument=True)
     sh = row_sharding(mesh, with_instrument=True)
     with jax.set_mesh(mesh):

@@ -1,14 +1,9 @@
-"""Elastic restart: resume a run on a DIFFERENT mesh than it was saved
-from — the SPMD answer to Ray's "recover tasks from a failed machine"
+"""Elastic sweeps: resume a segment sweep after a lost shard or a killed
+process — the SPMD answer to Ray's "recover tasks from a failed machine"
 (DESIGN.md §7).
 
-Flow on pod failure:
-  1. the job restarts with fewer (or more) pods -> a new mesh;
-  2. ``elastic_restore`` rebuilds the state template from the model and
-     re-places every checkpointed leaf under the NEW shardings (the
-     checkpoint format is mesh-free, so this is just device_put);
-  3. the data pipeline resumes from the checkpointed step — generation
-     is a pure function of (key, step), so the replay is exact.
+The per-column checkpoint format is mesh-free, so a restart may run on
+a different mesh than the one that saved.
 
 Straggler note: within a compiled step there are no stragglers (lock-step
 SPMD); a persistently slow pod is handled by dropping it through this
@@ -16,44 +11,9 @@ path.
 """
 from __future__ import annotations
 
-from typing import Any, Dict, Optional, Tuple
-
-import jax
-
 from repro.checkpoint.manager import CheckpointManager
-from repro.models.model import Model
-from repro.optim.adamw import AdamWState, adamw_init
 
 
-def state_template(model: Model) -> Dict[str, Any]:
-    """Abstract (ShapeDtypeStruct) train state matching train_loop's
-    checkpoints."""
-    params = model.abstract_params()
-    opt = jax.eval_shape(
-        lambda p: adamw_init(p, model.parallel.adam_moment_dtype), params)
-    return {"params": params, "opt": opt}
-
-
-def state_shardings(model: Model, rules, mesh) -> Dict[str, Any]:
-    from jax.sharding import NamedSharding, PartitionSpec as P
-    psh = model.param_shardings(rules, mesh)
-    osh = AdamWState(step=NamedSharding(mesh, P()), m=psh, v=psh)
-    return {"params": psh, "opt": osh}
-
-
-def elastic_restore(manager: CheckpointManager, model: Model, rules, mesh,
-                    *, step: Optional[int] = None
-                    ) -> Tuple[Dict[str, Any], Dict[str, Any]]:
-    """Restore the latest (or given) checkpoint onto ``mesh`` — which may
-    have a different shape than the mesh that saved it."""
-    template = state_template(model)
-    shardings = state_shardings(model, rules, mesh)
-    return manager.restore(template, step=step, shardings=shardings)
-
-
-# ----------------------------------------------------------------------
-# Causal path: elastic sweeps
-# ----------------------------------------------------------------------
 def sweep_checkpoint_manager(directory: str, spec,
                              *, keep_best: int = 1) -> CheckpointManager:
     """CheckpointManager sized for a per-column sweep checkpoint
@@ -67,12 +27,11 @@ def sweep_checkpoint_manager(directory: str, spec,
 
 
 def elastic_sweep(spec, *, directory: str, data_mesh=None, **sweep_kwargs):
-    """Run (or resume) a sweep with per-column checkpointing — the
-    causal-path analogue of ``elastic_restore``.  A lost shard or a
-    killed process costs at most the in-flight column: re-invoking with
-    the same ``directory`` restores every completed column from disk
-    and recomputes only the missing ones (sweep.engine's resume path,
-    signature-checked per column).  ``data_mesh`` passes through to
+    """Run (or resume) a sweep with per-column checkpointing.  A lost
+    shard or a killed process costs at most the in-flight column:
+    re-invoking with the same ``directory`` restores every completed
+    column from disk and recomputes only the missing ones
+    (sweep.engine's resume path, signature-checked per column).  ``data_mesh`` passes through to
     row-shard each column's moment passes."""
     from repro.sweep import sweep
 

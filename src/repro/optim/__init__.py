@@ -1,4 +1,1 @@
 from repro.optim.adamw import AdamWState, adamw_init, adamw_update  # noqa: F401
-from repro.optim.schedule import cosine_schedule, linear_schedule  # noqa: F401
-from repro.optim.compression import (compress_decompress,  # noqa: F401
-                                     compressed_psum_mean, ErrorFeedback)

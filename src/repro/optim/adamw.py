@@ -1,6 +1,6 @@
 """AdamW with decoupled weight decay, global-norm clipping and
-configurable moment dtype (bf16 moments halve optimizer HBM — required to
-fit the 480B/671B MoE cells on v5e, see EXPERIMENTS.md §Dry-run)."""
+configurable moment dtype — the optimizer of the MLP nuisance
+(``core.nuisance.make_mlp``)."""
 from __future__ import annotations
 
 import dataclasses

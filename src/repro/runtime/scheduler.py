@@ -197,14 +197,12 @@ class TaskRuntime:
         chunk: int = 0,
         max_retries: int = 2,
         mesh=None,
-        rules=None,
         data_mesh=None,
         tracer: Optional[Tracer] = None,
         events_maxlen: int = 512,
     ):
-        self._primary = make_executor(executor, mesh=mesh, rules=rules)
+        self._primary = make_executor(executor, mesh=mesh)
         self._mesh = mesh
-        self._rules = rules
         self.data_mesh = data_mesh
         self.memory_budget = int(memory_budget)
         self.chunk = int(chunk)
@@ -247,7 +245,7 @@ class TaskRuntime:
         chain: List[Executor] = [self._primary]
         nxt = DOWNGRADE.get(self._primary.name, "vmap")
         while nxt is not None:
-            chain.append(make_executor(nxt, mesh=self._mesh, rules=self._rules))
+            chain.append(make_executor(nxt, mesh=self._mesh))
             nxt = DOWNGRADE.get(nxt)
         # dedupe by backend name, keeping first occurrence
         seen, out = set(), []
@@ -542,7 +540,6 @@ def as_runtime(
     executor,
     *,
     mesh=None,
-    rules=None,
     data_mesh=None,
     memory_budget: int = 0,
     chunk: int = 0,
@@ -559,7 +556,6 @@ def as_runtime(
     return TaskRuntime(
         executor,
         mesh=mesh,
-        rules=rules,
         data_mesh=data_mesh,
         memory_budget=memory_budget,
         chunk=chunk,

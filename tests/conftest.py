@@ -1,5 +1,6 @@
-# Tests run on the host's single CPU device — the 512-placeholder-device
-# XLA flag belongs to launch/dryrun.py ONLY and must never be set here.
+# Tests run on the host's single CPU device — a forced host device
+# count must never be set here (jax locks the device count at first
+# init; the distributed suite takes its own XLA_FLAGS, see CI).
 import jax
 import pytest
 

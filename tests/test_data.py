@@ -1,13 +1,11 @@
-"""Data substrate: DGP determinism + ground truth, LM stream lineage,
-prefetching feed ordering."""
+"""Data substrate: DGP determinism + ground truth, prefetching feed
+ordering."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.data.causal_dgp import (make_causal_data,
                                    make_sharded_causal_data)
-from repro.data.lm_data import (bigram_ce_floor, lm_batch_stream,
-    synthetic_tokens)
 from repro.data.pipeline import ShardedFeed
 
 
@@ -36,23 +34,6 @@ def test_sharded_dgp_unions(key):
     assert all(s.X.shape == (25, 4) for s in shards)
     # shards differ (independent folds of the key)
     assert not np.allclose(np.asarray(shards[0].X), np.asarray(shards[1].X))
-
-
-def test_lm_stream_lineage(key):
-    s1 = lm_batch_stream(key, 2, 16, 97, start_step=0)
-    a = [next(s1) for _ in range(3)]
-    s2 = lm_batch_stream(key, 2, 16, 97, start_step=2)
-    b = next(s2)
-    np.testing.assert_array_equal(np.asarray(a[2]["tokens"]),
-                                  np.asarray(b["tokens"]))
-
-
-def test_lm_tokens_learnable_structure(key):
-    toks = synthetic_tokens(key, 4, 256, 97)
-    nxt = (5 * toks[:, :-1] + 13) % 97
-    frac = float((toks[:, 1:] == nxt).mean())
-    assert 0.7 < frac < 0.9  # ~1-eps of transitions follow the bigram map
-    assert 0 < bigram_ce_floor(97) < np.log(97)
 
 
 def test_sharded_feed_order_and_close(key):

@@ -111,3 +111,16 @@ def test_loo_engine_matches_parallel(data, key):
         data.y, data.t, data.X, key=key)
     assert abs(r1.ate - r2.ate) < 2e-3
     np.testing.assert_allclose(r1.theta, r2.theta, atol=2e-3)
+
+
+@pytest.mark.parametrize("slot", ["nuisance_y", "nuisance_t", "nuisance_z"])
+def test_removed_backbone_kind_is_rejected(slot):
+    """The nuisance kinds are ridge | logistic | mlp; the retired
+    "backbone" alias is refused in every nuisance slot, not quietly
+    read as a linear head."""
+    from repro.core.iv import OrthoIV
+
+    cfg = CausalConfig(**{slot: "backbone"})
+    est = OrthoIV if slot == "nuisance_z" else DML
+    with pytest.raises(ValueError, match="unknown nuisance kind 'backbone'"):
+        est(cfg)

@@ -1,7 +1,8 @@
 """Orthogonal-IV family (repro.core.iv): LATE recovery on the
 compliance DGP (the acceptance bar: within 2 standard errors), naive-DML
 bias as the control, DRIV agreement, CATE recovery, weak-instrument
-screening, replicate inference, and the IV dry-run cell."""
+screening, replicate inference, and the IV cell lowered as one
+program."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -204,16 +205,46 @@ def test_iv_summary_renders(fitted):
     assert "OrthoIV result" in s and "first-stage F" in s
 
 
-def test_iv_cell_lowers():
-    """The IV workload lowers against a mesh exactly like the DML cell
-    (smoke shape; the 256-chip version runs in the dry-run tier)."""
+def _run_cell(lowered, arrays):
+    """Compile a lowered cell, run it, and check its (theta, cov)."""
+    theta, cov = lowered.compile()(*arrays)
+    assert theta.shape == (1,) and cov.shape == (1, 1)
+    assert np.isfinite(np.asarray(theta)).all()
+    assert float(cov[0, 0]) > 0.0
+    return float(theta[0])
+
+
+@pytest.mark.parametrize("engine", ["parallel", "parallel_loo"])
+def test_dml_cell_lowers(engine):
+    """The DML workload lowers as one program against a one-device
+    mesh per cross-fit engine, and the compiled cell recovers the
+    effect (smoke shape: n = 512, p = 8)."""
     from jax.sharding import Mesh
+    from repro.core.crossfit import fold_ids
+    from repro.configs.dml_synthetic import CAUSAL
+    from repro.data.causal_dgp import make_causal_data
+    from repro.launch.dml_cell import lower_dml_cell
+    mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
+    lowered = lower_dml_cell(mesh, CAUSAL, n=512, p=8, engine=engine)
+    d = make_causal_data(jax.random.PRNGKey(5), 512, 8, effect=1.0)
+    folds = fold_ids(jax.random.PRNGKey(6), 512, CAUSAL.n_folds)
+    theta = _run_cell(lowered, (d.X, d.y, d.t, folds))
+    assert abs(theta - d.true_ate) < 0.5
+
+
+@pytest.mark.parametrize("engine", ["parallel", "parallel_loo"])
+def test_iv_cell_lowers(engine):
+    """The IV workload lowers against a mesh exactly like the DML cell,
+    per cross-fit engine, and the compiled cell runs (smoke shape)."""
+    from jax.sharding import Mesh
+    from repro.core.crossfit import fold_ids
     from repro.launch.dml_cell import lower_iv_cell
     from repro.configs.iv_synthetic import IV_CAUSAL
     mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
-    lowered = lower_iv_cell(mesh, IV_CAUSAL, n=512, p=8)
-    txt = lowered.as_text()
-    assert "func" in txt or len(txt) > 0
+    lowered = lower_iv_cell(mesh, IV_CAUSAL, n=512, p=8, engine=engine)
+    d = make_iv_data(jax.random.PRNGKey(5), 512, 8, effect=1.0)
+    folds = fold_ids(jax.random.PRNGKey(6), 512, IV_CAUSAL.n_folds)
+    _run_cell(lowered, (d.X, d.y, d.t, d.z, folds))
 
 
 def test_iv_data_ground_truth_properties():

@@ -63,66 +63,100 @@ def _close(a, b, msg="", atol=2e-4, rtol=2e-5):
 # against its chunked reference, per lowering.
 # ---------------------------------------------------------------------------
 
+def _shape_arrays(n, k, seed, empty_fold=None):
+    """The moment-form inputs at one shape; ``empty_fold`` routes no row
+    to that fold and zeroes its row of fold weights."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 10)
+    folds = jax.random.randint(ks[6], (n,), 0, k)
+    Wk = jax.random.exponential(ks[8], (k, n))
+    if empty_fold is not None:
+        folds = jnp.where(folds == empty_fold, (empty_fold + 1) % k, folds)
+        Wk = Wk.at[empty_fold].set(0.0)
+    return dict(
+        y=jax.random.normal(ks[0], (n,)),
+        t=(jax.random.uniform(ks[1], (n,)) < 0.5).astype(jnp.float32),
+        my=0.1 * jax.random.normal(ks[2], (n,)),
+        mt=jnp.full((n,), 0.5, jnp.float32),
+        rz=jax.random.normal(ks[3], (n,)),
+        phi=jax.random.normal(ks[4], (n, _P)),
+        w=jax.random.exponential(ks[5], (n,)),
+        folds=folds, k=k, Wk=Wk,
+        theta=jnp.arange(1.0, _P + 1),
+        X=jax.random.normal(ks[7], (n, 5)),
+    )
+
+
+# one shape per padding regime of the moments contract (row block 256)
+FORM_SHAPES = {
+    "n700_ragged_tail": dict(n=_N, k=_K, seed=7),
+    "n200_one_block_empty_fold": dict(n=200, k=5, seed=21, empty_fold=4),
+    "n1024_exact_blocks": dict(n=1024, k=5, seed=33),
+}
+
+# every moments form with a fused seg_gram builder: (a, strategy) -> out
+FUSED_FORMS = {
+    "weighted_gram": lambda a, **kw: moments.weighted_gram(
+        a["X"], a["w"], intercept=True, **kw),
+    "fold_gram": lambda a, **kw: moments.fold_gram(
+        a["X"], a["folds"], a["k"], intercept=True, append=a["y"], **kw),
+    "residual_moments": lambda a, **kw: moments.residual_moments(
+        a["y"], a["t"], a["my"], a["mt"], a["phi"], **kw),
+    "residual_weighted_gram": lambda a, **kw: moments.residual_weighted_gram(
+        a["y"], a["t"], a["phi"], a["w"], **kw),
+    "residual_meat": lambda a, **kw: moments.residual_meat(
+        a["y"], a["t"], a["my"], a["mt"], a["phi"], a["theta"], w=a["w"],
+        **kw),
+    "iv_gram": lambda a, **kw: moments.iv_gram(
+        a["y"], a["t"], a["rz"], a["phi"], a["w"], **kw),
+    "iv_meat": lambda a, **kw: moments.iv_meat(
+        a["y"], a["t"], a["rz"], a["phi"], a["theta"], w=a["w"], **kw),
+    "fold_iv_gram": lambda a, **kw: moments.fold_iv_gram(
+        a["y"], a["t"], a["rz"], a["phi"], a["folds"], a["k"], **kw),
+    "fold_weighted_gram": lambda a, **kw: moments.fold_weighted_gram(
+        a["X"], a["Wk"], intercept=True, **kw),
+    "weighted_gram_and_vec": lambda a, **kw: moments.weighted_gram_and_vec(
+        a["X"], a["w"], a["y"], intercept=True, **kw),
+}
+
+# outputs that are plain sums computed outside the kernels: bitwise
+EXACT_OUTPUTS = {"fold_weighted_gram": (1,), "weighted_gram_and_vec": (2,)}
+
+
+@pytest.fixture(scope="module")
+def form_arrs():
+    return {name: _shape_arrays(**kw) for name, kw in FORM_SHAPES.items()}
+
+
+@pytest.mark.parametrize("shape", FORM_SHAPES)
+@pytest.mark.parametrize("form", FUSED_FORMS)
 @pytest.mark.parametrize("backend", BACKENDS)
-def test_moments_forms_parity(arrs, backend):
-    a = arrs
-    kw = dict(row_block=_RB)
+def test_moments_forms_parity(form_arrs, backend, form, shape):
+    """Every fused moment form under strategy="pallas" agrees with its
+    chunked reference, per lowering and per padding regime (ragged
+    tail, fewer rows than one block with an empty fold, exact blocks),
+    and lowers through its fused builder: no
+    ``seg_gram.fallback[<form>]`` counter rises.  Before the fused
+    builders landed, forms silently laddered pallas→chunked on every
+    trace; the counter assertion pins the fusion."""
+    from repro.obs.metrics import default_registry
+
+    a = form_arrs[shape]
+    fn = FUSED_FORMS[form]
+    ref = fn(a, row_block=_RB, strategy="chunked")
     with sg_ops.force_backend(backend):
-        pairs = [
-            ("weighted_gram",
-             moments.weighted_gram(a["X"], a["w"], intercept=True,
-                                   strategy="chunked", **kw),
-             moments.weighted_gram(a["X"], a["w"], intercept=True,
-                                   strategy="pallas", **kw)),
-            ("fold_gram",
-             moments.fold_gram(a["X"], a["folds"], _K, intercept=True,
-                               append=a["y"], strategy="chunked", **kw),
-             moments.fold_gram(a["X"], a["folds"], _K, intercept=True,
-                               append=a["y"], strategy="pallas", **kw)),
-            ("residual_moments",
-             moments.residual_moments(a["y"], a["t"], a["my"], a["mt"],
-                                      a["phi"], strategy="chunked", **kw),
-             moments.residual_moments(a["y"], a["t"], a["my"], a["mt"],
-                                      a["phi"], strategy="pallas", **kw)),
-            ("residual_weighted_gram",
-             moments.residual_weighted_gram(a["y"], a["t"], a["phi"],
-                                            a["w"], strategy="chunked",
-                                            **kw),
-             moments.residual_weighted_gram(a["y"], a["t"], a["phi"],
-                                            a["w"], strategy="pallas",
-                                            **kw)),
-            ("residual_meat",
-             moments.residual_meat(a["y"], a["t"], a["my"], a["mt"],
-                                   a["phi"], a["theta"], w=a["w"],
-                                   strategy="chunked", **kw),
-             moments.residual_meat(a["y"], a["t"], a["my"], a["mt"],
-                                   a["phi"], a["theta"], w=a["w"],
-                                   strategy="pallas", **kw)),
-            ("iv_gram",
-             moments.iv_gram(a["y"], a["t"], a["rz"], a["phi"], a["w"],
-                             strategy="chunked", **kw),
-             moments.iv_gram(a["y"], a["t"], a["rz"], a["phi"], a["w"],
-                             strategy="pallas", **kw)),
-            ("iv_meat",
-             moments.iv_meat(a["y"], a["t"], a["rz"], a["phi"],
-                             a["theta"], w=a["w"], strategy="chunked",
-                             **kw),
-             moments.iv_meat(a["y"], a["t"], a["rz"], a["phi"],
-                             a["theta"], w=a["w"], strategy="pallas",
-                             **kw)),
-            ("fold_iv_gram",
-             moments.fold_iv_gram(a["y"], a["t"], a["rz"], a["phi"],
-                                  a["folds"], _K, strategy="chunked",
-                                  **kw),
-             moments.fold_iv_gram(a["y"], a["t"], a["rz"], a["phi"],
-                                  a["folds"], _K, strategy="pallas",
-                                  **kw)),
-        ]
-    for name, ref, got in pairs:
-        ref = ref if isinstance(ref, tuple) else (ref,)
-        got = got if isinstance(got, tuple) else (got,)
-        for i, (r, g) in enumerate(zip(ref, got)):
-            _close(g, r, f"{name}[{i}] {backend}")
+        got = fn(a, row_block=_RB, strategy="pallas")
+    ref = ref if isinstance(ref, tuple) else (ref,)
+    got = got if isinstance(got, tuple) else (got,)
+    assert len(ref) == len(got)
+    for i, (r, g) in enumerate(zip(ref, got)):
+        if i in EXACT_OUTPUTS.get(form, ()):
+            np.testing.assert_array_equal(np.asarray(r), np.asarray(g))
+        else:
+            _close(g, r, f"{form}[{i}] {backend} {shape}")
+    counters = default_registry().snapshot()["counters"]
+    fallbacks = {k: v for k, v in counters.items()
+                 if k.startswith("seg_gram.fallback[") and v}
+    assert not fallbacks, f"{form} took the fallback rung: {fallbacks}"
 
 
 @pytest.mark.parametrize("backend", BACKENDS)
@@ -149,44 +183,6 @@ def test_pallas_requires_blocked_path(arrs):
                                   strategy="pallas")
     np.testing.assert_array_equal(np.asarray(r0[0]), np.asarray(rp[0]))
     np.testing.assert_array_equal(np.asarray(r0[1]), np.asarray(rp[1]))
-
-
-@pytest.mark.parametrize("backend", BACKENDS)
-def test_fused_forms_take_no_fallback(arrs, backend):
-    """``fold_weighted_gram`` and ``weighted_gram_and_vec`` lower
-    through fused seg_gram builders: tolerance parity with the chunked
-    reference, n_eff bitwise, and — the load-bearing assertion — the
-    ``seg_gram.fallback[<form>]`` counters stay at ZERO.  Before the
-    fused builders landed, both forms silently laddered pallas→chunked
-    on every trace; this pins the fusion so it cannot regress."""
-    from repro.obs.metrics import default_registry
-
-    a = arrs
-    Wk = jax.random.exponential(jax.random.PRNGKey(9), (_K, _N))
-    ref_fw = moments.fold_weighted_gram(a["X"], Wk, intercept=True,
-                                        row_block=_RB, strategy="chunked")
-    ref_gv = moments.weighted_gram_and_vec(a["X"], a["w"], a["y"],
-                                           intercept=True, row_block=_RB,
-                                           strategy="chunked")
-    with sg_ops.force_backend(backend):
-        got_fw = moments.fold_weighted_gram(a["X"], Wk, intercept=True,
-                                            row_block=_RB,
-                                            strategy="pallas")
-        got_gv = moments.weighted_gram_and_vec(a["X"], a["w"], a["y"],
-                                               intercept=True,
-                                               row_block=_RB,
-                                               strategy="pallas")
-    _close(got_fw[0], ref_fw[0], f"fold_weighted_gram {backend}")
-    np.testing.assert_array_equal(np.asarray(ref_fw[1]),
-                                  np.asarray(got_fw[1]))  # n_eff bitwise
-    _close(got_gv[0], ref_gv[0], f"gram_and_vec.G {backend}")
-    _close(got_gv[1], ref_gv[1], f"gram_and_vec.u {backend}")
-    np.testing.assert_array_equal(np.asarray(ref_gv[2]),
-                                  np.asarray(got_gv[2]))
-    counters = default_registry().snapshot()["counters"]
-    fallbacks = {k: v for k, v in counters.items()
-                 if k.startswith("seg_gram.fallback[") and v}
-    assert not fallbacks, f"fused forms took the fallback rung: {fallbacks}"
 
 
 def test_fallback_ladder_counts_unfused_form(arrs):

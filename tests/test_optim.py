@@ -1,4 +1,4 @@
-"""Optimizer substrate: AdamW math, clipping, schedules, compression."""
+"""Optimizer substrate of the MLP nuisance: AdamW math and clipping."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -6,9 +6,6 @@ import pytest
 
 from repro.config import TrainConfig
 from repro.optim.adamw import adamw_init, adamw_update, clip_by_global_norm
-from repro.optim.compression import (ErrorFeedback, compress_decompress,
-    compressed_psum_mean)
-from repro.optim.schedule import cosine_schedule, linear_schedule
 
 
 def test_adamw_minimizes_quadratic(key):
@@ -40,54 +37,3 @@ def test_clip_by_global_norm(key):
     from repro.optim.adamw import global_norm
     assert float(global_norm(clipped)) == pytest.approx(1.0, rel=1e-5)
 
-
-def test_schedules():
-    total, warm, peak = 100, 10, 1.0
-    for sched in (cosine_schedule, linear_schedule):
-        v0 = float(sched(jnp.int32(0), peak=peak, warmup=warm, total=total))
-        v_w = float(sched(jnp.int32(warm), peak=peak, warmup=warm,
-                          total=total))
-        v_end = float(sched(jnp.int32(total), peak=peak, warmup=warm,
-                            total=total))
-        assert v0 == pytest.approx(0.0, abs=1e-6)
-        assert v_w == pytest.approx(peak, rel=1e-3)
-        assert v_end < 0.2 * peak
-
-
-@pytest.mark.parametrize("method", ["bf16", "int8"])
-def test_compress_roundtrip_error_bounded(key, method):
-    g = jax.random.normal(key, (1024,))
-    rec = compress_decompress(g, method)
-    rel = float(jnp.linalg.norm(rec - g) / jnp.linalg.norm(g))
-    assert rel < (0.01 if method == "bf16" else 0.02)
-
-
-def test_compressed_psum_with_error_feedback(key):
-    """Inside vmap-as-axis, compressed mean-reduction + EF: the residual
-    carries the quantization error so the bias vanishes over steps."""
-    n_dev = 4
-    gs = jax.random.normal(key, (n_dev, 256))
-
-    def red(g, r):
-        out, ef = compressed_psum_mean(
-            {"g": g}, "dev", "int8", ErrorFeedback(residual={"g": r}))
-        return out["g"], ef.residual["g"]
-
-    out, res = jax.vmap(red, axis_name="dev", in_axes=(0, 0))(
-        gs, jnp.zeros_like(gs))
-    # all devices agree, approximately equal to the true mean
-    np.testing.assert_allclose(out[0], out[1], rtol=1e-6)
-    rel = float(jnp.linalg.norm(out[0] - gs.mean(0)) /
-                jnp.linalg.norm(gs.mean(0)))
-    assert rel < 0.05
-    # error feedback residual holds the quantization error (nonzero)
-    assert float(jnp.abs(res).max()) > 0
-    # EF guarantee: the CUMULATIVE average of T compressed reductions
-    # converges to the true mean (error stays O(1/T), not O(1))
-    total = out[0]
-    for _ in range(4):
-        out, res = jax.vmap(red, axis_name="dev", in_axes=(0, 0))(gs, res)
-        total = total + out[0]
-    rel_cum = float(jnp.linalg.norm(total / 5 - gs.mean(0)) /
-                    jnp.linalg.norm(gs.mean(0)))
-    assert rel_cum < rel

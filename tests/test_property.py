@@ -7,13 +7,38 @@ import pytest
 hypothesis = pytest.importorskip("hypothesis")
 from hypothesis import given, settings, strategies as st  # noqa: E402
 
+from repro.core import moments
 from repro.core.crossfit import fold_ids, fold_weights
-from repro.distributed.sharding import ShardingRules, logical_to_spec
-from repro.kernels.ssm_scan import ref as gla_ref
-from repro.models import attention as attn_mod
-from repro.optim.compression import compress_decompress
+from repro.inference.numerics import _UNBLOCKED_MAX, det_solve
+from repro.kernels.seg_gram import ops as sg_ops
 
 SETTINGS = dict(max_examples=20, deadline=None)
+
+
+def _close(got, want, rtol=1e-5):
+    """fp32 reassociation: agreement to ``rtol`` of the largest entry."""
+    got, want = np.asarray(got), np.asarray(want)
+    np.testing.assert_allclose(got, want, rtol=0,
+                               atol=rtol * max(1.0, np.abs(want).max()))
+
+
+def _segment_rows(seed, n, q, s):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    U = jax.random.normal(ks[0], (n, q))
+    V = jax.random.normal(ks[1], (n, q + 1))
+    seg = jax.random.randint(ks[2], (n,), 0, s)
+    w = jax.random.exponential(ks[3], (n,))
+    return U, V, seg, w
+
+
+def _scatter_outer(U, V, seg, s, w):
+    return sg_ops.segment_outer(U, V, seg, s, w=w, backend="scatter")
+
+
+# few distinct shapes, so the examples share compiled programs
+ROWS = st.sampled_from([1, 37, 256, 300])
+WIDTHS = st.sampled_from([1, 4])
+SEGMENTS = st.sampled_from([2, 5, 9])   # S = 1 returns an unstacked Gram
 
 
 @settings(**SETTINGS)
@@ -31,97 +56,76 @@ def test_fold_partition_invariants(n, k, seed):
 
 
 @settings(**SETTINGS)
-@given(b=st.integers(1, 2), h=st.integers(1, 3),
-       nchunks=st.integers(1, 4), dk=st.sampled_from([4, 8, 16]),
-       dv=st.sampled_from([4, 8]), mode=st.sampled_from(["post", "bonus"]),
+@given(cut=st.sampled_from([1, 100, 255, 299]), q=WIDTHS, s=SEGMENTS,
        seed=st.integers(0, 999))
-def test_gla_chunked_equals_naive(b, h, nchunks, dk, dv, mode, seed):
-    t = 16 * nchunks
-    key = jax.random.PRNGKey(seed)
-    ks = jax.random.split(key, 5)
-    q = jax.random.normal(ks[0], (b, h, t, dk))
-    k = jax.random.normal(ks[1], (b, h, t, dk))
-    v = jax.random.normal(ks[2], (b, h, t, dv))
-    w = 0.05 + 0.95 * jax.random.uniform(ks[3], (b, h, t, dk))
-    u = None if mode == "post" else jax.random.normal(ks[4], (h, dk))
-    o1, s1 = gla_ref.gla_chunked_ref(q, k, v, w, u, chunk=16)
-    o2, s2 = gla_ref.gla_naive(q, k, v, w, u)
-    np.testing.assert_allclose(np.asarray(o1), np.asarray(o2),
-                               rtol=5e-4, atol=5e-4)
-    np.testing.assert_allclose(np.asarray(s1), np.asarray(s2),
-                               rtol=5e-4, atol=5e-4)
+def test_segment_outer_additive_over_row_splits(cut, q, s, seed):
+    """The store's additivity: the segmented Gram over rows A ∪ B is its
+    sum over A plus its sum over B, wherever the rows are split."""
+    U, V, seg, w = _segment_rows(seed, 300, q, s)
+    whole = _scatter_outer(U, V, seg, s, w)
+    parts = (_scatter_outer(U[:cut], V[:cut], seg[:cut], s, w[:cut])
+             + _scatter_outer(U[cut:], V[cut:], seg[cut:], s, w[cut:]))
+    _close(parts, whole)
 
 
 @settings(**SETTINGS)
-@given(sq=st.sampled_from([32, 64]), h=st.integers(1, 4),
-       kv_ratio=st.sampled_from([1, 2, 4]), d=st.sampled_from([8, 16]),
-       causal=st.booleans(), seed=st.integers(0, 999))
-def test_chunked_attention_equals_dense(sq, h, kv_ratio, d, causal, seed):
-    heads = h * kv_ratio
-    key = jax.random.PRNGKey(seed)
-    ks = jax.random.split(key, 3)
-    q = jax.random.normal(ks[0], (1, sq, heads, d))
-    k = jax.random.normal(ks[1], (1, sq, h, d))
-    v = jax.random.normal(ks[2], (1, sq, h, d))
-    dense = attn_mod._sdpa(q, k, v, causal=causal)
-    chunked = attn_mod._chunked_attn(q, k, v, causal=causal, chunk=16)
-    np.testing.assert_allclose(np.asarray(dense), np.asarray(chunked),
-                               rtol=5e-5, atol=5e-5)
+@given(n=ROWS, q=WIDTHS, s=SEGMENTS, c=st.floats(-4.0, 4.0),
+       seed=st.integers(0, 999))
+def test_segment_outer_linear_in_row_weights(n, q, s, c, seed):
+    """G(w1 + c·w2) = G(w1) + c·G(w2): a row weight enters once and
+    linearly (a bootstrap or segment mask is just another weight)."""
+    U, V, seg, w1 = _segment_rows(seed, n, q, s)
+    w2 = jax.random.normal(jax.random.PRNGKey(seed + 1), (n,))
+    got = _scatter_outer(U, V, seg, s, w1 + c * w2)
+    want = (_scatter_outer(U, V, seg, s, w1)
+            + c * _scatter_outer(U, V, seg, s, w2))
+    _close(got, want, rtol=1e-4)
 
 
 @settings(**SETTINGS)
-@given(scale=st.floats(1e-4, 1e4), n=st.sampled_from([16, 257]),
-       method=st.sampled_from(["bf16", "int8"]), seed=st.integers(0, 99))
-def test_compression_relative_error_bounded(scale, n, method, seed):
-    g = scale * jax.random.normal(jax.random.PRNGKey(seed), (n,))
-    rec = compress_decompress(g, method)
-    num = float(jnp.linalg.norm(rec - g))
-    den = float(jnp.linalg.norm(g)) + 1e-30
-    assert num / den < 0.03
+@given(n=ROWS, q=WIDTHS, s=SEGMENTS, seed=st.integers(0, 999))
+def test_segment_outer_equivariant_under_relabelling(n, q, s, seed):
+    """Relabelling the segments by a permutation π moves slab s to
+    π(s) and changes nothing else."""
+    U, V, seg, w = _segment_rows(seed, n, q, s)
+    perm = jax.random.permutation(jax.random.PRNGKey(seed + 7), s)
+    base = _scatter_outer(U, V, seg, s, w)
+    moved = _scatter_outer(U, V, perm[seg], s, w)
+    _close(np.asarray(moved)[np.asarray(perm)], base)
 
 
 @settings(**SETTINGS)
-@given(seed=st.integers(0, 99), frac=st.sampled_from([0.5, 1.0]))
-def test_rope_preserves_norm_and_relativity(seed, frac):
-    """RoPE is an orthogonal per-position rotation: norms preserved, and
-    <rope(q,m), rope(k,n)> depends only on (m - n)."""
-    from repro.config import ModelConfig
-    from repro.models.layers import apply_rope, rope_frequencies
-    cfg = ModelConfig(name="t", family="dense", num_layers=1, d_model=32,
-                      num_heads=1, num_kv_heads=1, head_dim=16, d_ff=32,
-                      vocab_size=64, rope_fraction=frac)
-    key = jax.random.PRNGKey(seed)
-    q = jax.random.normal(key, (1, 4, 1, 16))
-    positions = jnp.arange(4)[None, :]
-    sin, cos = rope_frequencies(cfg, positions)
-    q_r = apply_rope(q, sin, cos)
-    np.testing.assert_allclose(
-        np.linalg.norm(np.asarray(q), axis=-1),
-        np.linalg.norm(np.asarray(q_r), axis=-1), rtol=1e-5)
-    # relativity: shift both positions by a constant -> same dot product
-    k = jax.random.normal(jax.random.fold_in(key, 1), (1, 4, 1, 16))
-    k_r = apply_rope(k, sin, cos)
-    dots1 = np.einsum("bshd,bthd->bst", np.asarray(q_r), np.asarray(k_r))
-    sin2, cos2 = rope_frequencies(cfg, positions + 5)
-    q_r2 = apply_rope(q, sin2, cos2)
-    k_r2 = apply_rope(k, sin2, cos2)
-    dots2 = np.einsum("bshd,bthd->bst", np.asarray(q_r2), np.asarray(k_r2))
-    np.testing.assert_allclose(dots1, dots2, rtol=1e-3, atol=1e-4)
+@given(p=st.sampled_from([1, 7, _UNBLOCKED_MAX, _UNBLOCKED_MAX + 1, 96]),
+       seed=st.integers(0, 999))
+def test_det_solve_matches_linalg_solve_on_spd(p, seed):
+    """det_solve agrees with jnp.linalg.solve on random SPD systems on
+    both sides of its unblocked/panelled threshold."""
+    ka, kb = jax.random.split(jax.random.PRNGKey(seed))
+    B = jax.random.normal(ka, (p, p))
+    A = B @ B.T / p + jnp.eye(p)
+    b = jax.random.normal(kb, (p,))
+    with jax.default_matmul_precision("highest"):
+        want = jnp.linalg.solve(A, b)
+    _close(jax.jit(det_solve)(A, b), want, rtol=1e-4)
 
 
 @settings(**SETTINGS)
-@given(seed=st.integers(0, 999))
-def test_spec_never_reuses_mesh_axis(seed):
-    """logical_to_spec must never emit a PartitionSpec using one mesh
-    axis twice (GSPMD rejects it)."""
-    rng = np.random.RandomState(seed)
-    names = ["batch", "seq", "vocab", "heads", "ff", "embed"]
-    mesh_axes = ["data", "model", None]
-    rules = ShardingRules(rules=tuple(
-        (n, mesh_axes[rng.randint(3)]) for n in names))
-    axes = tuple(names[rng.randint(len(names))]
-                 for _ in range(rng.randint(1, 5)))
-    spec = logical_to_spec(axes, rules)
-    flat = [a for p in spec for a in
-            (p if isinstance(p, tuple) else (p,)) if a]
-    assert len(flat) == len(set(flat)), (axes, spec)
+@given(n=ROWS, p=WIDTHS, k=st.sampled_from([2, 5]),
+       row_block=st.sampled_from([0, 64]), seed=st.integers(0, 999))
+def test_fold_complement_gram_is_total_less_own_fold(n, p, k, row_block,
+                                                     seed):
+    """The leave-one-out identity behind ``parallel_loo`` and the
+    segmented sweep: the fold-weighted Gram under ``fold_weights``'
+    complement weights (times row weights) is, for each fold, the total
+    Gram less that fold's ``fold_gram``."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    X = jax.random.normal(ks[0], (n, p))
+    folds = jax.random.randint(ks[1], (n,), 0, k)
+    w = jax.random.exponential(ks[2], (n,))
+    Wk = fold_weights(folds, k) * w[None, :]
+    G, n_eff = moments.fold_weighted_gram(X, Wk, row_block=row_block,
+                                          strategy="chunked")
+    Gh, _ = moments.fold_gram(X * jnp.sqrt(w)[:, None], folds, k,
+                              row_block=row_block, strategy="chunked")
+    _close(G, Gh.sum(0)[None] - Gh)
+    _close(n_eff, Wk.sum(1))

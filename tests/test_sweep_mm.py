@@ -94,8 +94,12 @@ def step_inputs(data):
     return Xa, data["t"], jnp.asarray(sids), jnp.asarray(folds), beta
 
 
+# explicit block_n tilings of the 4,096 rows: 384 gives ten full blocks
+# and a padded tail, 128 gives 32 blocks and no tail, 1,024 an exact
+# multiple, 8,192 one block wider than n
 @pytest.mark.parametrize("backend,block_n", [
-    ("scatter", None), ("interpret", None), ("interpret", 384)])
+    ("scatter", None), ("interpret", None), ("interpret", 384),
+    ("interpret", 128), ("interpret", 1024), ("interpret", 8192)])
 def test_fused_step_matches_einsum_step(step_inputs, backend, block_n):
     Xa, t, sids, folds, beta = step_inputs
     assert 0.8 < float(t.mean()) < 0.9
@@ -106,7 +110,7 @@ def test_fused_step_matches_einsum_step(step_inputs, backend, block_n):
                         folds.astype(jnp.float32)])
     if block_n is None:
         got = sg_ops.mm_logistic_grad(xa_t, meta_t, beta, backend=backend)
-    else:  # 4,096 rows in blocks of 384: ten full blocks and a padded tail
+    else:
         table = jnp.transpose(beta, (1, 2, 0)).reshape(K * Q, E)
         got = sg_kernel.seg_gram_lanes(
             sg_ref.build_mm_logistic, [xa_t, meta_t, table], interpret=True,
