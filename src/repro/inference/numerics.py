@@ -197,12 +197,19 @@ def _select(mask: jax.Array, X: jax.Array, axis: int) -> jax.Array:
 
 
 def det_solve(A: jax.Array, b: jax.Array) -> jax.Array:
-    """Deterministic (p,p) @ x = (p,) solve via Gauss-Jordan without
-    pivoting, panelled for large p (``_gauss_jordan``).  Elementwise
-    broadcast updates only — bit-identical under any number of leading
-    vmap axes, and to the unblocked loop.  Requires A SPD-ish (ridge
-    added by every caller).  Its ops carry the ``det_solve`` scope."""
+    """Deterministic (p,p) @ x = b solve via Gauss-Jordan without
+    pivoting, panelled for large p (``_gauss_jordan``).  ``b`` is one
+    right-hand side (p,) or r of them (p, r), eliminated together: a
+    column only ever meets the multiply-subtracts of its own entries, so
+    each comes out with the bits of its own one-column solve.
+    Elementwise broadcast updates only — bit-identical under any number
+    of leading vmap axes, and to the unblocked loop.  Requires A SPD-ish
+    (ridge added by every caller).  Its ops carry the ``det_solve``
+    scope."""
     with jax.named_scope("det_solve"):
+        if b.ndim == 2:
+            return _gauss_jordan(jnp.concatenate([A, b], axis=1),
+                                 A.shape[0])
         M = jnp.concatenate([A, b[:, None]], axis=1)
         return _gauss_jordan(M, A.shape[0])[:, 0]
 
@@ -227,12 +234,18 @@ def _aug(X: jax.Array) -> jax.Array:
 # batch-invariant, the explicit "ni,kn,nj->kij" form is.
 # ---------------------------------------------------------------------------
 
-def ridge_fit_folds_w(lam: jax.Array, X: jax.Array, y: jax.Array,
+def ridge_fit_folds_w(lam, X: jax.Array, y: jax.Array,
                       Wk: jax.Array, *, row_block: int = 0,
                       strategy: Optional[str] = None) -> jax.Array:
     """Weighted per-fold ridge, one augmented fold-weighted Gram from
     the moments engine.  Returns beta (k, p+1) (intercept last,
-    matching nuisance.make_ridge's column order)."""
+    matching nuisance.make_ridge's column order).
+
+    ``y`` (n, m) fits m targets on the same X and weights from ONE Gram
+    over [X | 1 | y_1 .. y_m], returning (m, k, p+1); ``lam`` is then
+    the m penalties (Python numbers).  When they agree, the targets
+    share one elimination (``det_solve``'s right-hand sides); each
+    target's bits are those of its own one-target fit."""
     f32 = jnp.float32
     p = X.shape[1] + 1
     Gaug, n_eff = moments.fold_weighted_gram(X, Wk, intercept=True,
@@ -240,10 +253,18 @@ def ridge_fit_folds_w(lam: jax.Array, X: jax.Array, y: jax.Array,
                                              row_block=row_block,
                                              strategy=strategy)
     n_eff = jnp.maximum(n_eff, 1.0)                             # (k,)
-    A = Gaug[:, :p, :p] / n_eff[:, None, None] \
-        + lam * jnp.eye(p, dtype=f32)[None]
-    b = Gaug[:, :p, p] / n_eff[:, None]
-    return jax.vmap(det_solve)(A, b)
+    if y.ndim == 1:
+        A = Gaug[:, :p, :p] / n_eff[:, None, None] \
+            + lam * jnp.eye(p, dtype=f32)[None]
+        b = Gaug[:, :p, p] / n_eff[:, None]
+        return jax.vmap(det_solve)(A, b)
+    G = Gaug[:, :p, :p] / n_eff[:, None, None]
+    eye = jnp.eye(p, dtype=f32)[None]
+    b = Gaug[:, :p, p:] / n_eff[:, None, None]                  # (k, p, m)
+    if len(set(lam)) == 1:
+        return jnp.moveaxis(jax.vmap(det_solve)(G + lam[0] * eye, b), 2, 0)
+    return jnp.stack([jax.vmap(det_solve)(G + l * eye, b[..., j])
+                      for j, l in enumerate(lam)])
 
 
 def logistic_fit_folds_w(lam: jax.Array, iters: int, X: jax.Array,

@@ -87,3 +87,41 @@ def test_panelled_ops_carry_the_scope(scope, fn, args):
              if n.startswith("jit(")]
     assert names and all(scope in n for n in names), \
         [n for n in names if scope not in n][:5]
+
+
+def _one_column_det_solve(A, b):
+    """``det_solve`` as it was before it took several right-hand sides:
+    the oracle of its one-column lowering."""
+    with jax.named_scope("det_solve"):
+        M = jnp.concatenate([A, b[:, None]], axis=1)
+        return numerics._gauss_jordan(M, A.shape[0])[:, 0]
+
+
+@pytest.mark.parametrize("batch", [(5,), (2, 5)], ids=["vmap", "vmap2"])
+@pytest.mark.parametrize("r", [2, 3])
+@pytest.mark.parametrize("p", [13, 38, 65, 131])
+def test_right_hand_sides_match_one_column_solves_bitwise(p, r, batch):
+    """Several right-hand sides in one elimination, unblocked and
+    panelled: each column has the bits of its own one-column solve."""
+    A, _ = _ridge_systems(p, batch, seed=p)
+    b = jax.random.normal(jax.random.PRNGKey(p + r), batch + (p, r))
+    d = len(batch)
+    x = _batched(det_solve, d)(A, b)
+    assert x.shape == batch + (p, r)
+    for j in range(r):
+        np.testing.assert_array_equal(
+            _bits(x[..., j]), _bits(_batched(det_solve, d)(A, b[..., j])))
+
+
+@pytest.mark.parametrize("p", [13, 65, 501])
+def test_one_column_lowering_is_unchanged(p):
+    """One right-hand side lowers to the same text as before."""
+    spec = (jax.ShapeDtypeStruct((2, 5, p, p), jnp.float32),
+            jax.ShapeDtypeStruct((2, 5, p), jnp.float32))
+
+    def text(solve):
+        def program(A, b):
+            return jax.vmap(jax.vmap(solve))(A, b)
+        return jax.jit(program).lower(*spec).as_text(debug_info=False)
+
+    assert text(det_solve) == text(_one_column_det_solve)

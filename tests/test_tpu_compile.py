@@ -115,6 +115,15 @@ def test_bootstrap_fold_weighted_kron(one_chip):
              _spec((N_FIT, 5), one_chip), _spec((N_FIT, 502), one_chip))
 
 
+def test_bootstrap_shared_nuisance_gram_kron(one_chip):
+    """Both ridge nuisances of a replicate from one fold-weighted Gram
+    over [X | 1 | y | t]: q = 503, K·q = 2515 columns."""
+    compiled = _compile(_kernel(sg_ref.build_fold_weighted),
+                        _spec((N_FIT, 5), one_chip),
+                        _spec((N_FIT, 503), one_chip))
+    assert "%seg_gram_fold_weighted." in compiled.as_text()
+
+
 @pytest.mark.parametrize("qU,qV", [(1, 51), (52, 52), (106, 106)])
 def test_sweep_segment_outer_s320(one_chip, qU, qV):
     """S = E·K = 320 cells at E = 64, K = 5, p = 50: the sweep's MM
@@ -143,6 +152,17 @@ def test_bootstrap_det_solve_panelled(one_chip):
                               _spec((2, 5, 501, 501), one_chip),
                               _spec((2, 5, 501), one_chip))
     assert "tpu_custom_call" not in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 4 * mem.argument_size_in_bytes, mem
+
+
+def test_bootstrap_det_solve_two_right_hand_sides(one_chip):
+    """The shared nuisance Gram's elimination: both ridges' right-hand
+    sides over 2 replicates x K = 5 folds of 501 unknowns, panelled,
+    in the one-column solve's scratch bound."""
+    compiled = _compile_plain(jax.vmap(jax.vmap(det_solve)),
+                              _spec((2, 5, 501, 501), one_chip),
+                              _spec((2, 5, 501, 2), one_chip))
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 4 * mem.argument_size_in_bytes, mem
 
