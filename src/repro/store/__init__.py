@@ -12,10 +12,14 @@ incremental path is *bitwise identical* to a full refit on the
 concatenated data (the fixed-order block-fold contract), and versioned
 snapshots ride through ``repro.checkpoint`` for hot-swap/rollback.
 Coverage is gated by ``store_supported`` (all-ridge DML and OrthoIV
-families); unsupported columns fault-isolate as failed panel columns.
+families; with a ``Window``, also DML with a logistic treatment model,
+refreshed from the window's rows kept on the device); unsupported
+columns fault-isolate as failed panel columns.  A ``Window`` of days
+turns the store into a trailing-window daily refresh: each ingest is
+one day and retires the oldest once the window is full.
 """
 
 from repro.store.stats import ColumnLayout
-from repro.store.store import MomentStore, store_supported
+from repro.store.store import MomentStore, Window, store_supported
 
-__all__ = ["ColumnLayout", "MomentStore", "store_supported"]
+__all__ = ["ColumnLayout", "MomentStore", "Window", "store_supported"]

@@ -25,6 +25,11 @@ Standard errors are the **homoskedastic** sandwich ``σ²·A⁻¹ G A⁻¹``
 and is NOT a contraction of any stored moment — computing it would
 need a data pass, which is exactly what refresh must not do.  See
 docs/ARCHITECTURE.md for the contract table entry.
+
+``refresh_rows`` is the other path, for a windowed column whose
+treatment model is logistic: the MM steps need a gradient pass over the
+rows, so it re-reads the window's rows kept on the device and runs the
+segmented sweep's stages on them (HC0 SEs, as the sweep's).
 """
 
 from __future__ import annotations
@@ -34,8 +39,10 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 
+from repro.config import CausalConfig
 from repro.inference.numerics import det_inv, det_solve
-from repro.store.stats import ColumnLayout, State
+from repro.store.stats import ColumnLayout, State, window_sum
+from repro.sweep.segmented import segmented_stages
 
 Array = jax.Array
 _F32 = jnp.float32
@@ -111,3 +118,27 @@ def refresh_column(layout: ColumnLayout, state: State, n_segments: int, *,
                      sigma2[:, None, None] * meat_base, ainv)
     se = jnp.sqrt(jnp.clip(jnp.diagonal(cov, axis1=1, axis2=2), 0.0, None))
     return {"theta": theta, "se": se, "ate": theta[:, 0]}
+
+
+def refresh_rows(layout: ColumnLayout, cfg: CausalConfig, state: State,
+                 start, n_segments: int, day_rows: int) -> Dict[str, Array]:
+    """Re-solve one windowed column from the window's rows, oldest day
+    first (slot ``start`` leads): sum the day slots' Grams, then the
+    segmented sweep's stages over the ring — fold complements, ridge
+    visit models, ``2·newton_iters`` MM logistic steps from zero, the
+    out-of-fold predictions and the per-cohort final stage with its HC0
+    SE.  Returns the sweep's {"theta", "se", "ate", "beta_y", "beta_t",
+    "cell_rows"}."""
+    E, k, q, qd = n_segments, layout.k, layout.q, layout.qd
+    ng = window_sum(state["ng"], start).reshape(E, k, qd, qd)
+    counts = window_sum(state["counts"], start).reshape(E, k)
+    keep = list(range(q)) + [layout.iy]  # [X | 1 | y]
+    Gh = ng[:, :, keep][:, :, :, keep]
+    shift = -start * day_rows
+    xa_t = jnp.roll(state["xa"], shift, axis=1)
+    meta = jnp.roll(state["meta"], shift, axis=1)  # [t; cohort + 1; fold; y]
+    sids = meta[1].astype(jnp.int32) - 1
+    folds = meta[2].astype(jnp.int32)
+    comb = jnp.where(sids >= 0, sids * k + folds, 0)
+    return segmented_stages(cfg, Gh, counts, E, y=meta[3], t=meta[0],
+                            sids=sids, folds=folds, comb=comb, xa_t=xa_t)

@@ -27,6 +27,20 @@ boundary — the store's fixed-order block-fold contract.
 ``strategy="pallas"`` routes through the fused segment-outer kernels
 instead (bitwise on the scatter lowering, delta-add tolerance on the
 compiled kernel).
+
+A windowed store (``Window``: a trailing window of ``days`` day slots)
+keeps each statistic per day instead, with a leading ``days`` axis:
+``ng`` (days, cells, qd, qd), ``counts`` (days, cells), and ``vg``
+for the columns refreshed from moments.  A day's ingest computes its
+slot afresh (one pass over the day's rows, no ``init``) and overwrites
+the oldest slot once the window is full; refresh sums the slots in a
+fixed age order, oldest first (``window_sum``), so the sums do not
+depend on where in the ring the days sit.  A column whose treatment
+model is logistic (``ColumnLayout.rows``) has no ``vg``: its refresh
+re-reads the window's rows, which it keeps on the device in a
+lane-major ring, ``xa`` = [X | 1]ᵀ (q, days·day_rows) and ``meta`` =
+[t; cohort + 1; fold; y] (4, days·day_rows); a padded row has a zero
+``xa`` column and cohort tag 0.
 """
 
 from __future__ import annotations
@@ -53,6 +67,7 @@ class ColumnLayout:
     pf: int   # CATE basis width (cate_basis column count)
     k: int    # cross-fit folds
     iv: bool  # instrumented design (z column present)
+    rows: bool = False  # refreshed from the window's rows (logistic t)
 
     @property
     def q(self) -> int:
@@ -85,13 +100,58 @@ class ColumnLayout:
         return self.pf * self.qd
 
 
-def init_state(layout: ColumnLayout, n_cells: int) -> State:
-    """Zero accumulators for ``n_cells = n_segments · k`` cells."""
-    return {
-        "ng": jnp.zeros((n_cells, layout.qd, layout.qd), _F32),
-        "vg": jnp.zeros((n_cells, layout.pv, layout.pv), _F32),
-        "counts": jnp.zeros((n_cells,), _F32),
+def init_state(layout: ColumnLayout, n_cells: int, days: int = 0,
+               day_rows: int = 0) -> State:
+    """Zero accumulators for ``n_cells = n_segments · k`` cells; with
+    ``days``, one slot per day and, for a ``rows`` column, the ring of
+    ``days · day_rows`` rows."""
+    lead = (days,) if days else ()
+    state = {
+        "ng": jnp.zeros(lead + (n_cells, layout.qd, layout.qd), _F32),
+        "counts": jnp.zeros(lead + (n_cells,), _F32),
     }
+    if layout.rows:
+        state["xa"] = jnp.zeros((layout.q, days * day_rows), _F32)
+        state["meta"] = jnp.zeros((4, days * day_rows), _F32)
+    else:
+        state["vg"] = jnp.zeros(lead + (n_cells, layout.pv, layout.pv), _F32)
+    return state
+
+
+def window_sum(slots: Array, start) -> Array:
+    """Σ over the leading day axis of ``slots``, oldest day first: the
+    slots rolled so that slot ``start`` leads, then a left fold."""
+    rolled = jnp.roll(slots, -start, axis=0)
+    acc = rolled[0]
+    for d in range(1, slots.shape[0]):
+        acc = acc + rolled[d]
+    return acc
+
+
+def ingest_day(layout: ColumnLayout, state: State, slot, X: Array,
+               t: Array, y: Array, z: Optional[Array], phi: Array,
+               sids: Array, folds: Array, n_cells: int, *,
+               row_block: int = 0, strategy: Optional[str] = None) -> State:
+    """Write one day into slot ``slot`` of a windowed column: its cell
+    statistics from one pass over the day's rows alone, and for a
+    ``rows`` column its rows into the ring.  ``sids`` is -1 on padded
+    rows, which add nothing."""
+    comb = jnp.where(sids >= 0, sids * layout.k + folds, -1)
+    day = {key: jnp.zeros(v.shape[1:], _F32) for key, v in state.items()
+           if key in ("ng", "vg", "counts")}
+    day = ingest_cells(layout, day, X, t, y, z, phi, comb, n_cells,
+                       row_block=row_block, strategy=strategy)
+    out = {key: state[key].at[slot].set(v) for key, v in day.items()}
+    if layout.rows:
+        r = X.shape[0]
+        real = (sids >= 0).astype(_F32)
+        xa = jnp.concatenate([X.astype(_F32).T, real[None]])
+        meta = jnp.stack([t.astype(_F32), (sids + 1).astype(_F32),
+                          folds.astype(_F32), y.astype(_F32)])
+        at = (0, slot * r)
+        out["xa"] = jax.lax.dynamic_update_slice(state["xa"], xa, at)
+        out["meta"] = jax.lax.dynamic_update_slice(state["meta"], meta, at)
+    return out
 
 
 def _dn(layout: ColumnLayout, X: Array, t: Array, y: Array,
@@ -126,6 +186,14 @@ def ingest_cells(layout: ColumnLayout, state: State, X: Array, t: Array,
         from repro.kernels.seg_gram import ops as sg_ops
 
         dn = _dn(layout, X, t, y, z)
+        if layout.rows:
+            return {
+                "ng": sg_ops.segment_outer(dn, dn, comb, n_cells,
+                                           row_block=row_block,
+                                           init=state["ng"]),
+                "counts": state["counts"] + sg_ops.segment_counts(comb,
+                                                                  n_cells),
+            }
         v = _vrow(layout, phi, dn)
         return {
             "ng": sg_ops.segment_outer(dn, dn, comb, n_cells,
@@ -143,6 +211,10 @@ def ingest_cells(layout: ColumnLayout, state: State, X: Array, t: Array,
         else:
             (phib, cb), zb = rest, None
         dn = _dn(layout, Xb, tb, yb, zb)
+        if layout.rows:
+            oh = jax.nn.one_hot(cb, n_cells, dtype=_F32)
+            return {"ng": jnp.einsum("nc,ni,nj->cij", oh, dn, dn),
+                    "counts": oh.sum(0)}
         v = _vrow(layout, phib, dn)
         oh = jax.nn.one_hot(cb, n_cells, dtype=_F32)
         return {

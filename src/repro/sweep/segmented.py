@@ -42,7 +42,7 @@ bitwise panel ≡ loop contract stays on the default cells mode.
 
 from __future__ import annotations
 
-from typing import Any, Dict
+from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -210,16 +210,53 @@ def segmented_dml_sweep(
     (segment, fold)}."""
     n = X.shape[0]
     k = cfg.n_folds
-    lam = cfg.ridge_lambda
-    rb, st = cfg.row_block, cfg.row_block_strategy
     folds = fold_ids(key, n, k)
     comb = sids * k + folds  # (n,) in [0, E·k)
 
     # one pass serves the y ridge and the logistic majorizer's design Gram
-    Gh, counts = _fold_grams(X, y, comb, n_segments, k, rb, st)
+    Gh, counts = _fold_grams(X, y, comb, n_segments, k, cfg.row_block,
+                             cfg.row_block_strategy)
+    return segmented_stages(cfg, Gh, counts, n_segments, y=y, t=t, sids=sids,
+                            folds=folds, comb=comb, X=X)
+
+
+def segmented_stages(
+    cfg: CausalConfig,
+    Gh: jax.Array,
+    counts: jax.Array,
+    n_segments: int,
+    *,
+    y: jax.Array,
+    t: jax.Array,
+    sids: jax.Array,
+    folds: jax.Array,
+    comb: jax.Array,
+    X: Optional[jax.Array] = None,
+    xa_t: Optional[jax.Array] = None,
+) -> Dict[str, jax.Array]:
+    """Everything after the fold Gram, shared by the sweep and the
+    moment store's windowed refresh: the fold complements, the ridge
+    visit models, the treatment models (MM logistic, or ridge for a
+    continuous treatment), the out-of-fold predictions and the
+    per-cohort final stage with its HC0 SE.
+
+    ``Gh`` (E, k, q + 1, q + 1) holds the held-out Grams of
+    [X | 1 | y] and ``counts`` (E, k) their rows.  The rows come either
+    row-major as ``X`` (the sweep, which packs [X | 1]ᵀ here) or
+    already lane-major as ``xa_t`` = [X | 1]ᵀ (the store's ring).  A
+    continuous treatment needs ``X`` (its ridge takes a fold Gram pass
+    of its own).  A row whose cohort is -1 (padding) adds nothing: its
+    ``xa_t`` column is zero, its MM cohort tag is 0 and every segment
+    sum drops it; the caller gives it a ``comb`` in range, so the
+    prediction gathers never wrap a negative index."""
+    k = cfg.n_folds
+    lam = cfg.ridge_lambda
+    rb, st = cfg.row_block, cfg.row_block_strategy
     Gc, n_eff = _complement(Gh, counts)
     beta_y = _ridge(Gc, n_eff, lam)
-    xa_t = jnp.concatenate([X.astype(_F32).T, jnp.ones((1, n), _F32)])  # [X | 1]ᵀ
+    if xa_t is None:
+        n = X.shape[0]
+        xa_t = jnp.concatenate([X.astype(_F32).T, jnp.ones((1, n), _F32)])  # [X | 1]ᵀ
     tt = t.astype(_F32)
     mm_iters = 2 * cfg.newton_iters  # MM trades per-step cost for steps
     if cfg.discrete_treatment:
@@ -234,6 +271,8 @@ def segmented_dml_sweep(
     my = _oof_predict(xa_t, beta_y, comb)
     ry = y.astype(_F32) - my
     rt = tt - mt
+    if X is None:  # the basis needs only the first few features
+        X = xa_t[: min(max(cfg.cate_features - 1, 0), xa_t.shape[0] - 1)].T
     phi = cate_basis(X, cfg.cate_features)
     theta, se = _segment_final_stage(
         ry, rt, phi, sids, n_segments, row_block=rb, strategy=st

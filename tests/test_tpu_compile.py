@@ -185,3 +185,56 @@ def test_sweep_mm_step_fused(one_chip):
                         _spec((E, K, q), one_chip))
     assert "%seg_gram_mm_logistic." in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < n * K * q * 4
+
+
+def test_store_window_refresh_and_ingest(one_chip, monkeypatch):
+    """The windowed store at ``panel_daily``'s size (28 days of 499,712
+    rows, p = 12, E = 64 x K = 5): the refresh program, 32 fused MM
+    steps and the sweep's final stage over the 14.0M rows of the ring,
+    fits well under 12 GB (9.14 GB when written); a day's ingest writes
+    its slot and its rows into the donated ring in place."""
+    from repro.config import CausalConfig
+    from repro.core.final_stage import cate_basis
+    from repro.kernels.seg_gram import ops as sg_ops
+    from repro.store import stats
+    from repro.store.solve import refresh_rows
+    from repro.store.store import _row_folds
+
+    # steer the seg_gram dispatch to the Mosaic kernels while JAX runs
+    # on the CPU (the programs are compiled for the described chip)
+    monkeypatch.setattr(sg_ops.jax, "default_backend", lambda: "tpu")
+    days, rows, p, E, K = 28, 499_712, 12, 64, 5
+    cfg = CausalConfig(n_folds=K, nuisance_t="logistic",
+                       discrete_treatment=True, newton_iters=16,
+                       row_block=8192, row_block_strategy="pallas",
+                       inference="none")
+    lay = stats.ColumnLayout(p=p, pf=1, k=K, iv=False, rows=True)
+    state = {"ng": _spec((days, E * K, lay.qd, lay.qd), one_chip),
+             "counts": _spec((days, E * K), one_chip),
+             "xa": _spec((lay.q, days * rows), one_chip),
+             "meta": _spec((4, days * rows), one_chip)}
+    slot = _spec((), one_chip, jnp.int32)
+    with sg_ops.force_backend("pallas"):
+        refresh = _compile(
+            lambda st, s: refresh_rows(lay, cfg, st, s, E, rows), state, slot)
+        text = refresh.as_text()
+        assert "%seg_gram_mm_logistic." in text
+        assert "%seg_gram_pair_seg." in text
+        mem = refresh.memory_analysis()
+        assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+                < 12e9), mem
+
+        def day(st, X, t, y, sids, start, key, s):
+            folds = _row_folds(key, start, rows, K)
+            return stats.ingest_day(lay, st, s, X, t, y, None,
+                                    cate_basis(X, 1), sids, folds, E * K,
+                                    row_block=8192, strategy="pallas")
+
+        ingest = jax.jit(day, donate_argnums=(0,)).lower(
+            state, _spec((rows, p), one_chip), _spec((rows,), one_chip),
+            _spec((rows,), one_chip), _spec((rows,), one_chip, jnp.int32),
+            _spec((), one_chip, jnp.uint32),
+            _spec((2,), one_chip, jnp.uint32), slot).compile()
+    mem = ingest.memory_analysis()
+    assert mem.alias_size_in_bytes >= days * rows * (lay.q + 4) * 4, mem
+    assert mem.temp_size_in_bytes < 1e9, mem
