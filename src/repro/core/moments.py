@@ -390,6 +390,71 @@ def fold_weighted_gram(X: Array, Wk: Array, *, intercept: bool = False,
     return G, n_eff
 
 
+def fold_sizes(n: int, k: int) -> Tuple[int, ...]:
+    """Rows per fold of ``crossfit.fold_ids``, which shuffles
+    ``arange(n) % k``: fold j holds ceil((n - j) / k) of them."""
+    return tuple((n - j + k - 1) // k for j in range(k))
+
+
+def grouped_fold_weighted_gram(X: Array, folds: Array, w: Array, k: int, *,
+                               intercept: bool = False,
+                               append: Optional[Array] = None,
+                               row_block: int = 0,
+                               strategy: Optional[str] = None
+                               ) -> Tuple[Array, Array]:
+    """``fold_weighted_gram`` on ``fold_weights(folds, k) * w`` from
+    rows grouped by fold: each fold's own weighted Gram
+    ``H_j = Σ_{n in fold j} w_n d_n d_nᵀ`` over its rows alone, then
+    ``G[c] = Σ_{j ≠ c} H_j`` by additions in a fixed order (no
+    subtraction, so no cancellation).  A row costs one Gram's work, not
+    the kron form's k.
+
+    ``folds`` must be drawn by ``crossfit.fold_ids``, so every fold has
+    its static size (``fold_sizes``).  A stable sort of the ids groups
+    the rows; the folds are reduced one after another, each from a
+    gathered copy of its rows padded with zero-weight rows to the
+    largest fold, so one fold's copy is live at a time.  Each reduction
+    is the S = 1 weighted form of the strategy: the explicit-fold-axis
+    einsum with one fold (whole-array or blocked), or the pallas design
+    Gram with row weights.  ``n_eff`` is the kron form's plain sum,
+    bitwise."""
+    f32 = jnp.float32
+    n = X.shape[0]
+    ks = jnp.arange(k, dtype=folds.dtype)
+    n_eff = ((folds[None, :] != ks[:, None]).astype(f32)
+             * w[None, :]).astype(f32).sum(axis=1)
+    sizes = jnp.asarray(fold_sizes(n, k), jnp.int32)
+    starts = jnp.cumsum(sizes) - sizes
+    # each fold's rows (a stable sort: in ascending order), a shorter
+    # fold padded with repeats of its last row at weight zero
+    order = jnp.argsort(folds, stable=True)
+    at = jnp.arange(fold_sizes(n, k)[0], dtype=jnp.int32)
+    idx = order[starts[:, None] + jnp.minimum(at[None, :], sizes[:, None] - 1)]
+    keep = at[None, :] < sizes[:, None]
+    pallas = _use_pallas(n, row_block, strategy)
+
+    def rows(a, ix):
+        return a.at[ix].get(mode="promise_in_bounds")
+
+    def own(args):
+        ix, ok = args
+        Xj = rows(X, ix)
+        aj = None if append is None else rows(append, ix)
+        wj = jnp.where(ok, rows(w, ix), 0).astype(f32)
+        if pallas:
+            D = design_parts(Xj, intercept=intercept, append=aj)
+            return _seg_ops().design_gram(D, w=wj, row_block=row_block)
+        G, _ = fold_weighted_gram(Xj, wj[None, :], intercept=intercept,
+                                  append=aj, row_block=row_block,
+                                  strategy=strategy)
+        return G[0]
+
+    H = lax.map(own, (idx, keep))                                  # (k, q, q)
+    zero = jnp.zeros_like(H[0])
+    G = [sum((H[j] for j in range(k) if j != c), zero) for c in range(k)]
+    return jnp.stack(G), n_eff
+
+
 # ---------------------------------------------------------------------------
 # Residual moments (the DML final stage): Z = (t - mt) ⊙ phi,
 # G = ZᵀZ, b = Zᵀ(y - my), meat = Σ e²·z zᵀ.
@@ -459,18 +524,21 @@ def _meat_gram(score: Array, e: Array, p: int) -> Array:
 
     XLA's tiling of the n-contraction is shape-dependent: with a
     COMPUTED weight (e² is a fused elementwise producer, unlike the
-    plain-input weights of the Gram kernels above) the 3-operand
-    ``ni,n,nj->ij`` einsum tends to keep its reduction order under an
-    added vmap axis at p = 1, while folding e into the score and
-    contracting ``mᵀm`` keeps it at p ≥ 2 (measured on CPU XLA).
-    Dispatch on the static width picks the stabler form per regime; the
+    plain-input weights of the Gram kernels above) folding e into the
+    score and contracting ``mᵀm`` keeps its reduction order under an
+    added vmap axis at p ≥ 2 (measured on CPU XLA).  At p = 1 every
+    contraction is a vector dot, which CPU XLA emits as a fused loop
+    without a batch axis and as a dot kernel with one, so a replicate
+    run alone (or in a chunk of one) and the same replicate in a batch
+    sum in different orders; the meat is then the plain sum Σ (e·s)²,
+    elementwise square and sum, as ``iv_meat``'s p = 1 branch.  The
     serial ≡ vmap CONTRACT is certified on the row-blocked path, where
     the scan barrier makes it shape-robust (tests/test_conformance.py
     pins it there)."""
+    m = e[:, None] * score
     if p >= 2:
-        m = e[:, None] * score
         return jnp.einsum("ni,nj->ij", m, m)
-    return jnp.einsum("ni,n,nj->ij", score, jnp.square(e), score)
+    return jnp.square(m[:, 0]).sum().reshape(1, 1)
 
 
 def residual_meat(y: Array, t: Array, my: Array, mt: Array, phi: Array,

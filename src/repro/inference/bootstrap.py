@@ -7,7 +7,9 @@ bootstrap = multinomial row counts; multiplier/Bayesian = Exp(1) row
 weights), which reuses the weighted-fit path that ``fold_weights``
 already exercises for C1: replicate weights multiply the fold-complement
 masks, so the (B, k, n) weight tensor turns B full re-estimations into
-one stacked program dispatched by an Executor.
+one stacked program dispatched by an Executor.  A ridge nuisance takes
+the same weights as fold ids and row weights, and builds its fold Grams
+from the rows grouped by fold.
 
 Replay: replicate b derives all of its randomness (resampling weights
 AND fold assignment) from ``fold_in(base_key, b)`` — any replicate can
@@ -64,15 +66,19 @@ def _hyper(nuis: Nuisance, name: str, default):
 
 
 def fit_predict_folds(nuis: Nuisance, key: jax.Array, X: jax.Array,
-                      target: jax.Array, Wk: jax.Array,
-                      row_block: int = 0) -> jax.Array:
-    """(k, n) fold-model predictions under weighted training.
+                      target: jax.Array, folds: jax.Array, w: jax.Array,
+                      k: int, row_block: int = 0) -> jax.Array:
+    """(k, n) fold-model predictions, fold model j trained on the rows
+    outside fold j with row weights ``w`` (folds drawn by
+    ``crossfit.fold_ids``).
 
     ridge/logistic take the replicate-invariant fold-batched kernels
     (serial == vmap bitwise), streamed in row blocks when the nuisance
     carries a ``row_block`` hyper (or one is passed) through the
-    nuisance's ``strategy`` hyper (the pallas strategy takes the fused
-    fold-weighted kernel, like the point fit); other nuisances
+    nuisance's ``strategy`` hyper; ridge builds its fold Grams from
+    rows grouped by fold, logistic from the dense (k, n) weights
+    ``fold_weights(folds, k) * w`` (its Newton weights differ by fold
+    model, not only by the fold mask).  Other nuisances
     (MLP, custom) fall back to vmapping ``nuis.fit`` over folds —
     statistically identical, but LAPACK-free bit-identity is not
     guaranteed there.
@@ -82,8 +88,9 @@ def fit_predict_folds(nuis: Nuisance, key: jax.Array, X: jax.Array,
     if nuis.name == "ridge":
         lam = _hyper(nuis, "lam", 1e-3)
         return predict_folds_linear(
-            ridge_fit_folds_w(lam, X, target, Wk, row_block=rb,
-                              strategy=st), X)
+            ridge_fit_folds_w(lam, X, target, folds=folds, w=w, k=k,
+                              row_block=rb, strategy=st), X)
+    Wk = fold_weights(folds, k) * w[None, :]
     if nuis.name == "logistic":
         lam = _hyper(nuis, "lam", 1e-3)
         iters = int(_hyper(nuis, "iters", 16))
@@ -109,7 +116,8 @@ def shares_nuisance_gram(nuis_y: Nuisance, nuis_t: Nuisance) -> bool:
 
 def _ridge_pair_predict_folds(nuis_y: Nuisance, nuis_t: Nuisance,
                               X: jax.Array, y: jax.Array, t: jax.Array,
-                              Wk: jax.Array, row_block: int = 0):
+                              folds: jax.Array, w: jax.Array, k: int,
+                              row_block: int = 0):
     """The (k, n) fold-model predictions of the y and t ridges from one
     Gram and, when the penalties agree, one elimination — the bits of
     two ``fit_predict_folds`` calls.  Each ridge predicts on its own: a
@@ -118,8 +126,8 @@ def _ridge_pair_predict_folds(nuis_y: Nuisance, nuis_t: Nuisance,
     f32 = jnp.float32
     beta_y, beta_t = ridge_fit_folds_w(
         (_hyper(nuis_y, "lam", 1e-3), _hyper(nuis_t, "lam", 1e-3)), X,
-        jnp.stack([y.astype(f32), t.astype(f32)], axis=1), Wk,
-        row_block=row_block or int(_hyper(nuis_y, "row_block", 0)),
+        jnp.stack([y.astype(f32), t.astype(f32)], axis=1), folds=folds,
+        w=w, k=k, row_block=row_block or int(_hyper(nuis_y, "row_block", 0)),
         strategy=_hyper(nuis_y, "strategy", None))
     return predict_folds_linear(beta_y, X), predict_folds_linear(beta_t, X)
 
@@ -136,17 +144,16 @@ def dml_residuals_once(nuis_y: Nuisance, nuis_t: Nuisance, n_folds: int,
     their Gram (``shares_nuisance_gram``)."""
     kf, ky, kt = jax.random.split(key, 3)
     folds = fold_ids(kf, XW.shape[0], n_folds)
-    Wk = fold_weights(folds, n_folds) * w[None, :]
     if shares_nuisance_gram(nuis_y, nuis_t):
-        pred_y, pred_t = _ridge_pair_predict_folds(nuis_y, nuis_t, XW, y,
-                                                   t, Wk, row_block)
+        pred_y, pred_t = _ridge_pair_predict_folds(
+            nuis_y, nuis_t, XW, y, t, folds, w, n_folds, row_block)
         oof_y = _oof_select(pred_y, folds)
         oof_t = _oof_select(pred_t, folds)
     else:
-        oof_y = _oof_select(fit_predict_folds(nuis_y, ky, XW, y, Wk,
-                                              row_block), folds)
-        oof_t = _oof_select(fit_predict_folds(nuis_t, kt, XW, t, Wk,
-                                              row_block), folds)
+        oof_y = _oof_select(fit_predict_folds(nuis_y, ky, XW, y, folds, w,
+                                              n_folds, row_block), folds)
+        oof_t = _oof_select(fit_predict_folds(nuis_t, kt, XW, t, folds, w,
+                                              n_folds, row_block), folds)
     return {"ry": y.astype(jnp.float32) - oof_y,
             "rt": t.astype(jnp.float32) - oof_t}
 
@@ -226,7 +233,10 @@ def dml_bootstrap(nuis_y: Nuisance, nuis_t: Nuisance, *, n_folds: int,
     or ``[reused]`` on the process registry, and the span carries the
     same word as ``replicate_fn``; likewise
     ``inference.nuisance_gram[shared|separate]`` and ``nuisance_gram``
-    say whether the replicate fits both nuisances from one Gram."""
+    say whether the replicate fits both nuisances from one Gram, and
+    ``inference.fold_gram[grouped|kron]`` and ``fold_gram`` whether
+    both (ridge) build their fold Grams from rows grouped by fold or one
+    takes the dense (k, n) weights (``fit_predict_folds``)."""
     from repro.obs.metrics import default_registry
     from repro.obs.trace import layer_span
     from repro.runtime import as_runtime
@@ -255,6 +265,10 @@ def dml_bootstrap(nuis_y: Nuisance, nuis_t: Nuisance, *, n_folds: int,
                 else "separate")
         default_registry().counter(f"inference.nuisance_gram[{gram}]").inc()
         sp.attrs["nuisance_gram"] = gram
+        form = ("grouped" if nuis_y.name == nuis_t.name == "ridge"
+                else "kron")
+        default_registry().counter(f"inference.fold_gram[{form}]").inc()
+        sp.attrs["fold_gram"] = form
         out = rt.map(replicate, keys, XW, y, t, phi, label="dml_bootstrap")
         thetas = out["theta"]
         se = jnp.std(thetas, axis=0, ddof=1)
@@ -276,13 +290,10 @@ def iv_residuals_once(nuis_y: Nuisance, nuis_t: Nuisance,
     (shared by sweep cells that differ only in final stage)."""
     kf, ky, kt, kz = jax.random.split(key, 4)
     folds = fold_ids(kf, XW.shape[0], n_folds)
-    Wk = fold_weights(folds, n_folds) * w[None, :]
-    oof_y = _oof_select(fit_predict_folds(nuis_y, ky, XW, y, Wk,
-                                          row_block), folds)
-    oof_t = _oof_select(fit_predict_folds(nuis_t, kt, XW, t, Wk,
-                                          row_block), folds)
-    oof_z = _oof_select(fit_predict_folds(nuis_z, kz, XW, z, Wk,
-                                          row_block), folds)
+    fw = (folds, w, n_folds, row_block)
+    oof_y = _oof_select(fit_predict_folds(nuis_y, ky, XW, y, *fw), folds)
+    oof_t = _oof_select(fit_predict_folds(nuis_t, kt, XW, t, *fw), folds)
+    oof_z = _oof_select(fit_predict_folds(nuis_z, kz, XW, z, *fw), folds)
     return {"ry": y.astype(jnp.float32) - oof_y,
             "rt": t.astype(jnp.float32) - oof_t,
             "rz": z.astype(jnp.float32) - oof_z}
@@ -360,18 +371,15 @@ def driv_theta_once(nuis_y: Nuisance, nuis_t: Nuisance, nuis_z: Nuisance,
     n = XW.shape[0]
     kf, ky, kt, kz, kb = jax.random.split(key, 5)
     folds = fold_ids(kf, n, n_folds)
-    Wk = fold_weights(folds, n_folds) * w[None, :]
-    oof_y = _oof_select(fit_predict_folds(nuis_y, ky, XW, y, Wk,
-                                          row_block), folds)
-    oof_t = _oof_select(fit_predict_folds(nuis_t, kt, XW, t, Wk,
-                                          row_block), folds)
-    oof_z = _oof_select(fit_predict_folds(nuis_z, kz, XW, z, Wk,
-                                          row_block), folds)
+    fw = (folds, w, n_folds, row_block)
+    oof_y = _oof_select(fit_predict_folds(nuis_y, ky, XW, y, *fw), folds)
+    oof_t = _oof_select(fit_predict_folds(nuis_t, kt, XW, t, *fw), folds)
+    oof_z = _oof_select(fit_predict_folds(nuis_z, kz, XW, z, *fw), folds)
     ry = y.astype(f32) - oof_y
     rt = t.astype(f32) - oof_t
     rz = z.astype(f32) - oof_z
     oof_b = _oof_select(fit_predict_folds(compliance, kb, XW, rt * rz,
-                                          Wk, row_block), folds)
+                                          *fw), folds)
     beta = clip_compliance(oof_b, cov_clip)
     ones = jnp.ones((n, 1), f32)
     th_pre, _ = weighted_iv_theta(ry, rt, rz, ones, w, with_se=False,
@@ -440,17 +448,14 @@ def dr_theta_once(outcome: Nuisance, propensity: Nuisance, n_folds: int,
     kf, k0, k1, ke = jax.random.split(key, 4)
     n = X.shape[0]
     folds = fold_ids(kf, n, n_folds)
-    W = fold_weights(folds, n_folds)
     tt = t.astype(jnp.float32)
-    arm0 = (1.0 - tt)[None, :]
-    arm1 = tt[None, :]
-    wk = w[None, :]
-    m0 = _oof_select(fit_predict_folds(outcome, k0, X, y,
-                                       W * arm0 * wk, row_block), folds)
-    m1 = _oof_select(fit_predict_folds(outcome, k1, X, y,
-                                       W * arm1 * wk, row_block), folds)
-    e = _oof_select(fit_predict_folds(propensity, ke, X, tt, W * wk,
-                                      row_block), folds)
+    m0 = _oof_select(fit_predict_folds(outcome, k0, X, y, folds,
+                                       (1.0 - tt) * w, n_folds, row_block),
+                     folds)
+    m1 = _oof_select(fit_predict_folds(outcome, k1, X, y, folds, tt * w,
+                                       n_folds, row_block), folds)
+    e = _oof_select(fit_predict_folds(propensity, ke, X, tt, folds, w,
+                                      n_folds, row_block), folds)
     e = jnp.clip(e, clip, 1.0 - clip)
     psi = (m1 - m0
            + tt * (y - m1) / e

@@ -32,7 +32,9 @@ because every system we solve is SPD plus an explicit ridge.
 
 These kernels double as the weighted-fit path for bootstrap replicates:
 ``Wk`` carries fold-complement masks multiplied by per-row bootstrap
-weights, the same mechanism ``crossfit.fold_weights`` uses for C1.
+weights, the same mechanism ``crossfit.fold_weights`` uses for C1.  The
+ridge fit also takes those weights as fold ids and row weights, and then
+reduces the rows grouped by fold (``moments.grouped_fold_weighted_gram``).
 
 The Gram-shaped reductions themselves live in the streaming moments
 engine (``repro.core.moments``): this module no longer re-implements
@@ -235,11 +237,22 @@ def _aug(X: jax.Array) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 def ridge_fit_folds_w(lam, X: jax.Array, y: jax.Array,
-                      Wk: jax.Array, *, row_block: int = 0,
+                      Wk: Optional[jax.Array] = None, *,
+                      folds: Optional[jax.Array] = None,
+                      w: Optional[jax.Array] = None, k: int = 0,
+                      row_block: int = 0,
                       strategy: Optional[str] = None) -> jax.Array:
     """Weighted per-fold ridge, one augmented fold-weighted Gram from
     the moments engine.  Returns beta (k, p+1) (intercept last,
     matching nuisance.make_ridge's column order).
+
+    The weights come as a dense (k, n) ``Wk`` (the kron form,
+    ``moments.fold_weighted_gram``), or as fold ids drawn by
+    ``crossfit.fold_ids`` with row weights ``w`` and the fold count
+    ``k``: the fit's weights are then ``fold_weights(folds, k) * w``,
+    and the Gram comes from rows grouped by fold
+    (``moments.grouped_fold_weighted_gram``), at a fifth of the kron
+    form's work for k = 5.
 
     ``y`` (n, m) fits m targets on the same X and weights from ONE Gram
     over [X | 1 | y_1 .. y_m], returning (m, k, p+1); ``lam`` is then
@@ -248,10 +261,15 @@ def ridge_fit_folds_w(lam, X: jax.Array, y: jax.Array,
     target's bits are those of its own one-target fit."""
     f32 = jnp.float32
     p = X.shape[1] + 1
-    Gaug, n_eff = moments.fold_weighted_gram(X, Wk, intercept=True,
-                                             append=y,
-                                             row_block=row_block,
-                                             strategy=strategy)
+    if Wk is None:
+        Gaug, n_eff = moments.grouped_fold_weighted_gram(
+            X, folds, w, k, intercept=True, append=y, row_block=row_block,
+            strategy=strategy)
+    else:
+        Gaug, n_eff = moments.fold_weighted_gram(X, Wk, intercept=True,
+                                                 append=y,
+                                                 row_block=row_block,
+                                                 strategy=strategy)
     n_eff = jnp.maximum(n_eff, 1.0)                             # (k,)
     if y.ndim == 1:
         A = Gaug[:, :p, :p] / n_eff[:, None, None] \

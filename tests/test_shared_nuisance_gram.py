@@ -144,13 +144,13 @@ def test_unequal_penalties_share_the_gram_and_solve_apart(datasets,
     nt = make_ridge(3e-2, ROW_BLOCK, "chunked")
     assert bootstrap.shares_nuisance_gram(ny, nt)
     grams = []
-    real = moments.fold_weighted_gram
+    real = moments.grouped_fold_weighted_gram
 
     def counted(*a, **kw):
         grams.append(kw["append"].shape)
         return real(*a, **kw)
 
-    monkeypatch.setattr(moments, "fold_weighted_gram", counted)
+    monkeypatch.setattr(moments, "grouped_fold_weighted_gram", counted)
     shared = _replicates(ny, nt, datasets[p], True, final_stage=False)
     assert grams == [(N, 2)]
     path = "panelled" if p > 64 else "unblocked"

@@ -124,6 +124,37 @@ def test_bootstrap_shared_nuisance_gram_kron(one_chip):
     assert "%seg_gram_fold_weighted." in compiled.as_text()
 
 
+def test_bootstrap_replicate_grouped_fold_grams(one_chip, monkeypatch):
+    """The ridge bootstrap replicate at the Fit's size (n = 1M, p = 500,
+    K = 5, the pairs scheme) as the runtime runs it, two replicates a
+    chunk: its fold Grams come from rows grouped by fold, so the program
+    holds the S = 1 design kernel and no kron form.  Its temp is 3.07 GB
+    a replicate, as the kron form's was, so a 60% budget of a v5e's
+    16 GB still takes chunks of 2."""
+    from repro.core.nuisance import make_ridge
+    from repro.inference.bootstrap import make_dml_replicate_fn
+    from repro.kernels.seg_gram import ops as sg_ops
+
+    monkeypatch.setattr(sg_ops.jax, "default_backend", lambda: "tpu")
+    p, K, rb, chunk = 500, 5, 8192, 2
+    ny, nt = (make_ridge(1e-3, rb, "pallas") for _ in range(2))
+    fn = make_dml_replicate_fn(ny, nt, K, row_block=rb, strategy="pallas")
+
+    def batched(kb, X, y, t, phi):
+        return jax.vmap(lambda k: fn(k, X, y, t, phi))(kb)
+
+    row = _spec((N_FIT,), one_chip)
+    with sg_ops.force_backend("pallas"):
+        compiled = _compile(batched, _spec((chunk, 2), one_chip, jnp.uint32),
+                            _spec((N_FIT, p), one_chip), row, row,
+                            _spec((N_FIT, 1), one_chip))
+    text = compiled.as_text()
+    assert "%seg_gram_design." in text
+    assert "seg_gram_fold_weighted" not in text
+    per_replicate = compiled.memory_analysis().temp_size_in_bytes / chunk
+    assert per_replicate < 3.2e9, per_replicate
+
+
 @pytest.mark.parametrize("qU,qV", [(1, 51), (52, 52), (106, 106)])
 def test_sweep_segment_outer_s320(one_chip, qU, qV):
     """S = E·K = 320 cells at E = 64, K = 5, p = 50: the sweep's MM
